@@ -52,7 +52,7 @@ _METRIC_KEYS_RE = re.compile(r"^[A-Z0-9_]*METRIC_KEYS$")
 # deliberately differ per stage and are only type-checked.
 SHARED_RUNTIME_FLAGS = frozenset({
     "telemetry", "data_placement", "data_window_batches",
-    "device_budget_mb", "compile_cache",
+    "device_budget_mb",
     "trace_dir", "trace_start_step", "trace_steps",
     "flight_recorder", "watchdog_secs", "metrics_port", "metrics_host",
 })

@@ -100,10 +100,9 @@ class SupConConfig:
     # (ops/pallas_conv.py — the inter-op activation round-trips that fund
     # XLA's stage-1 BN-backward/residual fusions never touch HBM), in fp32
     # or bf16 compute (fp32 MXU accumulation, fp32 BN statistics); 'xla'
-    # is the bitwise-pinned default path; 'auto' picks pallas only on a
-    # single-chip TPU mesh at supported stage geometries
-    # (train.supcon.resolve_conv_impl, the --loss_impl ladder convention,
-    # startup banner names the resolution and the compute dtype)
+    # is the bitwise-pinned default path; 'auto' resolves to 'xla' until a
+    # chip cell shows a fused kind compiling and winning (ROADMAP A1;
+    # train.supcon.resolve_conv_impl, startup banner names the resolution)
     conv_impl: str = "auto"
     # 'sgd' is the published recipe (util.py:79-84); 'lars' for the
     # large-global-batch configs (SimCLR ImageNet bs=4096, BASELINE configs[4])
@@ -112,9 +111,6 @@ class SupConConfig:
     trace_dir: str = ""
     trace_start_step: int = 10
     trace_steps: int = 10
-    # persistent XLA compile cache ('auto' = <workdir>/.jax_cache, '' = off);
-    # cuts the ~40-80s first-step compile on restarts/resumes
-    compile_cache: str = "auto"
     # abort + emergency-checkpoint on NaN/Inf loss (utils/guard.py)
     nan_guard: bool = True
     # what to DO about a non-finite loss (utils/guard.py FailurePolicy):
@@ -359,10 +355,9 @@ def supcon_parser() -> argparse.ArgumentParser:
                         "the stem, BasicBlocks (identity and "
                         "projection/stride-2 shortcuts), and rn50-family "
                         "Bottlenecks, fp32 or bf16 compute, vs the "
-                        "bitwise-pinned XLA path; 'auto' = pallas only "
-                        "on a single-chip TPU at supported geometries "
-                        "(startup banner names the resolution and "
-                        "compute dtype)")
+                        "bitwise-pinned XLA path; 'auto' = xla (no fused "
+                        "kind has yet compiled and won on the chip; "
+                        "startup banner names the resolution)")
     p.add_argument("--optimizer", type=str, default=d.optimizer,
                    choices=["sgd", "lars"],
                    help="lars: layer-adaptive scaling for large global batches")
@@ -455,8 +450,8 @@ def nonnegative_int_arg(name: str):
 
 
 def _add_shared_runtime_flags(p: argparse.ArgumentParser, d) -> None:
-    """The shared runtime surface (telemetry/data-placement/profiling/
-    compile-cache): ONE registry serving all three trainers' parsers.
+    """The shared runtime surface (telemetry/data-placement/profiling):
+    ONE registry serving all three trainers' parsers.
 
     These flags mean the same thing on every stage, so they must parse the
     same way everywhere — previously three hand-synced copies, now the one
@@ -493,7 +488,6 @@ def _add_shared_runtime_flags(p: argparse.ArgumentParser, d) -> None:
                    help="capture a jax.profiler trace into this dir")
     p.add_argument("--trace_start_step", type=int, default=d.trace_start_step)
     p.add_argument("--trace_steps", type=int, default=d.trace_steps)
-    p.add_argument("--compile_cache", type=str, default=d.compile_cache)
 
 
 def _add_observability_flags(p: argparse.ArgumentParser, d) -> None:
@@ -719,7 +713,6 @@ class LinearConfig:
     seed: int = 0
     workdir: str = "./work_space"
     trial: str = "0"
-    compile_cache: str = "auto"  # same semantics as the pretrain flag
     telemetry: str = "async"  # same semantics as the pretrain flag
     data_placement: str = "auto"  # same semantics as the pretrain flag
     data_window_batches: int = 32  # same semantics as the pretrain flag

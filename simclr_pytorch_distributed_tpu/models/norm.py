@@ -209,11 +209,9 @@ class CrossReplicaBatchNorm(nn.Module):
             for a in reduce_axes:
                 count *= x.shape[a]
             if self.axis_name is not None and self.sync:
-                from simclr_pytorch_distributed_tpu.compat import axis_size
-
                 mean = jax.lax.pmean(mean, self.axis_name)
                 mean_sq = jax.lax.pmean(mean_sq, self.axis_name)
-                count *= axis_size(self.axis_name)
+                count *= jax.lax.axis_size(self.axis_name)
             var = mean_sq - jnp.square(mean)  # biased — used for normalization
 
             if not self.is_initializing():

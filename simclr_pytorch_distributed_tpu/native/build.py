@@ -7,6 +7,7 @@ numpy paths) if no toolchain is available.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -15,19 +16,33 @@ from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "gather.cpp")
-_LIB = os.path.join(_DIR, "libsptpu_native.so")
+_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17"]
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _compile() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _LIB]
+def _lib_path() -> str:
+    """The library's file name carries a hash of its source and flags, so a
+    stale ``.so`` (other source, a copied tree) is never the one loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(_FLAGS).encode())
+    return os.path.join(_DIR, f"libsptpu_native.{digest.hexdigest()[:16]}.so")
+
+
+def _compile(lib_path: str) -> bool:
+    # build under a private name, then rename: concurrent first imports
+    # (pytest workers) never load a half-written file
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
         return True
-    except Exception as e:  # toolchain missing / sandboxed
-        logging.info("native staging lib unavailable (%s); using numpy paths", e)
+    except (OSError, subprocess.SubprocessError) as e:  # no toolchain
+        logging.warning(
+            "native staging lib unavailable (%s); using numpy paths", e
+        )
         return False
 
 
@@ -40,13 +55,13 @@ def load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("SPTPU_NATIVE", "1") == "0":
             return None
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-            if not _compile():
-                return None
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _compile(lib_path):
+            return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(lib_path)
         except OSError as e:
-            logging.info("failed to load native lib: %s", e)
+            logging.warning("failed to load native lib: %s", e)
             return None
         lib.gather_rows_u8.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,

@@ -48,7 +48,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from simclr_pytorch_distributed_tpu.compat import axis_size, shape_dtype_struct
 
 _NEG_INF = -1e30
 
@@ -164,7 +163,7 @@ def _fwd_call(
     kernel = functools.partial(
         _fwd_kernel, bm=bm, bn=bn, inv_temp=1.0 / temperature, scale=scale
     )
-    out_shape = [shape_dtype_struct((nr, 1), jnp.float32, vma=vma)] * 3
+    out_shape = [jax.ShapeDtypeStruct((nr, 1), jnp.float32, vma=vma)] * 3
     scratch = [pltpu.VMEM((bm, 1), jnp.float32) for _ in range(4)]
     row_spec = _vmem_spec((bm, 1), lambda i, j: (i, 0))
     col_spec = _vmem_spec((1, bn), lambda i, j: (0, j))
@@ -207,7 +206,7 @@ def _bwd_call(
             row_spec, col_spec, row_spec, col_spec,
         ],
         out_specs=_vmem_spec((bm, d), lambda i, j: (i, 0)),
-        out_shape=shape_dtype_struct((nr, d), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((nr, d), jnp.float32, vma=vma),
         interpret=interpret,
         scratch_shapes=scratch,
     )(
@@ -278,9 +277,7 @@ def _vary(x, axis_name):
             return x
     except AttributeError:
         pass
-    from simclr_pytorch_distributed_tpu.compat import pvary
-
-    return pvary(x, (axis_name,))  # identity on pre-vma jax
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
@@ -297,7 +294,7 @@ def _fused_sharded(
 
 def _sharded_indices(feats_local, axis_name):
     m = feats_local.shape[0]
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     grow = my * m + jnp.arange(m, dtype=jnp.int32)  # device-varying
     gcol = _vary(jnp.arange(m * p, dtype=jnp.int32), axis_name)
@@ -333,7 +330,7 @@ def _fused_sharded_bwd(
         # cotangent as per-shard 1/P shares — psum recovers the full scalar.
         g = jax.lax.psum(g, axis_name)
     m = feats_local.shape[0]
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     n = m * p
     all_feats = _vary(
         jax.lax.all_gather(feats_local, axis_name, tiled=True), axis_name
@@ -440,7 +437,7 @@ def fused_sharded_supcon_loss(
     computes the exact global gradient of its own rows (see module docstring).
     """
     m = feats_local.shape[0]
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     n = m * p
     if n % n_views:
         raise ValueError(f"global rows {n} not divisible by n_views={n_views}")

@@ -57,9 +57,7 @@ def ring_supcon_loss(
       Per-device mean anchor loss pmean-ed over the axis == the global loss.
     """
     m, _ = feats_local.shape
-    from simclr_pytorch_distributed_tpu.compat import axis_size
-
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     rows_total = m * p  # V*B
     batch = rows_total // n_views
@@ -103,10 +101,8 @@ def ring_supcon_loss(
 
     def dev_varying(x):
         # mark fresh accumulators as device-varying for shard_map's vma
-        # typing (identity on pre-vma jax, compat.pvary)
-        from simclr_pytorch_distributed_tpu.compat import pvary
-
-        return pvary(x, (axis_name,))
+        # typing
+        return jax.lax.pcast(x, (axis_name,), to="varying")
 
     init = (
         feats_local,

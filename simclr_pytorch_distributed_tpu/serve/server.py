@@ -329,8 +329,14 @@ def build_stack(args):
     """
     from simclr_pytorch_distributed_tpu.serve.cache import EmbeddingCache
     from simclr_pytorch_distributed_tpu.serve.engine import EmbeddingEngine
+    from simclr_pytorch_distributed_tpu.train.supcon import (
+        enable_compile_cache,
+    )
     from simclr_pytorch_distributed_tpu.utils import prom, tracing
 
+    # each bucket program compiles on its first request: share the
+    # trainers' persistent cache so a restarted server does not pay again
+    enable_compile_cache()
     buckets = tuple(int(b) for b in args.buckets.split(","))
     cache = EmbeddingCache(args.cache_capacity) if args.cache_capacity else None
     kwargs = dict(buckets=buckets, normalize=args.normalize,

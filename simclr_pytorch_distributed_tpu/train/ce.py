@@ -148,7 +148,7 @@ def run(cfg: config_lib.LinearConfig):
     setup_distributed()
     cfg.save_folder = broadcast_from_main(cfg.save_folder)
     cfg.tb_folder = broadcast_from_main(cfg.tb_folder)
-    enable_compile_cache(cfg.compile_cache, cfg.workdir)
+    enable_compile_cache()
     setup_logging(cfg.save_folder, is_main_process())
     mesh = create_mesh()
 

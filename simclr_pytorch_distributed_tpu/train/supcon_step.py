@@ -34,8 +34,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-
-from simclr_pytorch_distributed_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from simclr_pytorch_distributed_tpu.models import LinearClassifier
@@ -378,7 +377,9 @@ def contrastive_loss_terms(
             n_features, labels=loss_labels,
             temperature=cfg.temperature, base_temperature=cfg.base_temperature,
             # Mosaic compiles only on TPU; anywhere else (CPU tests) the
-            # kernel runs under the Pallas interpreter.
+            # kernel runs under the Pallas interpreter. What rules out a
+            # silent interpret run on the chip is chip_smoke.py's platform
+            # check plus the loss_impl/conv_impl banners, not an option here.
             interpret=jax.default_backend() != "tpu",
         )
     else:

@@ -32,10 +32,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-if cache_dir:
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(cache_dir))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# the persistent compile cache is placed by JAX_COMPILATION_CACHE_DIR
+# (jax reads the variable itself; the parent sets it)
 
 import logging  # noqa: E402
 

@@ -42,10 +42,8 @@ if nproc > 1:
     # backend" (the env-var spelling does not reach this flag on this
     # jax/jaxlib, so it must be a config update here)
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
-cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-if cache_dir:
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(cache_dir))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# the persistent compile cache is placed by JAX_COMPILATION_CACHE_DIR
+# (jax reads the variable itself; the parent sets it)
 if nproc > 1:
     jax.distributed.initialize(
         coordinator_address=f"localhost:{port}",

@@ -254,7 +254,36 @@ def test_make_store_resolves_from_the_loader_itself():
 
 def test_device_budget_bytes_falls_back_without_memory_stats():
     # CPU devices report no memory stats -> the fixed conservative default
-    assert device_store.device_budget_bytes() > 0
+    assert device_store.device_budget_bytes() == device_store.DEFAULT_BUDGET_BYTES
+
+
+class _FakeTpu:
+    platform = "tpu"
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("stats", [None, {}, {"bytes_in_use": 5}])
+def test_device_budget_bytes_raises_on_a_tpu_without_stats(monkeypatch, stats):
+    """No fallback that hides the device: a TPU that cannot say how much
+    memory it has is an error, not DEFAULT_BUDGET_BYTES."""
+    monkeypatch.setattr(
+        device_store.jax, "local_devices", lambda: [_FakeTpu(stats)]
+    )
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        device_store.device_budget_bytes()
+
+
+def test_device_budget_bytes_is_a_fraction_of_free_tpu_memory(monkeypatch):
+    monkeypatch.setattr(
+        device_store.jax, "local_devices",
+        lambda: [_FakeTpu({"bytes_limit": 1000, "bytes_in_use": 200})],
+    )
+    assert device_store.device_budget_bytes(fraction=0.5) == 400
 
 
 def test_resolve_placement_verdict_is_collective(monkeypatch, caplog):

@@ -133,7 +133,7 @@ def test_shard_map_sync_equals_full_batch(rng):
     """pmean-synced per-device BN == BN over the concatenated batch — the
     SyncBatchNorm semantic (reference main_supcon.py:223-224) mesh-natively."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from simclr_pytorch_distributed_tpu.compat import shard_map
+    from jax import shard_map
 
     devices = jax.devices()
     assert len(devices) == 8, "conftest must fake 8 CPU devices"
@@ -166,7 +166,7 @@ def test_shard_map_sync_equals_full_batch(rng):
 def test_unsynced_bn_uses_local_stats(rng):
     """sync=False reproduces the reference's non---syncBN per-device BN."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from simclr_pytorch_distributed_tpu.compat import shard_map
+    from jax import shard_map
 
     x = rng.normal(loc=0.0, scale=1.0, size=(16, 2, 2, 4)).astype(np.float32)
     # make shards statistically distinct

@@ -886,25 +886,31 @@ def test_resolve_conv_impl_ladder(monkeypatch):
     # explicit xla: honored anywhere
     impl, reason = supcon.resolve_conv_impl("xla", "resnet18", 256, 32, 1)
     assert impl == "xla" and "explicit" in reason
-    # auto on CPU: degrades with the backend named
+    # auto on CPU: xla, and the reason points at the open chip question
     impl, reason = supcon.resolve_conv_impl("auto", "resnet18", 256, 32, 1)
-    assert impl == "xla" and "non-TPU" in reason
-    # auto on TPU single chip: pallas, reason names the fused sites and
-    # the compute dtype
+    assert impl == "xla" and "ROADMAP A1" in reason
+    # auto on a TPU single chip: STILL xla — Mosaic refuses several of the
+    # kernels the supports_* gates admit at this geometry
+    # (tests/test_tpu_aot_compile.py), so no default run selects them
     monkeypatch.setattr(supcon.jax, "default_backend", lambda: "tpu")
     impl, reason = supcon.resolve_conv_impl("auto", "resnet18", 256, 32, 1)
-    assert impl == "pallas"
-    assert "layer1_block0" in reason and "stem" in reason
-    assert "fp32" in reason
-    # auto multi-device: xla with the mesh named
+    assert impl == "xla"
+    assert "ROADMAP A1" in reason and "not yet shown" in reason
+    # auto multi-device: xla as well
     impl, reason = supcon.resolve_conv_impl("auto", "resnet18", 256, 32, 8)
-    assert impl == "xla" and "multi-device" in reason
-    # auto + bf16: pallas since round 19 (the bf16 kernel variants), with
-    # the dtype on record and the wider bf16 admission visible
+    assert impl == "xla"
+    # auto + bf16: xla too (the bf16 stem's backward is refused)
     impl, reason = supcon.resolve_conv_impl(
         "auto", "resnet18", 256, 32, 1, bf16=True
     )
-    assert impl == "pallas" and "bf16" in reason
+    assert impl == "xla"
+    # explicit pallas on TPU names the compiled mode and the admitted sites
+    impl, reason = supcon.resolve_conv_impl("pallas", "resnet18", 256, 32, 1)
+    assert impl == "pallas" and "compiled" in reason
+    assert "layer1_block0" in reason and "stem" in reason and "fp32" in reason
+    impl, reason = supcon.resolve_conv_impl(
+        "pallas", "resnet18", 256, 32, 1, bf16=True
+    )
     assert "layer3_block0" in reason  # bf16-only site (half the VMEM)
     # explicit pallas + bf16: honored, the round-15 raise inverted
     impl, reason = supcon.resolve_conv_impl(
@@ -921,6 +927,26 @@ def test_resolve_conv_impl_ladder(monkeypatch):
         # a geometry with zero admitted sites still raises, naming the
         # dtype it resolved under
         supcon.resolve_conv_impl("pallas", "resnet18", 2, 2, 1)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("model,n_devices,bf16", [
+    ("resnet50", 1, False),  # run_supcon.sh on one chip
+    ("resnet50", 1, True),
+    ("resnet50", 4, False),  # ... and on the four-chip mesh
+    ("resnet18", 1, True),
+])
+def test_resolve_conv_impl_auto_is_xla(monkeypatch, backend, model,
+                                        n_devices, bf16):
+    """``auto`` never selects a conv kernel on any backend, mesh or dtype
+    until a chip cell shows one compiling and winning (ROADMAP A1)."""
+    from simclr_pytorch_distributed_tpu.train import supcon
+
+    monkeypatch.setattr(supcon.jax, "default_backend", lambda: backend)
+    impl, reason = supcon.resolve_conv_impl(
+        "auto", model, 256, 32, n_devices, bf16=bf16
+    )
+    assert impl == "xla" and "ROADMAP A1" in reason
 
 
 def test_conv_fused_sites_geometry_walk():

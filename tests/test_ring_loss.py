@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from simclr_pytorch_distributed_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from simclr_pytorch_distributed_tpu.ops.losses import supcon_loss

@@ -151,7 +151,7 @@ def test_fused_loss_matches_reference(ref_losses, temperature, use_labels):
 @pytest.mark.parametrize("use_labels", [False, True])
 def test_ring_loss_matches_reference(ref_losses, use_labels):
     """The ring-sharded loss on the 8-device mesh against the torch oracle."""
-    from simclr_pytorch_distributed_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from simclr_pytorch_distributed_tpu.parallel.collectives import (
@@ -196,7 +196,7 @@ def test_fused_sharded_loss_matches_reference(ref_losses, use_labels):
     """The shard_map-sharded Pallas kernel (8-device mesh, interpret mode)
     DIRECTLY against the torch oracle — the fourth engine gets the same
     golden treatment as dense/fused/ring, not just sharded==dense."""
-    from simclr_pytorch_distributed_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from simclr_pytorch_distributed_tpu.ops.pallas_loss import (
