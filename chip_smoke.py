@@ -11,8 +11,9 @@ fp32, SyncBN), on synthetic data made from the seed:
 It needs a TPU: without one it exits non-zero before it imports a trainer.
 Any phase that raises ends the script at once. The last line of stdout is the
 result, ``{"ok": true, "device": {...}}``; the lines before it are free-form
-facts about the run (versions, cache dir, per-phase wall/compile seconds, the
-driver's own BT, peak device memory).
+facts about the run (versions, cache dir, per-phase wall seconds and peak
+device memory, the compile seconds that the pretrain's and the probe's own
+flight recorders counted, the driver's own BT).
 
 ``--rehearse`` shrinks the sizes so the control flow can be walked on the CPU
 (add ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` for ``--chips
@@ -54,8 +55,6 @@ REHEARSAL_FLAGS = [
     "--model", "resnet18", "--size", "8",
 ]
 
-_COMPILE = {"backend_s": 0.0, "programs": 0, "cache_hits": 0}
-
 
 def say(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
@@ -66,37 +65,32 @@ def check(cond: bool, what: str) -> None:
         raise SystemExit(f"chip_smoke FAILED: {what}")
 
 
-def _watch_compiles() -> None:
-    def on_duration(event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            _COMPILE["backend_s"] += duration
-            _COMPILE["programs"] += 1
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            _COMPILE["cache_hits"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-
-
 @contextlib.contextmanager
 def phase(name: str):
-    """Wall and compile seconds of one phase; compile = time inside XLA's
-    backend compile *or* its persistent-cache read, summed over programs."""
-    before = dict(_COMPILE)
+    """Wall seconds of one phase and the devices' peak memory after it."""
     t0 = time.perf_counter()
     yield
     wall = time.perf_counter() - t0
     peaks = [d.memory_stats()["peak_bytes_in_use"] if d.platform == "tpu"
              else 0 for d in jax.devices()]
-    say(
-        f"phase {name}: wall {wall:.2f}s, compile "
-        f"{_COMPILE['backend_s'] - before['backend_s']:.2f}s over "
-        f"{_COMPILE['programs'] - before['programs']} programs "
-        f"({_COMPILE['cache_hits'] - before['cache_hits']} cache hits), "
-        f"peak_bytes_in_use per device {peaks}"
-    )
+    say(f"phase {name}: wall {wall:.2f}s, peak_bytes_in_use per device {peaks}")
+
+
+def compile_record(name: str, run: str) -> None:
+    """What a driver's own flight recorder saw compile (track ``compile``,
+    utils/tracing.forward_compile_events): seconds inside XLA's backend
+    compile *or* its persistent-cache read, summed over programs."""
+    from simclr_pytorch_distributed_tpu.utils import tracing
+
+    events = [e for e in tracing.load_events_jsonl(os.path.join(run, "events.jsonl"))
+              if e.get("track") == tracing.COMPILE_TRACK]
+    compiles = [e["args"] for e in events if e["name"] == "backend_compile"]
+    check(compiles, f"{name}: <run>/events.jsonl records no backend_compile")
+    slowest = max(compiles, key=lambda a: a["duration_s"])
+    say(f"phase {name}: compile {sum(a['duration_s'] for a in compiles):.2f}s "
+        f"over {len(compiles)} programs "
+        f"({sum(e['name'] == 'cache_hit' for e in events)} cache hits), "
+        f"slowest {slowest.get('fun_name', '?')} {slowest['duration_s']:.2f}s")
 
 
 def _drop_file_log_handlers() -> None:
@@ -194,6 +188,7 @@ def pretrain(out: str, flags: list, n_devices: int) -> str:
     check(os.path.isdir(os.path.join(run, "last")), "<run>/last missing")
     check(os.path.isfile(os.path.join(run, "events.jsonl")),
           "<run>/events.jsonl missing")
+    compile_record("pretrain", run)
     return run
 
 
@@ -213,6 +208,7 @@ def probe(out: str, run: str, model_flags: list) -> None:
                                  recursive=True)
             if os.path.dirname(p) != run]
     check(len(logs) == 1, f"expected one probe log-ing, found {logs}")
+    compile_record("probe", os.path.dirname(logs[0]))
     with open(logs[0]) as f:
         log = f.read()
     losses = [float(v) for v in
@@ -384,7 +380,6 @@ def main() -> None:
     from simclr_pytorch_distributed_tpu.native.build import load as load_native
     from simclr_pytorch_distributed_tpu.train.supcon import enable_compile_cache
 
-    _watch_compiles()
     versions()
     say(f"compile cache dir: {enable_compile_cache()}")
     say(f"native gather library: "

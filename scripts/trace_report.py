@@ -8,6 +8,12 @@ their span durations partitions the run's measured wall clock exactly:
     wall = compile + data + flush + checkpoint + collective + ...
            + steady_state (the remainder: the dispatch-only hot loop)
 
+The remainder is split once more, by the counters the hot loop keeps without
+recording (each ``flush_boundary`` span carries its window's ``dispatch_s``):
+``dispatch`` is the time inside the update calls, mostly the host waiting for
+room in the device's queue, and ``rest`` is everything else no span covers.
+The epoch-end ``drain_wait`` spans are on ``main:flush`` and count there.
+
 This script reads the jsonl, builds that attribution table with anomaly
 flags (compile-dominated runs, flush-heavy windows, data stalls, recorded
 stall/rollback/preemption events), prints it, and writes a JSON artifact —
@@ -141,6 +147,11 @@ def build_report(events):
 
     attributed = sum(p["seconds"] for p in phases.values())
     steady = wall - attributed
+    boundaries = [
+        e["args"] for e in tracks.get(MAIN_TRACK_PREFIX + "flush", ())
+        if "dispatch_s" in e.get("args", {})
+    ]
+    dispatch = sum(a["dispatch_s"] for a in boundaries)
     for name, p in phases.items():
         p["share"] = round(p["seconds"] / wall, 4) if wall > 0 else 0.0
 
@@ -167,6 +178,13 @@ def build_report(events):
         "steady_state": {
             "seconds": round(steady, 6),
             "share": round(steady / wall, 4) if wall > 0 else 0.0,
+            # inside the update calls (counters, not spans) / the rest
+            "dispatch_s": round(dispatch, 6),
+            "rest_s": round(steady - dispatch, 6),
+            # the longest single update call: one blocked call (a recompile,
+            # a stalled device) hides in a window's sum, not in its max
+            "dispatch_max_ms": round(1e3 * max(
+                (a["dispatch_max_s"] for a in boundaries), default=0.0), 3),
         },
         "anomalies": anomalies,
         "consistency": {
@@ -198,6 +216,13 @@ def render_table(report):
         "steady_state", f"{ss['seconds']:.3f}", f"{ss['share']:.1%}",
         "-", "-", "-",
     ))
+    wall = report["consistency"]["wall_s"]
+    for name, key, max_ms in (
+        ("  dispatch", "dispatch_s", f"{ss['dispatch_max_ms']:.1f}"),
+        ("  rest", "rest_s", "-"),
+    ):
+        share = ss[key] / wall if wall > 0 else 0.0
+        rows.append((name, f"{ss[key]:.3f}", f"{share:.1%}", "-", "-", max_ms))
     rows.append((
         "wall", f"{report['consistency']['wall_s']:.3f}", "100.0%",
         "-", "-", "-",

@@ -69,6 +69,9 @@ from simclr_pytorch_distributed_tpu.train.supcon_step import (
     HEALTH_METRIC_KEYS,
     METRIC_KEYS,
     ONLINE_PROBE_METRIC_KEYS,
+    SCOPE_AUG,
+    SCOPE_DATA,
+    SCOPE_RING,
     SupConStepConfig,
     build_online_probe,
     epoch_position,
@@ -85,6 +88,7 @@ from simclr_pytorch_distributed_tpu.utils.checkpoint import (
     wait_for_saves,
 )
 from simclr_pytorch_distributed_tpu.utils import preempt
+from simclr_pytorch_distributed_tpu.utils import profiling
 from simclr_pytorch_distributed_tpu.utils import tracing
 from simclr_pytorch_distributed_tpu.utils.obs import RunObservability
 from simclr_pytorch_distributed_tpu.utils.guard import (
@@ -396,15 +400,19 @@ def make_fused_update(
         data_sh = (batch_sharding(mesh, 4), batch_sharding(mesh, 1))
 
     def core(state: TrainState, images_arg, labels_arg, base_key):
-        if resident:
-            pos = epoch_position(state.step, step_cfg.steps_per_epoch)
-            if window_batches is not None:
-                pos = pos % window_batches
-            images_u8, labels = slice_epoch_step(images_arg, labels_arg, pos)
-        else:
-            images_u8, labels = images_arg, labels_arg
-        key = jax.random.fold_in(base_key, state.step)
-        views = two_crop_batch(key, images_u8, aug_cfg)
+        with jax.named_scope(SCOPE_DATA):
+            if resident:
+                pos = epoch_position(state.step, step_cfg.steps_per_epoch)
+                if window_batches is not None:
+                    pos = pos % window_batches
+                images_u8, labels = slice_epoch_step(
+                    images_arg, labels_arg, pos
+                )
+            else:
+                images_u8, labels = images_arg, labels_arg
+            key = jax.random.fold_in(base_key, state.step)
+        with jax.named_scope(SCOPE_AUG):
+            views = two_crop_batch(key, images_u8, aug_cfg)
         return train_step(state, views, labels)
 
     if metric_ring is None:
@@ -417,14 +425,19 @@ def make_fused_update(
 
     def ring_update(state: TrainState, ring, images_arg, labels_arg, base_key):
         new_state, metrics = core(state, images_arg, labels_arg, base_key)
-        return new_state, metric_ring.write(ring, metrics, state.step)
+        with jax.named_scope(SCOPE_RING):
+            return new_state, metric_ring.write(ring, metrics, state.step)
 
-    return jax.jit(
+    update = jax.jit(
         ring_update,
         in_shardings=(state_sh, repl, *data_sh, repl),
         out_shardings=(state_sh, repl),
         donate_argnums=(0, 1),
     )
+    # the driver loop's program, findable by whoever reads a profile
+    # (StepTracer, the benchmark's readers): nothing is lowered here
+    profiling.register_step_program(ring_update.__name__, update)
+    return update
 
 
 TB_ITER_SCALARS = (  # reference per-iter scalars, main_supcon.py:327-333
@@ -511,6 +524,12 @@ def train_one_epoch(
     bsz = cfg.batch_size
     telemetry.start_window_clock()
     ring_buf = telemetry.init_buffer(replicated_sharding(mesh))
+    # host seconds inside the window's update_fn(...) calls: [sum, min, max].
+    # Two clock reads a step into this local; the boundary's flush_boundary
+    # span takes it as attributes — still no record between boundaries.
+    # Dispatch is asynchronous, so past the enqueue cost (the min) this is
+    # back-pressure: the host waiting for room in the device's queue.
+    dispatch = [0.0, math.inf, 0.0]
 
     def submit_window(boundary_idx, step_hint):
         """One ``flush_boundary`` (utils/telemetry.py: meter the window on
@@ -562,8 +581,11 @@ def train_one_epoch(
                 last_host["norm_var"],
             )
 
+        timed = dispatch[1] < math.inf  # the window timed a step
         telemetry.flush_boundary(ring_buf, consume, batch_meter=batch_time,
-                                 step_hint=step_hint)
+                                 step_hint=step_hint,
+                                 dispatch=tuple(dispatch) if timed else None)
+        dispatch[:] = [0.0, math.inf, 0.0]
 
     def epoch_loss_avg():
         return losses.avg if losses.count else last_host.get("loss", 0.0)
@@ -589,26 +611,39 @@ def train_one_epoch(
             # and main:* phase spans never nest across tracks). Every later
             # step takes the nullcontext arm: no span records in the hot
             # loop.
+            compiling = compile_span and idx == start_step
             span = (
                 tracing.span("first_step", track="main:compile",
                              step=global_step)
-                if compile_span and idx == start_step
-                else contextlib.nullcontext()
+                if compiling else contextlib.nullcontext()
             )
             # per-step key = fold_in(base_key, state.step) INSIDE the program
             # (state.step == global_step); see make_fused_update
             if batches is None:
-                epoch_images, epoch_labels = store.batch_buffers(epoch, idx)
-                with span:
-                    state, ring_buf = update_fn(
-                        state, ring_buf, epoch_images, epoch_labels, base_key
-                    )
+                data_args = store.batch_buffers(epoch, idx)
             else:
-                batch = shard_host_batch((images_u8, labels), mesh)
-                with span:
-                    state, ring_buf = update_fn(
-                        state, ring_buf, batch[0], batch[1], base_key
-                    )
+                data_args = shard_host_batch((images_u8, labels), mesh)
+            t_dispatch = time.perf_counter()
+            with span:
+                state, ring_buf = update_fn(
+                    state, ring_buf, data_args[0], data_args[1], base_key
+                )
+            if compiling:
+                # once a run: what every LATER call looks like in the
+                # abstract (the state and ring this call returned, committed
+                # to the program's own shardings), so that a profile's reader
+                # can ask for the text of the program the steady steps run.
+                # Not this call's own arguments: a fresh state is
+                # uncommitted, and run() compiles the update a second time
+                # for the committed one
+                profiling.note_step_signature(
+                    (state, ring_buf, data_args[0], data_args[1], base_key)
+                )
+            else:  # the compiling call is main:compile's
+                took = time.perf_counter() - t_dispatch
+                dispatch[0] += took
+                dispatch[1] = min(dispatch[1], took)
+                dispatch[2] = max(dispatch[2], took)
             telemetry.append((idx, global_step), global_step)
             if tracer is not None:
                 tracer.step(global_step)
@@ -653,11 +688,23 @@ def train_one_epoch(
 def enable_compile_cache() -> str:
     """Persistent XLA compile cache, placed from outside: returns its dir.
 
-    ``JAX_COMPILATION_CACHE_DIR`` set -> jax has already read it; nothing is
-    set in code. Unset -> ``<checkout>/.jax_cache``, one fixed path for the
+    ``JAX_COMPILATION_CACHE_DIR`` set -> jax has already read it; no place
+    is set in code. Unset -> ``<checkout>/.jax_cache``, one fixed path for the
     trainers, the server, bench.py and chip_smoke.py (a dir that moves
     with ``--workdir`` never hits).
     """
+    # Scopes and module paths are metadata, which the cache's key leaves out
+    # by default: an executable cached by a build with OTHER scopes (or none)
+    # would be loaded with its stale op_names, and the per-scope reduction of
+    # a profile (benchmark/scope_reduce.py) would read them. With metadata
+    # in the key a hit is always this build's own names. Metadata is also
+    # each instruction's source file and line, which would make every line
+    # shift in a file the step is traced through a cold compile: with no
+    # traceback frames in the locations the metadata is the op_name alone.
+    # The price: compiled programs name no source line (xprof's source
+    # view, XLA's own error messages).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir
@@ -887,7 +934,9 @@ def run(cfg: config_lib.SupConConfig) -> TrainState:
             # `state` object is DELETED after the first step — an un-donated
             # on-device copy (one HBM->HBM copy per epoch) is what the crash
             # handler can still save.
-            backup = copy_state(state) if cfg.nan_guard else None
+            with tracing.span("epoch_backup", track="main:checkpoint",
+                              epoch=epoch):
+                backup = copy_state(state) if cfg.nan_guard else None
             obs.set_epoch(epoch)
             try:
                 with tracing.span("epoch", track="main:epoch", epoch=epoch):
@@ -958,14 +1007,19 @@ def run(cfg: config_lib.SupConConfig) -> TrainState:
                     step_in_epoch=preempted_at, extra_meta=policy_meta(),
                 )
             t2 = time.time()
-            logging.info("epoch %d, total time %.2f", epoch, t2 - t1)
-            if is_main_process():
-                tb.log_value("loss", loss_avg, epoch)
-                tb.log_value(
-                    "learning_rate",
-                    float(schedule((epoch - 1) * steps_per_epoch)) * policy.lr_scale,
-                    epoch,
-                )
+            # the schedule is evaluated eagerly here (a device round trip on
+            # a queue the drain has just emptied): its own phase, so the
+            # epoch-edge idle gap is not put down to "no span open"
+            with tracing.span("epoch_log", track="main:log", epoch=epoch):
+                logging.info("epoch %d, total time %.2f", epoch, t2 - t1)
+                if is_main_process():
+                    tb.log_value("loss", loss_avg, epoch)
+                    tb.log_value(
+                        "learning_rate",
+                        float(schedule((epoch - 1) * steps_per_epoch))
+                        * policy.lr_scale,
+                        epoch,
+                    )
             if epoch % cfg.save_freq == 0:
                 # collective on all processes (see crash handler note); async
                 # write: D2H serialization is synchronous (safe with buffer
@@ -1013,6 +1067,9 @@ def run(cfg: config_lib.SupConConfig) -> TrainState:
         if store is not None:
             store.close()
         tracer.close()
+        # the registry is process-wide: a later run of this process (the
+        # probe after a pretrain) must not find this run's program
+        profiling.clear_step_program()
         tb.close()
         wait_for_saves()
         # observability teardown LAST (after the final wait_for_saves so

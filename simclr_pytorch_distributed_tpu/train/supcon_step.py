@@ -60,6 +60,19 @@ from simclr_pytorch_distributed_tpu.parallel.mesh import (
 from simclr_pytorch_distributed_tpu.train.state import TrainState
 
 
+# Named scopes of the compiled step: what flax's module paths do not name.
+# Every instruction of the program's text then carries one of these (or a
+# module path: ``encoder/conv1``, ``encoder/layer2_block1``, ``proj_head``)
+# in its ``op_name``, and ``benchmark/scope_reduce.py`` buckets device time
+# by it. Metrics are keyed on these strings: rename one and its metric falls
+# silent.
+SCOPE_DATA = "data"            # resident slice + per-step key fold_in
+SCOPE_AUG = "aug"              # two_crop_batch
+SCOPE_LOSS = "loss"            # norms, normalize, contrastive / recipe loss
+SCOPE_OPTIMIZER = "optimizer"  # tx.update + apply_updates (+ recipe's own)
+SCOPE_RING = "ring"            # metric arithmetic, health, the ring write
+STEP_SCOPES = (SCOPE_DATA, SCOPE_AUG, SCOPE_LOSS, SCOPE_OPTIMIZER, SCOPE_RING)
+
 # The step's base metric-dict key set (aux + learning_rate), sorted — the
 # column order of the device-side metric ring (ops/metrics.MetricRing): the
 # jitted writer and the host reader both derive columns from this one tuple,
@@ -293,7 +306,10 @@ def two_view_forward(
     2-tuple.
     """
     B = images.shape[0]
-    flat = jnp.transpose(images, (1, 0, 2, 3, 4)).reshape((2 * B,) + images.shape[2:])
+    with jax.named_scope(SCOPE_AUG):  # the views' layout, not the encoder's time
+        flat = jnp.transpose(images, (1, 0, 2, 3, 4)).reshape(
+            (2 * B,) + images.shape[2:]
+        )
     method = type(model).forward_with_features if with_features else None
     if train:
         feats, mutated = model.apply(
@@ -460,54 +476,61 @@ def make_train_step(
             feats, new_batch_stats = two_view_forward(
                 model, params, state.batch_stats, images, train=True
             )
-        feats = feats.astype(jnp.float32)
+        # everything between the head's output and the scalar loss is the
+        # loss's device time: norms, the normalize, the contrastive (or the
+        # recipe's) term with its custom-VJP backward, the aux ramps and
+        # the DDP gradient scale
+        with jax.named_scope(SCOPE_LOSS):
+            feats = feats.astype(jnp.float32)
 
-        # feature-norm statistics on UNNORMALIZED embeddings (main_supcon.py:298-301)
-        norms = jnp.linalg.norm(feats, axis=1)
-        norm_mean = jnp.mean(norms)
-        norm_var = jnp.mean(jnp.square(norms - norm_mean))
+            # feature-norm statistics on UNNORMALIZED embeddings (main_supcon.py:298-301)
+            norms = jnp.linalg.norm(feats, axis=1)
+            norm_mean = jnp.mean(norms)
+            norm_var = jnp.mean(jnp.square(norms - norm_mean))
 
-        # SEC EMA: update-then-use, seeded with the first batch's mean
-        # (main_supcon.py:304-307; momentum 1.0 degenerates to the batch mean)
-        norm_mean_sg = jax.lax.stop_gradient(norm_mean)
-        record = jnp.where(
-            state.step == 0,
-            norm_mean_sg,
-            (1.0 - cfg.norm_momentum) * state.record_norm_mean
-            + cfg.norm_momentum * norm_mean_sg,
-        )
-        loss_sec = jnp.mean(jnp.square(norms - record))
-        loss_l2reg = jnp.mean(jnp.square(norms))
+            # SEC EMA: update-then-use, seeded with the first batch's mean
+            # (main_supcon.py:304-307; momentum 1.0 degenerates to the batch mean)
+            norm_mean_sg = jax.lax.stop_gradient(norm_mean)
+            record = jnp.where(
+                state.step == 0,
+                norm_mean_sg,
+                (1.0 - cfg.norm_momentum) * state.record_norm_mean
+                + cfg.norm_momentum * norm_mean_sg,
+            )
+            loss_sec = jnp.mean(jnp.square(norms - record))
+            loss_l2reg = jnp.mean(jnp.square(norms))
 
-        # normalize AFTER the (logical) gather (main_supcon.py:283)
-        n_fea = feats / jnp.linalg.norm(feats, axis=1, keepdims=True)
+            # normalize AFTER the (logical) gather (main_supcon.py:283)
+            n_fea = feats / jnp.linalg.norm(feats, axis=1, keepdims=True)
 
-        recipe_aux = {}
-        if recipe is None:
-            # the pre-recipe inline path (bitwise control arm; bench/dryruns)
-            if cfg.method not in ("SupCon", "SimCLR"):
-                raise ValueError(
-                    f"contrastive method not supported: {cfg.method}"
+            recipe_aux = {}
+            if recipe is None:
+                # the pre-recipe inline path (bitwise control arm; bench/dryruns)
+                if cfg.method not in ("SupCon", "SimCLR"):
+                    raise ValueError(
+                        f"contrastive method not supported: {cfg.method}"
+                    )
+                loss_labels = labels if cfg.method == "SupCon" else None
+                contrastive = contrastive_loss_terms(
+                    cfg, mesh, fused_on_mesh, n_fea, loss_labels
                 )
-            loss_labels = labels if cfg.method == "SupCon" else None
-            contrastive = contrastive_loss_terms(
-                cfg, mesh, fused_on_mesh, n_fea, loss_labels
-            )
-        else:
-            ctx = RecipeContext(
-                model=model, params=params, batch_stats=state.batch_stats,
-                images=images, labels=labels, feats=feats, n_fea=n_fea,
-                recipe_params=recipe_params, recipe_state=state.recipe_state,
-            )
-            contrastive, recipe_aux = recipe.loss(cfg, mesh, fused_on_mesh, ctx)
+            else:
+                ctx = RecipeContext(
+                    model=model, params=params, batch_stats=state.batch_stats,
+                    images=images, labels=labels, feats=feats, n_fea=n_fea,
+                    recipe_params=recipe_params, recipe_state=state.recipe_state,
+                )
+                contrastive, recipe_aux = recipe.loss(cfg, mesh, fused_on_mesh, ctx)
 
-        # linear-ramped aux terms (main_supcon.py:311-317)
-        ramp = state.step / (cfg.epochs * cfg.steps_per_epoch)
-        loss = contrastive
-        if cfg.sec:
-            loss = loss + cfg.sec_wei * ramp * loss_sec
-        if cfg.l2reg:
-            loss = loss + cfg.l2reg_wei * ramp * loss_l2reg
+            # linear-ramped aux terms (main_supcon.py:311-317)
+            ramp = state.step / (cfg.epochs * cfg.steps_per_epoch)
+            loss = contrastive
+            if cfg.sec:
+                loss = loss + cfg.sec_wei * ramp * loss_sec
+            if cfg.l2reg:
+                loss = loss + cfg.l2reg_wei * ramp * loss_l2reg
+            # grad-scale fidelity: DDP means over ngpu ranks (module docstring)
+            scaled_loss = loss / cfg.grad_div
 
         aux = {
             "loss": loss,  # the reported (unscaled) loss, main_supcon.py:320
@@ -527,8 +550,7 @@ def make_train_step(
             aux["embeddings"] = jax.lax.stop_gradient(n_fea)
         if probe is not None:
             aux["probe_feats"] = probe_feats
-        # grad-scale fidelity: DDP means over ngpu ranks (module docstring)
-        return loss / cfg.grad_div, (aux, new_batch_stats)
+        return scaled_loss, (aux, new_batch_stats)
 
     def probe_update(state: TrainState, probe_feats, labels):
         """One detached classifier step on the stop_gradient encoder
@@ -569,23 +591,30 @@ def make_train_step(
                 None if recipe is None else state.recipe_params,
                 state, images, labels,
             )
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        metrics = dict(aux, learning_rate=jnp.asarray(schedule(state.step)))
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(SCOPE_RING):
+            metrics = dict(
+                aux, learning_rate=jnp.asarray(schedule(state.step))
+            )
         metrics.pop("embeddings", None)
         metrics.pop("probe_feats", None)
         metrics.pop("recipe_embeddings", None)
         replace_kwargs = {}
         if recipe_trainable:
-            rupdates, new_ropt = recipe.tx.update(
-                rgrads, state.recipe_opt_state, state.recipe_params
-            )
-            replace_kwargs.update(
-                recipe_params=optax.apply_updates(
-                    state.recipe_params, rupdates
-                ),
-                recipe_opt_state=new_ropt,
-            )
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                rupdates, new_ropt = recipe.tx.update(
+                    rgrads, state.recipe_opt_state, state.recipe_params
+                )
+                replace_kwargs.update(
+                    recipe_params=optax.apply_updates(
+                        state.recipe_params, rupdates
+                    ),
+                    recipe_opt_state=new_ropt,
+                )
         if recipe is not None and state.recipe_state is not None:
             # the recipe's post-step state transition (BYOL EMA toward the
             # freshly updated online params; queue rotation with the batch's
@@ -597,15 +626,16 @@ def make_train_step(
             # lax.cond, not where: the false branch must SKIP the O((2B)^2)
             # similarity matmul and the d x d eigendecomposition at runtime,
             # not just mask their results — non-health steps pay nothing
-            metrics.update(jax.lax.cond(
-                state.step % cfg.health_freq == 0,
-                lambda ops: contrastive_health_metrics(*ops),
-                lambda ops: {
-                    k: jnp.full((), jnp.nan, jnp.float32)
-                    for k in HEALTH_METRIC_KEYS
-                },
-                (aux["embeddings"], grads),
-            ))
+            with jax.named_scope(SCOPE_RING):
+                metrics.update(jax.lax.cond(
+                    state.step % cfg.health_freq == 0,
+                    lambda ops: contrastive_health_metrics(*ops),
+                    lambda ops: {
+                        k: jnp.full((), jnp.nan, jnp.float32)
+                        for k in HEALTH_METRIC_KEYS
+                    },
+                    (aux["embeddings"], grads),
+                ))
         if probe is not None:
             new_pparams, new_popt, pmetrics = probe_update(
                 state, aux["probe_feats"], labels

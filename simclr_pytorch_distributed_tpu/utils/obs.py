@@ -35,8 +35,10 @@ class RunObservability:
     """Build (and later tear down, in the right order) the per-run
     observability stack from a trainer config:
 
-    - ``recorder`` — installed as the module-level tracing recorder;
-      ``None`` under ``--flight_recorder off``;
+    - ``recorder`` — installed as the module-level tracing recorder, and
+      fed one ``backend_compile`` / ``cache_hit`` event per compile
+      (``tracing.forward_compile_events``); ``None`` under
+      ``--flight_recorder off``;
     - ``watchdog`` — a started :class:`tracing.StallWatchdog` beating on
       the flush boundary (via ``TelemetrySession``); ``None`` unless
       ``--watchdog_secs > 0``;
@@ -53,6 +55,10 @@ class RunObservability:
             cfg.save_folder, enabled=(cfg.flight_recorder != "off")
         )
         tracing.install(self.recorder)
+        if self.recorder is not None:
+            # the run's compile record (track "compile"): listeners forward
+            # to whichever recorder is installed when a compile happens
+            tracing.forward_compile_events()
         self.watchdog = None
         if cfg.watchdog_secs > 0:
             self.watchdog = tracing.StallWatchdog(

@@ -266,7 +266,14 @@ class TelemetrySession:
         collective save while its peers enter it deadlocks the job.
         Single-process this is ``drain()`` with the failure-type contract
         of :meth:`check_failures_global` applied."""
-        self.executor.wait_idle()
+        # the wait is for the last window's D2H, and so for every step the
+        # device still has in flight: at an epoch end it is where the main
+        # thread spends about (steps in flight) x (step time), and where the
+        # device's queue runs empty. Its own span on main:flush, around the
+        # wait ONLY: check_failures_global opens main:collective, and main:*
+        # tracks never nest.
+        with tracing.span("drain_wait", track="main:flush", step=step_hint):
+            self.executor.wait_idle()
         self.check_failures_global(step_hint)
         if self._watchdog is not None:
             # a completed drain is progress: the epoch-end save that often
@@ -287,6 +294,7 @@ class TelemetrySession:
         consume: Callable,
         batch_meter=None,
         step_hint: int = 0,
+        dispatch=None,
     ) -> None:
         """The drivers' shared ``print_freq``-boundary protocol, in order:
 
@@ -316,7 +324,19 @@ class TelemetrySession:
         the main thread: the async job runs while later boundaries keep
         mutating the meter, so a worker-side read would print window k+1's
         (possibly torn) numbers against window k's log line.
+
+        ``dispatch`` (``(sum, min, max)`` seconds, optional) is what the
+        driver's hot loop accumulated around the closing window's update
+        calls; it rides this boundary's span as ``dispatch_s`` /
+        ``dispatch_min_s`` / ``dispatch_max_s`` so the hot loop itself
+        records nothing.
         """
+        attrs = {}
+        if dispatch is not None:
+            attrs = dict(zip(
+                ("dispatch_s", "dispatch_min_s", "dispatch_max_s"),
+                (round(d, 9) for d in dispatch),
+            ))
         # span covers the main-thread boundary work (meter + snapshot +
         # queue) but NOT the collective failure observation below — that
         # records on its own main:collective track, and main:* phase tracks
@@ -324,7 +344,7 @@ class TelemetrySession:
         # invariant, utils/tracing.py)
         with tracing.span(
             "flush_boundary", track="main:flush", step=step_hint,
-            steps=self.pending_count(),
+            steps=self.pending_count(), **attrs,
         ):
             if batch_meter is not None:
                 n_pending = self.pending_count()

@@ -454,6 +454,46 @@ def record_span(name: str, track: str, start: float, end: float, **attrs) -> Non
         rec.record_span(name, track, start, end, **attrs)
 
 
+# ---------------------------------------------------------------- compiles
+# The run's own compile record: one ``backend_compile`` event (track
+# ``compile``) per program the backend compiled or read back from the
+# persistent cache, with its seconds and the jitted function's name, and a
+# ``cache_hit`` event per persistent-cache hit. An operator whose run
+# recompiles (resume on another mesh, a second layout of the update) sees
+# which program and for how long, not one slow step.
+
+COMPILE_TRACK = "compile"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_compile_listeners_on = False
+
+
+def forward_compile_events() -> None:
+    """Register (once a process; jax.monitoring keeps listeners for good)
+    the two listeners that forward to whichever recorder is installed when
+    a compile happens. No recorder, no work."""
+    global _compile_listeners_on
+    if _compile_listeners_on:
+        return
+    import jax  # lazy: this module must stay importable without jax
+
+    def on_duration(name, duration, **kw):
+        rec = _current
+        if rec is not None and name == _BACKEND_COMPILE:
+            # fun_name: this JAX version passes it; an older one passes none
+            rec.event("backend_compile", track=COMPILE_TRACK,
+                      duration_s=round(duration, 6), **kw)
+
+    def on_event(name, **kw):
+        rec = _current
+        if rec is not None and name == _CACHE_HIT:
+            rec.event("cache_hit", track=COMPILE_TRACK, **kw)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    _compile_listeners_on = True
+
+
 # ---------------------------------------------------------------- watchdog
 
 

@@ -54,12 +54,18 @@ def config_updates(monkeypatch):
     return calls
 
 
-def test_compile_cache_env_var_set_means_code_sets_nothing(
+def test_compile_cache_env_var_set_means_code_sets_no_place(
     monkeypatch, config_updates
 ):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
     assert supcon.enable_compile_cache() == "/somewhere/else"
-    assert config_updates == []
+    # nothing about the PLACE; what the key holds is set in either case
+    # (PR 25: op_names and nothing else of the metadata, so that a hit never
+    # hands back another build's scopes and a line shift still hits)
+    assert config_updates == [
+        ("jax_compilation_cache_include_metadata_in_key", True),
+        ("jax_traceback_in_locations_limit", 0),
+    ]
 
 
 def test_compile_cache_defaults_to_checkout(monkeypatch, config_updates):
@@ -67,6 +73,7 @@ def test_compile_cache_defaults_to_checkout(monkeypatch, config_updates):
     want = os.path.join(REPO, ".jax_cache")
     assert supcon.enable_compile_cache() == want
     assert ("jax_compilation_cache_dir", want) in config_updates
+    assert ("jax_compilation_cache_include_metadata_in_key", True) in config_updates
 
 
 def test_compile_cache_dir_does_not_move_with_the_workdir(

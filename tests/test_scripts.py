@@ -2,7 +2,7 @@
 
 The reference ships no CI tooling at all (SURVEY.md §4); this repo's round
 gates (`scripts/ratchet.py`, `scripts/northstar.py`) and PERF.md evidence
-(`scripts/xplane_bw.py`, `scripts/crop_ab.py`, `scripts/_honest_timing.py`)
+(`scripts/crop_ab.py`, `scripts/_honest_timing.py`)
 hang off small parsing/summary functions that until now were only exercised
 by the full chip runs. A silent parse regression there would let a failing
 accuracy gate read as green — worth pinning with fast CPU tests.
@@ -436,50 +436,6 @@ def test_retrieval_ab_smoke_oracle_and_recall(tmp_path):
     top = max(artifact["rungs"], key=lambda r: r["rows"])
     assert top["ivf_stats"]["trained_lists"] > 1  # not the provisional rung
     assert top["speedup_p50"] > 0
-
-
-# -------------------------------------------------------------- xplane_bw
-
-
-def _varint(n):
-    out = b""
-    while True:
-        b7 = n & 0x7F
-        n >>= 7
-        out += bytes([b7 | (0x80 if n else 0)])
-        if not n:
-            return out
-
-
-def test_xplane_parse_breakdown_wire_decode():
-    """_parse_breakdown hand-decodes the repeated MemoryAccessed block
-    (field 1, LEN-delimited) because the wrapper message type is not
-    exported by the installed xprof protos — pin the framing."""
-    op_metrics_pb2 = pytest.importorskip("xprof.protobuf.op_metrics_pb2")
-    xplane_bw = _load("xplane_bw")
-    MA = op_metrics_pb2.OpMetrics.MemoryAccessed
-    hbm = op_metrics_pb2.MemorySpace.Value("MEMORY_SPACE_HBM")
-
-    msgs = [
-        MA(memory_space=hbm, bytes_accessed=12345),
-        MA(memory_space=hbm, bytes_accessed=2**40),
-    ]
-    payloads = [m.SerializeToString() for m in msgs]
-    raw = b"\x0a" + _varint(len(payloads[0])) + payloads[0]
-    # a MemoryAccessed message can never exceed 127 bytes, so force the
-    # multi-byte length-varint continuation path with the (legal)
-    # non-canonical two-byte encoding of the same length
-    ln = len(payloads[1])
-    assert ln < 128
-    raw += b"\x0a" + bytes([(ln & 0x7F) | 0x80, 0x00]) + payloads[1]
-
-    got = xplane_bw._parse_breakdown(raw, MA)
-    assert [g.bytes_accessed for g in got] == [12345, 2**40]
-    assert all(g.memory_space == hbm for g in got)
-
-    # an unknown field tag after the repeated block stops the scan cleanly
-    got2 = xplane_bw._parse_breakdown(raw + b"\x12\x00", MA)
-    assert [g.bytes_accessed for g in got2] == [12345, 2**40]
 
 
 # ----------------------------------------------------------------- flush_ab
@@ -1102,9 +1058,11 @@ def _good_events():
         _span("epoch", "main:epoch", 0.0, 100.0, epoch=1),  # envelope
         _span("first_step", "main:compile", 1.0, 40.0),
         _span("epoch_gather", "main:data", 0.2, 0.5),
-        _span("flush_boundary", "main:flush", 50.0, 2.0),
-        _span("flush_boundary", "main:flush", 60.0, 2.0),
-        _span("flush_boundary", "main:flush", 70.0, 2.0),
+        _span("flush_boundary", "main:flush", 50.0, 2.0, dispatch_s=8.0,
+              dispatch_min_s=0.5, dispatch_max_s=1.25),
+        _span("flush_boundary", "main:flush", 60.0, 2.0, dispatch_s=7.5,
+              dispatch_min_s=0.4, dispatch_max_s=3.5),
+        _span("flush_boundary", "main:flush", 70.0, 2.0),  # nothing timed
         _span("checkpoint_save", "main:checkpoint", 90.0, 5.0),
         _span("flush_job", "telemetry:flush", 50.5, 8.0),  # other thread
         _instant("run_end", "events", 100.0),
@@ -1119,6 +1077,14 @@ def test_trace_report_attribution_partitions_wall(tmp_path):
     # compile 40 + data 0.5 + flush 6 + checkpoint 5 = 51.5 attributed
     assert cons["attributed_s"] == pytest.approx(51.5)
     assert cons["steady_state_s"] == pytest.approx(48.5)
+    # the remainder, split by the boundaries' dispatch counters
+    assert report["steady_state"]["dispatch_s"] == pytest.approx(15.5)
+    assert report["steady_state"]["rest_s"] == pytest.approx(33.0)
+    # the longest single call of the run, in the max_ms column
+    assert report["steady_state"]["dispatch_max_ms"] == pytest.approx(3500.0)
+    row = next(ln for ln in tr.render_table(report).splitlines()
+               if ln.startswith("  dispatch"))
+    assert row.split()[-1] == "3500.0"
     assert cons["monotone_ok"] and cons["nonnegative_ok"] and cons["ok"]
     assert set(report["phases"]) == {"compile", "data", "flush", "checkpoint"}
     assert report["phases"]["flush"]["count"] == 3

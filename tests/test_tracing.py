@@ -498,6 +498,12 @@ def test_recorder_adds_no_device_transfers_in_driver_hot_loop(
     # transfer happened (the ring count above stayed 3)
     assert len([b for b in boundaries if b["args"]["steps"] > 0]) == 3
     assert all(b["args"]["steps"] == 0 for b in boundaries[3:])
+    # what the hot loop accumulated rides the boundary's span (PR 25): the
+    # loop itself still records nothing (tests/test_step_scopes.py)
+    assert all(("dispatch_s" in b["args"]) == (b["args"]["steps"] > 0)
+               for b in boundaries)
+    assert any(e["name"] == "drain_wait" and e["track"] == "main:flush"
+               for e in events)
     assert any(e["name"] == "first_step" for e in events)
     assert any(e["name"] == "epoch_gather" for e in events)
     assert any(e["name"] == "epoch" for e in events)
