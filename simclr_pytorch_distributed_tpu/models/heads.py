@@ -121,6 +121,9 @@ class SupConResNet(nn.Module):
     # BasicBlock kernels where the geometry admits (models/resnet.py,
     # ops/pallas_conv.py); resolve via train.supcon.resolve_conv_impl
     conv_impl: str = "xla"
+    # Bottleneck's tail through ops/pointwise_bwd.py's one backward kernel:
+    # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
+    pointwise_bwd: bool = False
 
     def setup(self):
         model_fn, dim_in = MODEL_DICT[self.model_name]
@@ -129,6 +132,7 @@ class SupConResNet(nn.Module):
             bn_local_groups=self.bn_local_groups,
             bn_group_views=self.bn_group_views,
             remat=self.remat, stem=self.stem, conv_impl=self.conv_impl,
+            pointwise_bwd=self.pointwise_bwd,
         )
         self.proj_head = ProjectionHead(
             head=self.head, dim_in=dim_in, feat_dim=self.feat_dim, dtype=self.dtype

@@ -33,7 +33,11 @@ from simclr_pytorch_distributed_tpu.models.norm import (
     CrossReplicaBatchNorm,
     FusedTrainBN,
 )
-from simclr_pytorch_distributed_tpu.ops import pallas_conv
+from simclr_pytorch_distributed_tpu.ops import pallas_conv, pointwise_bwd
+
+# planes and first-block stride of the four stages
+_STAGE_WIDTHS = (64, 128, 256, 512)
+_STAGE_STRIDES = (1, 2, 2, 2)
 
 # torch nn.init.kaiming_normal_(mode='fan_out', nonlinearity='relu')
 conv_kernel_init = nn.initializers.variance_scaling(2.0, "fan_out", "normal")
@@ -171,6 +175,12 @@ class Bottleneck(nn.Module):
     # geometries supports_bottleneck rejects stay on the bitwise-pinned
     # XLA path below.
     conv_impl: str = "xla"
+    # the tail (relu(bn2) -> Conv_2 -> bn3) through ops/pointwise_bwd.py:
+    # XLA's forward, one backward kernel. The ResNet owner decides
+    # (ResNet.tail_bwd_reason: its attributes and this site's shape); only
+    # the mode is left to check here: train, and a traced (not initializing)
+    # apply.
+    tail_bwd: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = True):  # train is
@@ -232,9 +242,13 @@ class Bottleneck(nn.Module):
         out = conv(
             self.planes, (3, 3), strides=(self.stride, self.stride), padding=PAD3
         )(out)
-        out = nn.relu(norm(name="bn2")(out))
-        out = conv(self.expansion * self.planes, (1, 1), padding="VALID")(out)
-        out = norm(name="bn3")(out)
+        c4 = self.expansion * self.planes
+        if self.tail_bwd and train and not self.is_initializing():
+            out = self._tail_one_backward(out, c4)
+        else:
+            out = nn.relu(norm(name="bn2")(out))
+            out = conv(c4, (1, 1), padding="VALID")(out)
+            out = norm(name="bn3")(out)
 
         shortcut = x
         if self.stride != 1 or x.shape[-1] != self.expansion * self.planes:
@@ -245,6 +259,26 @@ class Bottleneck(nn.Module):
             )(x)
             shortcut = norm(name="shortcut_bn")(shortcut)
         return nn.relu(out + shortcut)
+
+    def _tail_one_backward(self, z, c4: int):
+        """bn2 -> ReLU -> Conv_2 -> bn3 on ``z``, the 3x3 conv's output, with
+        the parameter shadows under the XLA path's names: same trees, same
+        forward, same running-statistic updates."""
+        eps = CrossReplicaBatchNorm.epsilon
+        count = z.shape[0] * z.shape[1] * z.shape[2]
+        bn2 = FusedTrainBN(self.planes, name="bn2")
+        scale2, bias2 = bn2()
+        mean2, var2 = pointwise_bwd.batch_moments(z)
+        bn2(mean2, var2, count)
+        kernel = _ConvKernel((1, 1, self.planes, c4), name="Conv_2")()
+        bn3 = FusedTrainBN(c4, name="bn3")
+        scale3, bias3 = bn3()
+        out, mean3, var3 = pointwise_bwd.expand_conv_bn(
+            z, mean2, jax.lax.rsqrt(var2 + eps), scale2, bias2, kernel,
+            scale3, bias3, eps=eps, interpret=_interpret_pallas(),
+        )
+        bn3(mean3, var3, count)
+        return out
 
 
 class ResNet(nn.Module):
@@ -283,6 +317,38 @@ class ResNet(nn.Module):
     # per-site plan is fused_site_plan below (single-sourced with the
     # resolution banner).
     conv_impl: str = "xla"
+    # Bottleneck's tail through one backward kernel (ops/pointwise_bwd.py).
+    # Set by the owner that knows the mesh holds ONE device and the backend
+    # is a TPU (train.supcon.build); the attributes above and each site's
+    # shape can still say no (tail_bwd_reason).
+    pointwise_bwd: bool = False
+
+    def block_sites(self):
+        """``(name, width, stride)`` of every residual block, in order."""
+        for stage, (n_blocks, width, stride) in enumerate(
+            zip(self.stage_sizes, _STAGE_WIDTHS, _STAGE_STRIDES)
+        ):
+            for block in range(n_blocks):
+                yield (f"layer{stage + 1}_block{block}", width,
+                       stride if block == 0 else 1)
+
+    def tail_bwd_reason(self, rows: int, width: int) -> Optional[str]:
+        """Why the Bottleneck of ``width`` planes keeps its tail on XLA's
+        backward at a batch of ``rows``, or None: the kernel of
+        ops/pointwise_bwd.py is float32 whole-batch BN in one program, at a
+        shape it tiles (the stride does not enter: the tail is pointwise).
+        ``__call__`` and ``tail_bwd_plan`` both ask here."""
+        if self.conv_impl == "pallas":
+            return "--conv_impl pallas takes whole blocks"
+        if self.dtype != jnp.float32:
+            return f"compute dtype {jnp.dtype(self.dtype).name}"
+        if self.axis_name is not None:
+            return f"BN statistics reduced over axis {self.axis_name!r}"
+        if not self.sync_bn and self.bn_local_groups > 1:
+            return f"BN in {self.bn_local_groups} per-device groups"
+        return pointwise_bwd.unsupported(
+            rows, width, self.block_cls.expansion * width
+        )
 
     @nn.compact
     def __call__(self, x: jax.Array, *, train: bool = True) -> jax.Array:
@@ -346,20 +412,14 @@ class ResNet(nn.Module):
             x = nn.relu(norm(use_running_average=not train, name="bn1")(x))
         if self.stem == "s2d":
             x = nn.relu(norm(use_running_average=not train, name="bn1")(x))
-        widths = (64, 128, 256, 512)
-        strides = (1, 2, 2, 2)
-        for stage, (n_blocks, width, stage_stride) in enumerate(
-            zip(self.stage_sizes, widths, strides)
-        ):
-            for block in range(n_blocks):
-                x = block_cls(
-                    planes=width,
-                    stride=stage_stride if block == 0 else 1,
-                    dtype=self.dtype,
-                    norm=norm,
-                    conv_impl=block_conv_impl,
-                    name=f"layer{stage + 1}_block{block}",
-                )(x, train)
+        for name, width, stride in self.block_sites():
+            tail = {}
+            if self.pointwise_bwd and issubclass(self.block_cls, Bottleneck):
+                tail["tail_bwd"] = self.tail_bwd_reason(x.shape[0], width) is None
+            x = block_cls(
+                planes=width, stride=stride, dtype=self.dtype, norm=norm,
+                conv_impl=block_conv_impl, name=name, **tail,
+            )(x, train)
         x = jnp.mean(x, axis=(1, 2))  # global average pool (AdaptiveAvgPool2d((1,1)))
         return x.astype(jnp.float32)
 
@@ -397,6 +457,25 @@ MODEL_DICT: dict[str, Tuple[Callable[..., ResNet], int]] = {
 }
 
 
+def tail_bwd_plan(
+    model: str, rows: int, owner_reason: Optional[str] = None, **encoder_kwargs
+) -> list:
+    """One ``{"name", "reason"}`` per Bottleneck of ``model``: ``reason`` is
+    None where the train step's backward goes through ops/pointwise_bwd.py
+    and otherwise says why it stays XLA's: the owner's (``owner_reason``:
+    mesh size, backend) or the encoder's own ``ResNet.tail_bwd_reason``, which
+    is what its ``__call__`` asks too. ``encoder_kwargs`` are the attributes
+    the encoder is built with; ``rows`` is its batch, both views of the
+    two-crop step. A BasicBlock model has no such site."""
+    mod = MODEL_DICT[model][0](**encoder_kwargs)
+    if not issubclass(mod.block_cls, Bottleneck):
+        return []
+    return [
+        {"name": name, "reason": owner_reason or mod.tail_bwd_reason(rows, width)}
+        for name, width, _ in mod.block_sites()
+    ]
+
+
 def fused_site_plan(
     model: str, rows: int, size: int, dtype: Any = jnp.float32
 ) -> list:
@@ -431,12 +510,10 @@ def fused_site_plan(
         "in_channels": mod.in_channel, "width": 64, "stride": 1,
         "admitted": stem_ok, "desc": f"stem {mod.in_channel}->64@{h}x{w}",
     })
-    widths = (64, 128, 256, 512)
-    stage_strides = (1, 2, 2, 2)
     expansion = mod.block_cls.expansion
     in_c = 64
     for stage, (n_blocks, width, stage_stride) in enumerate(
-        zip(mod.stage_sizes, widths, stage_strides)
+        zip(mod.stage_sizes, _STAGE_WIDTHS, _STAGE_STRIDES)
     ):
         for block in range(n_blocks):
             stride = stage_stride if block == 0 else 1

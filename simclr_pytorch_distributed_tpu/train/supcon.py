@@ -255,6 +255,43 @@ def resolve_conv_impl(
     )
 
 
+def plan_pointwise_bwd(
+    cfg: config_lib.SupConConfig, n_devices: int, **encoder_kwargs
+) -> list:
+    """``models.resnet.tail_bwd_plan`` for this run, said once in a banner
+    line and one ``pointwise_bwd_plan`` event (track ``compile``): how many
+    Bottleneck tails take the one-kernel backward of ops/pointwise_bwd.py,
+    how many stay on XLA's, and why. No flag chooses: one device, a TPU, and
+    what the encoder built with ``encoder_kwargs`` says of itself
+    (``ResNet.tail_bwd_reason``: float32, whole-batch BN, a shape the kernel
+    tiles within its VMEM budget)."""
+    from simclr_pytorch_distributed_tpu.models.resnet import tail_bwd_plan
+
+    owner_reason = None
+    if n_devices > 1:
+        owner_reason = f"{n_devices} devices in the mesh"
+    elif jax.default_backend() != "tpu":
+        owner_reason = f"non-TPU backend ({jax.default_backend()})"
+    plan = tail_bwd_plan(
+        cfg.model, 2 * cfg.batch_size, owner_reason, **encoder_kwargs
+    )
+    reasons: dict = {}
+    for site in plan:
+        if site["reason"] is not None:
+            reasons.setdefault(site["reason"], []).append(site["name"])
+    on_xla = sum(len(names) for names in reasons.values())
+    logging.info(
+        "[pointwise_bwd] %d Bottleneck tails on one backward kernel, %d on "
+        "XLA's path%s", len(plan) - on_xla, on_xla,
+        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()),
+    )
+    tracing.event(
+        "pointwise_bwd_plan", track=tracing.COMPILE_TRACK,
+        engaged=len(plan) - on_xla, on_xla=on_xla, reasons=reasons,
+    )
+    return plan
+
+
 def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1):
     """Model, schedule, optimizer, initial state, and the fused jitted update."""
     dtype = jnp.bfloat16 if cfg.bf16 else jnp.float32
@@ -277,11 +314,16 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
             "conv_impl", cfg.conv_impl, conv_impl, conv_reason
         ),
     )
-    model = SupConResNet(
-        model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
+    encoder_kwargs = dict(
         dtype=dtype, sync_bn=cfg.syncBN, remat=cfg.remat,
         bn_local_groups=1 if cfg.syncBN else data_parallel,
         conv_impl=conv_impl,
+    )
+    tail_plan = plan_pointwise_bwd(cfg, n_devices, **encoder_kwargs)
+    model = SupConResNet(
+        model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
+        pointwise_bwd=any(site["reason"] is None for site in tail_plan),
+        **encoder_kwargs,
     )
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the

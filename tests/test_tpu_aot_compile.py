@@ -16,7 +16,9 @@ process; the persistent compilation cache is off around the compiles (an
 entry written for a described chip cannot be read back without one).
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +28,9 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from simclr_pytorch_distributed_tpu.ops import pallas_conv, pallas_loss
+from jax import lax
+
+from simclr_pytorch_distributed_tpu.ops import pallas_conv, pallas_loss, pointwise_bwd
 
 ROWS, SIZE, FEAT_DIM = 512, 32, 128  # 2 * batch 256 view rows, CIFAR, head out
 
@@ -162,3 +166,205 @@ def test_fused_conv_kernel_compiles(one_chip, kind, dtype, mode):
     if mode == "grad":
         fn = jax.grad(fn, argnums=tuple(range(len(args))))
     _compile(fn, *args)
+
+
+# ---- Bottleneck's tail on one backward kernel (ops/pointwise_bwd.py): the
+# stage geometries of rn50 at the launcher's 512 rows, and other batches
+
+
+def _bottleneck_stack(x, blocks):
+    """Identity Bottlenecks in plain jnp, the tail routed as
+    models/resnet.py routes it; reduced to a scalar."""
+    eps = 1e-5
+
+    stats = pointwise_bwd.batch_moments
+
+    def bn(t, scale, bias):
+        mean, var = stats(t)
+        return (t - mean) * lax.rsqrt(var + eps) * scale + bias
+
+    def conv(t, w, padding):
+        return lax.conv_general_dilated(
+            t, w, (1, 1), padding, dimension_numbers=("NHWC", "HWIO", "NHWC")
+        )
+
+    for p in blocks:
+        out = jax.nn.relu(bn(conv(x, p["w1"], "VALID"), p["s1"], p["b1"]))
+        z = conv(out, p["w2"], ((1, 1), (1, 1)))
+        mean2, var2 = stats(z)
+        y, _, _ = pointwise_bwd.expand_conv_bn(
+            z, mean2, lax.rsqrt(var2 + eps), p["s2"], p["b2"], p["w3"],
+            p["s3"], p["b3"], eps=eps, interpret=False,
+        )
+        x = jax.nn.relu(y + x)
+    return jnp.sum(jnp.square(x))
+
+
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?)\s([\w\-]+)\((.*)$"
+)
+_THROUGH = ("bitcast", "reshape", "get-tuple-element")
+
+
+def _elements(result_type: str) -> int:
+    """Elements of the largest array in an instruction's result type."""
+    return max((math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\[([\d,]*)\]", result_type)), default=1)
+
+
+def _kernel_neighbours(text):
+    """``[(opcode, elements)]`` of what feeds and what follows each Mosaic
+    call in the entry computation, seen through bitcasts, reshapes and tuple
+    elements (which move no bytes)."""
+    entry = text[text.index("\nENTRY"):]
+    ops = {}
+    for line in entry.splitlines():
+        m = _HLO_LINE.match(line)
+        if not m:
+            continue
+        name, shape, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("),", 1)[0])
+        ops[name] = (opcode, _elements(shape), operands,
+                     "tpu_custom_call" in line)
+    users = {}
+    for name, (_, _, operands, _) in ops.items():
+        for operand in operands:
+            users.setdefault(operand, []).append(name)
+
+    def walk(name, step):
+        for other in step(name):
+            if other not in ops:
+                continue
+            if ops[other][0] in _THROUGH:
+                yield from walk(other, step)
+            else:
+                yield ops[other][:2]
+
+    found = []
+    for name, (_, _, operands, is_kernel) in ops.items():
+        if is_kernel:
+            found += list(walk(name, lambda n: ops[n][2]))
+            found += list(walk(name, lambda n: users.get(n, [])))
+    return found
+
+
+def _tail_kernel_shapes(sharding, rows, planes, positions=2):
+    """Operands of ``pointwise_bwd._backward_call`` as ``_bwd`` hands them
+    over (VMEM is per grid step: the number of positions does not enter)."""
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    wide = 4 * planes
+    gv, dy = sds(3, 1, wide), sds(positions, rows, wide)
+    if pointwise_bwd.rows_minor(planes):
+        return (gv, sds(4, planes, 1), sds(planes, wide, dtype=jnp.bfloat16),
+                dy, dy, sds(positions, planes, rows))
+    return (gv, sds(4, 1, planes), sds(wide, planes, dtype=jnp.bfloat16),
+            dy, dy, sds(positions, rows, planes))
+
+
+@pytest.mark.parametrize("rows", [128, 256, 384, 400, 512, 768, 1024, 1040, 2048])
+def test_pointwise_bwd_admits_only_what_mosaic_accepts(one_chip, rows):
+    """Whatever ``unsupported`` lets through at some batch, Mosaic compiles:
+    the step of a run at that batch cannot die in the compiler (the parent
+    trained at every one of these). 512 rows are the benchmark's; the others
+    are batches 64 to 1024 and two that are no power of two times 128."""
+    admitted = [
+        planes for planes in (64, 128, 256, 512)
+        if pointwise_bwd.unsupported(rows, planes, 4 * planes) is None
+    ]
+    if rows == ROWS:
+        assert admitted == [64, 128, 256]  # stage 4 needs 26 MiB a block
+    for planes in admitted:
+        _compile(
+            lambda *a, minor=pointwise_bwd.rows_minor(planes):
+                pointwise_bwd._backward_call(*a, minor=minor, interpret=False),
+            *_tail_kernel_shapes(one_chip, rows, planes),
+        )
+
+
+def test_pointwise_bwd_budget_is_the_compilers(one_chip):
+    """The shape rule is not guesswork: stage 4 at the launcher's 512 rows,
+    which ``unsupported`` leaves on XLA's path, is refused by Mosaic for the
+    VMEM the rule counts."""
+    assert "26.0 MiB of VMEM" in pointwise_bwd.unsupported(ROWS, 512, 2048)
+    with pytest.raises(Exception, match=r"(?i)vmem.*26\.04M"):
+        _compile(
+            lambda *a: pointwise_bwd._backward_call(*a, minor=False, interpret=False),
+            *_tail_kernel_shapes(one_chip, ROWS, 512),
+        )
+
+
+@pytest.mark.parametrize("size,planes", [(32, 64), (16, 128), (8, 256)],
+                         ids=lambda v: str(v))
+def test_pointwise_bwd_compiles_with_no_layout_copy(one_chip, size, planes):
+    """Mosaic accepts the kernel at the geometry of each stage that takes it
+    at 512 rows, float32, under ``jax.grad`` of a two-block stack, and XLA
+    puts no copy or transpose of activation size before or after it: the
+    operand orders of ops/pointwise_bwd.py are bitcasts of what the conv
+    fusions choose. (``copy-start`` / ``copy-done`` pairs are not layout
+    changes: the compiler's prefetches into fast memory, which its own
+    fusions get too.)"""
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    wide = 4 * planes
+    block = dict(
+        w1=sds(1, 1, wide, planes), s1=sds(planes), b1=sds(planes),
+        w2=sds(3, 3, planes, planes), s2=sds(planes), b2=sds(planes),
+        w3=sds(1, 1, planes, wide), s3=sds(wide), b3=sds(wide),
+    )
+    text = _compile(
+        jax.grad(_bottleneck_stack, argnums=(0, 1)),
+        sds(ROWS, size, size, wide), [block, block],
+    )
+    assert text.count("tpu_custom_call") == 2
+    neighbours = _kernel_neighbours(text)
+    activation = ROWS * size * size * planes  # the narrow side's elements
+    assert any(elements >= activation for _, elements in neighbours)
+    moved = [(opcode, elements) for opcode, elements in neighbours
+             if elements >= activation
+             and opcode in ("copy", "transpose")]
+    assert not moved, moved
+
+
+def test_routed_encoder_gets_no_elementwise_pass_before_the_kernel(one_chip, monkeypatch):
+    """Inside a real encoder (flax Bottlenecks, a stride-2 stage edge) the
+    kernel's ``dy`` has to stay an output of the conv fusion that applies the
+    block's ReLU mask. Without the optimization barrier in
+    ``ops/pointwise_bwd._bwd`` XLA moves the kernel's reshape up through the
+    mask's ``select`` and every site gets an elementwise pass of its own over
+    the wide tensors (measured on the chip, PERF.md section 6, PR 26: the
+    step 20% slower than XLA's own). A two-block stack does not show it."""
+    from simclr_pytorch_distributed_tpu.models import resnet
+
+    monkeypatch.setattr(resnet, "_interpret_pallas", lambda: False)
+    model = resnet.ResNet(
+        block_cls=resnet.Bottleneck, stage_sizes=(2, 1, 1, 1), pointwise_bwd=True
+    )
+    variables = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((2, SIZE, SIZE, 3)), train=True)),
+    )
+
+    def loss(params, batch_stats, x):
+        y, _ = model.apply({"params": params, "batch_stats": batch_stats}, x,
+                           train=True, mutable=["batch_stats"])
+        return jnp.mean(jnp.square(y))
+
+    text = jax.jit(jax.grad(loss)).lower(
+        variables["params"], variables["batch_stats"],
+        jax.ShapeDtypeStruct((ROWS, SIZE, SIZE, 3), jnp.float32, sharding=one_chip),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 4  # not stage 4: over the VMEM budget
+    wide = ROWS * SIZE * SIZE * 64  # a stage's narrow activation, in elements
+    passes = [
+        m.group(1)
+        for m in map(_HLO_LINE.match, text[text.index("\nENTRY"):].splitlines())
+        if m and "kind=kLoop" in m.group(4) and "transpose(jvp" in m.group(4)
+        and _elements(m.group(2)) > wide
+    ]
+    assert not passes, passes
