@@ -174,6 +174,7 @@ def build_report(events):
 
     nonnegative_ok = steady >= -OVERLAP_TOL_S
     return {
+        **encoder_section(events),
         "phases": phases,
         "steady_state": {
             "seconds": round(steady, 6),
@@ -200,6 +201,23 @@ def build_report(events):
         },
         "n_events": len(events),
     }
+
+
+def encoder_section(events):
+    """``{"encoder": ...}`` for a run whose encoder has expert layers: what
+    ``train.supcon.plan_experts`` said at build (the ``expert_plan`` event on
+    track ``compile``) and the newest ``health_window`` means of the
+    encoder's own ring columns, which the event names (``ring_columns``);
+    nothing for a ResNet's run."""
+    plan = next((e["args"] for e in events if e["name"] == "expert_plan"), None)
+    if plan is None:
+        return {}
+    last = {}
+    for e in events:
+        if e["name"] == "health_window":
+            last.update({k: e["args"][k] for k in plan.get("ring_columns", ())
+                         if k in e.get("args", {})})
+    return {"encoder": {"expert_plan": plan, "ring": last}}
 
 
 def render_table(report):
@@ -233,6 +251,15 @@ def render_table(report):
         for row in rows
     ]
     lines.insert(1, "-" * len(lines[0]))
+    if "encoder" in report:
+        plan, ring = report["encoder"]["expert_plan"], report["encoder"]["ring"]
+        lines.append(
+            f"experts: {plan['layers']} layers hold {plan['held']} of "
+            f"{plan['n_experts']}, {plan['per_token']} a token, "
+            f"{plan['rows_per_step']} token rows a step, "
+            f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
+            "the routing; " + (", ".join(
+                f"{k} {v:.4g}" for k, v in ring.items()) or "no health window yet"))
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

@@ -550,6 +550,24 @@ def validate_conv_impl(cfg: SupConConfig) -> None:
     """
 
 
+def validate_model(cfg: SupConConfig) -> None:
+    """Parse-time check of --model against the encoders there are
+    (models/resnet.MODEL_DICT), and of --size against a token encoder's
+    patches: both would otherwise fail inside the first trace."""
+    from simclr_pytorch_distributed_tpu.models import MODEL_DICT, TOKEN_ENCODERS
+
+    if cfg.model not in MODEL_DICT:
+        raise ValueError(
+            f"--model {cfg.model!r} is no encoder; choose from {sorted(MODEL_DICT)}"
+        )
+    spec = TOKEN_ENCODERS.get(cfg.model)
+    if spec is not None and cfg.size % spec.patch:
+        raise ValueError(
+            f"--model {cfg.model} cuts views into {spec.patch}x{spec.patch} "
+            f"patches; --size {cfg.size} is no multiple"
+        )
+
+
 def impl_resolution_banner(
     flag: str, requested: str, resolved: str, reason: str
 ) -> str:
@@ -643,6 +661,7 @@ def finalize_supcon(cfg: SupConConfig, make_dirs: bool = True) -> SupConConfig:
     validate_data_placement(cfg.dataset, cfg.data_placement)
     validate_conv_impl(cfg)
     validate_recipe(cfg)
+    validate_model(cfg)
     if cfg.dataset == "path":
         assert cfg.data_folder is not None and cfg.mean is not None and cfg.std is not None
     if cfg.data_folder is None:

@@ -1,6 +1,7 @@
 from simclr_pytorch_distributed_tpu.models.resnet import (  # noqa: F401
     MODEL_DICT,
     ResNet,
+    build_encoder,
     resnet18,
     resnet34,
     resnet50,
@@ -11,5 +12,9 @@ from simclr_pytorch_distributed_tpu.models.heads import (  # noqa: F401
     SupCEResNet,
     SupConResNet,
     infer_architecture_from_variables,
+)
+from simclr_pytorch_distributed_tpu.models.token_encoder import (  # noqa: F401
+    TOKEN_ENCODERS,
+    TokenEncoder,
 )
 from simclr_pytorch_distributed_tpu.models.norm import CrossReplicaBatchNorm  # noqa: F401

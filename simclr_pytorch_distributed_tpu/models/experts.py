@@ -1,0 +1,209 @@
+"""A layer of routed experts that is told which experts it holds.
+
+Expert parallelism's layer as one chip runs it: the router scores every
+token over all ``n_experts`` (softmax, the ``top_k`` largest, renormalised
+over those), and this layer computes the part of the result that its own
+experts give, ``held = (first, count)``:
+
+    y_t = sum over e in (top_k of t) and in held of gate[t, e] * f_e(b_t)
+    f_e(x) = (silu(x W_gate[e]) * (x W_up[e])) W_down[e]
+
+What the absent experts would add is left out; no code stands in for the
+other chips or their exchange. No token is dropped, whatever the imbalance:
+the held assignments are sorted by expert and taken a chunk at a time,
+each chunk one grouped product a projection (``lax.ragged_dot``), added back
+onto its tokens. The loop (``lax.while_loop``) sweeps the rows the layer is
+provisioned for, ``capacity_factor`` balanced shares, whatever the routing
+(rows past the held assignments enter as zeros), and goes on past them for as
+long as held assignments are left: up to that load every step does the same
+work, as a deployment's fixed-capacity buffers make it, and the step's time
+does not follow which experts a batch happened to favour; above it a step
+pays for the rows its routing gave these experts, and nothing is sized for
+the worst case. ``capacity_factor`` 0 provisions nothing: the loop is then as
+long as the data.
+
+Beside ``y`` the layer returns what the step and the tracing need of the
+routing, over all ``n_experts``: the share of assignments each expert took
+(``load``), its mean router probability (``prob``), the balance term
+``n_experts * sum(load * prob)`` and the share of assignments that landed on
+held experts.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from simclr_pytorch_distributed_tpu.models.sparse_attention import (
+    HIGHEST,
+    normal_init,
+    rms_norm,
+    tie_gradients,
+)
+
+SCOPE_EXPERTS = "experts"
+
+
+def balanced_chunk_rows(assignments: int, held: int, n_experts: int) -> int:
+    """Rows of a chunk of sorted assignments, in tiles of 512: a quarter of
+    the held experts' share of a balanced load."""
+    share = assignments * held / n_experts
+    return max(512, -(-int(share / 4) // 512) * 512)
+
+
+def provisioned_rows(assignments: int, held: int, n_experts: int,
+                     capacity_factor: float) -> int:
+    """Rows every step sweeps: ``capacity_factor`` times the held experts'
+    share of a balanced load, and no more than there are."""
+    return min(int(capacity_factor * assignments * held / n_experts), assignments)
+
+
+def route(logits: jax.Array, top_k: int):
+    """``[N, E]`` float32 router logits -> ``(probabilities [N, E], the chosen
+    experts [N, k], their gates [N, k] renormalised over the k)``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top_p, top_e = lax.top_k(probs, top_k)
+    return probs, top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def routing_statistics(probs: jax.Array, top_e: jax.Array):
+    """``(load [E], prob [E])``: the share of the ``N * k`` assignments that
+    chose each expert, and each expert's mean probability."""
+    n_experts = probs.shape[-1]
+    counts = jnp.zeros((n_experts,), jnp.float32).at[top_e.reshape(-1)].add(1.0)
+    return counts / top_e.size, jnp.mean(probs, axis=0)
+
+
+def _chunk_out(x, w_gate, w_up, w_down, gate, expert):
+    """One chunk of sorted assignments: token rows ``x [C, D]``, their gates
+    and (local) experts, ``count`` for a row that is no held assignment. Such
+    rows come last; they enter as zeros and count as the last expert's, so
+    that every chunk is a full one to the grouped products."""
+    count = w_gate.shape[0]
+    sizes = jnp.sum(expert[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    sizes = sizes.at[count - 1].add(expert.shape[0] - jnp.sum(sizes))
+    x = jnp.where((expert < count)[:, None], x, 0)
+    with jax.named_scope(SCOPE_EXPERTS):
+        hidden = (jax.nn.silu(lax.ragged_dot(x, w_gate, sizes))
+                  * lax.ragged_dot(x, w_up, sizes))
+        out = lax.ragged_dot(hidden, w_down, sizes)
+    return out * gate[:, None]
+
+
+def _sweep(step, carry, rows, chunk: int):
+    """``step(start, carry) -> carry`` over the first ``rows`` sorted
+    assignments, ``chunk`` rows a trip."""
+    return lax.while_loop(lambda c: c[0] < rows,
+                          lambda c: (c[0] + chunk, step(c[0], c[1])),
+                          (jnp.int32(0), carry))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
+    """``y [N, D]``: the first ``rows`` sorted assignments a chunk at a time
+    (``_sweep``), each chunk through ``_chunk_out`` and added onto its tokens.
+    The loop's length is not static, so the backward pass is written as the
+    same sweep over ``jax.vjp`` of a chunk: it recomputes the chunk and keeps
+    nothing."""
+    def step(start, y):
+        cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
+        tok = cut(token)
+        return y.at[tok].add(_chunk_out(b[tok], w_gate, w_up, w_down, cut(gate), cut(expert)))
+
+    return _sweep(step, jnp.zeros_like(b), rows, chunk)
+
+
+def _mix_sorted_fwd(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
+    return (_mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk),
+            (b, w_gate, w_up, w_down, gate, token, expert, rows))
+
+
+def _mix_sorted_bwd(chunk, kept, dy):
+    b, w_gate, w_up, w_down, gate, token, expert, rows = kept
+
+    def step(start, carry):
+        db, dw, dgate = carry
+        cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
+        tok, experts = cut(token), cut(expert)
+        _, back = jax.vjp(lambda x, wg, wu, wd, g: _chunk_out(x, wg, wu, wd, g, experts),
+                          b[tok], w_gate, w_up, w_down, cut(gate))
+        dx, *dw_chunk, dg = back(dy[tok])
+        return (db.at[tok].add(dx), [a + c for a, c in zip(dw, dw_chunk)],
+                lax.dynamic_update_slice_in_dim(dgate, dg, start, 0))
+
+    zeros = jnp.zeros_like
+    db, dw, dgate = _sweep(
+        step, (zeros(b), [zeros(w_gate), zeros(w_up), zeros(w_down)], zeros(gate)),
+        rows, chunk)
+    return (db, *dw, dgate, None, None, None)
+
+
+_mix_sorted.defvjp(_mix_sorted_fwd, _mix_sorted_bwd)
+
+
+def held_mix(b, top_e, gates, w_gate, w_up, w_down, first: int, chunk: int,
+             provisioned: int = 0):
+    """The held experts' part of the mix for tokens ``b [N, D]``: weights
+    ``[count, D, F]``, ``[count, D, F]``, ``[count, F, D]`` of experts
+    ``first .. first + count - 1``, ``chunk`` sorted assignments a trip of
+    the loop, which sweeps ``provisioned`` rows (to the end of their chunk) at
+    the least. Returns ``(y [N, D], held assignments)``."""
+    N, k = top_e.shape
+    count = w_gate.shape[0]
+    local = top_e.reshape(-1) - first
+    expert = jnp.where((local >= 0) & (local < count), local, count)  # count: not held
+    order = jnp.argsort(expert, stable=True)  # held assignments first, by expert
+    n_held = jnp.sum(expert < count, dtype=jnp.int32)
+    chunk = min(chunk, N * k)
+    pad = -(N * k) % chunk
+    y = _mix_sorted(
+        b, w_gate, w_up, w_down,
+        jnp.pad(gates.reshape(-1)[order].astype(b.dtype), (0, pad)),
+        jnp.pad(order // k, (0, pad)),
+        jnp.pad(expert[order], (0, pad), constant_values=count),
+        jnp.maximum(n_held, min(provisioned, N * k)), chunk)
+    return y, n_held
+
+
+class ExpertLayer(nn.Module):
+    """The layer with its pre-norm and its residual: router over
+    ``n_experts`` and the ``held`` experts' weights. Takes tokens ``h [...,
+    D]``; returns ``(h + y, statistics)`` with the module docstring's
+    ``load``, ``prob``, ``balance`` and ``held_share``."""
+
+    n_experts: int
+    top_k: int
+    width: int
+    held: Tuple[int, int]
+    capacity_factor: float = 0.0  # balanced shares every step sweeps; 0: as long as the data
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jax.Array) -> tuple:
+        D = h.shape[-1]
+        first, count = self.held
+        if not (0 <= first and first + count <= self.n_experts and count > 0):
+            raise ValueError(f"held {self.held} is no range of {self.n_experts} experts")
+        w = {name: self.param(name, normal_init, shape) for name, shape in (
+            ("router", (D, self.n_experts)), ("w_gate", (count, D, self.width)),
+            ("w_up", (count, D, self.width)), ("w_down", (count, self.width, D)))}
+        w["norm"] = self.param("norm", nn.initializers.ones, (D,))
+        w, h = tie_gradients((w, h))
+        b = rms_norm(h, w["norm"]).reshape(-1, D)
+        # float32 at highest: a rounded operand must not flip a choice
+        logits = jnp.dot(b.astype(jnp.float32), w["router"], precision=HIGHEST)
+        probs, top_e, gates = route(logits, self.top_k)
+        load, prob = routing_statistics(probs, top_e)
+        y, n_held = held_mix(
+            b.astype(self.dtype), top_e, gates,
+            *(w[name].astype(self.dtype) for name in ("w_gate", "w_up", "w_down")), first=first,
+            chunk=balanced_chunk_rows(top_e.size, count, self.n_experts),
+            provisioned=provisioned_rows(top_e.size, count, self.n_experts, self.capacity_factor))
+        return h + y.reshape(h.shape).astype(h.dtype), {
+            "load": load, "prob": prob, "balance": self.n_experts * jnp.sum(load * prob),
+            "held_share": n_held.astype(jnp.float32) / top_e.size}

@@ -23,7 +23,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from simclr_pytorch_distributed_tpu.models.resnet import MODEL_DICT, Bottleneck
+from simclr_pytorch_distributed_tpu.models import token_encoder
+from simclr_pytorch_distributed_tpu.models.resnet import (
+    MODEL_DICT,
+    Bottleneck,
+    ResNet,
+    build_encoder,
+)
 
 
 class TorchDense(nn.Module):
@@ -125,18 +131,43 @@ class SupConResNet(nn.Module):
     # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
     pointwise_bwd: bool = False
 
-    def setup(self):
-        model_fn, dim_in = MODEL_DICT[self.model_name]
-        self.encoder = model_fn(
+    @nn.nowrap
+    def build_encoder(self) -> nn.Module:
+        """The encoder this model runs, unbound: what ``setup`` binds, and
+        what tells the step and the tracing of itself before any ``apply``."""
+        return build_encoder(
+            self.model_name,
             dtype=self.dtype, axis_name=self.axis_name, sync_bn=self.sync_bn,
             bn_local_groups=self.bn_local_groups,
             bn_group_views=self.bn_group_views,
             remat=self.remat, stem=self.stem, conv_impl=self.conv_impl,
             pointwise_bwd=self.pointwise_bwd,
         )
+
+    def setup(self):
+        self.encoder = self.build_encoder()
         self.proj_head = ProjectionHead(
-            head=self.head, dim_in=dim_in, feat_dim=self.feat_dim, dtype=self.dtype
+            head=self.head, dim_in=self.encoder_dim, feat_dim=self.feat_dim,
+            dtype=self.dtype,
         )
+
+    @property
+    def encoder_dim(self) -> int:
+        """Width of the encoder's features: the probe's and the server's input."""
+        return MODEL_DICT[self.model_name][1]
+
+    @property
+    def aux_metric_keys(self) -> tuple:
+        """Metric-ring columns the encoder sows into the collection ``aux``
+        beside its ``aux_loss``, as the encoder itself says; none for a
+        ResNet, which says nothing."""
+        return tuple(getattr(self.build_encoder(), "aux_metric_keys", ()))
+
+    @nn.nowrap
+    def read_aux(self, collection: dict):
+        """``(auxiliary loss, {ring column: value})`` from the collection
+        ``aux`` of a train-mode ``apply``; only where ``aux_metric_keys``."""
+        return self.build_encoder().read_aux(collection["encoder"])
 
     def __call__(self, x: jax.Array, *, train: bool = True) -> jax.Array:
         return self.proj_head(self.encoder(x, train=train))
@@ -177,6 +208,9 @@ def infer_architecture_from_variables(variables: dict) -> Tuple[str, str, int]:
             "variables tree has no encoder/proj_head — not a SupConResNet "
             f"checkpoint (top-level keys: {sorted(params)})"
         )
+    name = token_encoder.match_tree(enc)
+    if name is not None:
+        return (name, *_head_of(head_tree))
     stages = [0, 0, 0, 0]
     for name in enc:
         if m := re.match(r"layer(\d)_block(\d+)$", name):
@@ -186,7 +220,8 @@ def infer_architecture_from_variables(variables: dict) -> Tuple[str, str, int]:
     name = next(
         (
             n for n, (ctor, _) in MODEL_DICT.items()
-            if tuple(ctor().stage_sizes) == tuple(stages)
+            if isinstance(ctor(), ResNet)
+            and tuple(ctor().stage_sizes) == tuple(stages)
             and (ctor().block_cls is Bottleneck) == bottleneck
         ),
         None,
@@ -196,13 +231,15 @@ def infer_architecture_from_variables(variables: dict) -> Tuple[str, str, int]:
             f"unrecognized encoder geometry: stages={tuple(stages)}, "
             f"bottleneck={bottleneck}"
         )
+    return (name, *_head_of(head_tree))
+
+
+def _head_of(head_tree: dict) -> Tuple[str, int]:
     if "fc1" in head_tree:
-        head, feat_dim = "mlp", int(head_tree["fc2"]["kernel"].shape[-1])
-    elif "fc" in head_tree:
-        head, feat_dim = "linear", int(head_tree["fc"]["kernel"].shape[-1])
-    else:
-        raise ValueError(f"unrecognized proj_head tree: {sorted(head_tree)}")
-    return name, head, feat_dim
+        return "mlp", int(head_tree["fc2"]["kernel"].shape[-1])
+    if "fc" in head_tree:
+        return "linear", int(head_tree["fc"]["kernel"].shape[-1])
+    raise ValueError(f"unrecognized proj_head tree: {sorted(head_tree)}")
 
 
 class SupCEResNet(nn.Module):
@@ -223,8 +260,8 @@ class SupCEResNet(nn.Module):
     bn_group_views: int = 1
 
     def setup(self):
-        model_fn, _ = MODEL_DICT[self.model_name]
-        self.encoder = model_fn(
+        self.encoder = build_encoder(
+            self.model_name,
             dtype=self.dtype, axis_name=self.axis_name, sync_bn=self.sync_bn,
             bn_local_groups=self.bn_local_groups,
             bn_group_views=self.bn_group_views,

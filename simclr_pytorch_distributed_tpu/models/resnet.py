@@ -22,6 +22,7 @@ fp32 inside ``CrossReplicaBatchNorm``).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Callable, Optional, Sequence, Tuple
 
@@ -448,13 +449,30 @@ def resnet101(**kwargs) -> ResNet:
 
 
 # name -> (constructor, feature dim); reference model_dict resnet_big.py:137-142.
-MODEL_DICT: dict[str, Tuple[Callable[..., ResNet], int]] = {
+# The encoder protocol, which every entry keeps (the ResNets here, the token
+# encoders that models/token_encoder.py adds): the constructor takes, as
+# keywords, those of the owner's flags that its module declares as fields
+# (``build_encoder`` hands it no other) and returns a flax module whose
+# ``__call__(views [N, H, W, 3], train=...)`` gives ``[N, feature dim]``
+# float32 features; running statistics, if it keeps any, live in
+# ``batch_stats`` and move in train mode; what it wants added to the loss or
+# written to the metric ring it sows into the collection ``aux``.
+MODEL_DICT: dict[str, Tuple[Callable[..., nn.Module], int]] = {
     "resnet10": (resnet10, 512),  # test/smoke extension, not in the reference
     "resnet18": (resnet18, 512),
     "resnet34": (resnet34, 512),
     "resnet50": (resnet50, 2048),
     "resnet101": (resnet101, 2048),
 }
+
+
+def build_encoder(model: str, **flags) -> nn.Module:
+    """The encoder ``model`` names, built with those of ``flags`` that its
+    module declares: a ResNet takes the conv and BN flags, an encoder that has
+    neither is not handed them."""
+    ctor, _ = MODEL_DICT[model]
+    declared = {f.name for f in dataclasses.fields(ctor())}
+    return ctor(**{k: v for k, v in flags.items() if k in declared})
 
 
 def tail_bwd_plan(
@@ -467,8 +485,8 @@ def tail_bwd_plan(
     is what its ``__call__`` asks too. ``encoder_kwargs`` are the attributes
     the encoder is built with; ``rows`` is its batch, both views of the
     two-crop step. A BasicBlock model has no such site."""
-    mod = MODEL_DICT[model][0](**encoder_kwargs)
-    if not issubclass(mod.block_cls, Bottleneck):
+    mod = build_encoder(model, **encoder_kwargs)
+    if not (isinstance(mod, ResNet) and issubclass(mod.block_cls, Bottleneck)):
         return []
     return [
         {"name": name, "reason": owner_reason or mod.tail_bwd_reason(rows, width)}

@@ -21,6 +21,8 @@ those state trees stay exactly the pre-recipe ones).
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
 from simclr_pytorch_distributed_tpu.recipes.base import (  # noqa: F401
@@ -132,8 +134,17 @@ def attach_for_config(cfg, model, state, schedule=None):
     """``(state_with_slots, recipe)`` in one call — the drivers' and bench's
     shared entry point (the ``device_store.make_store`` convention). The rng
     is derived from ``cfg.seed + 2`` (the probe uses ``seed``, the data key
-    ``seed + 1``)."""
+    ``seed + 1``).
+
+    This is where a run's extra ring columns are decided: the recipe's own
+    and, after them, those that ``model``'s encoder says it sows
+    (``model.aux_metric_keys``; none for a ResNet). The step builder and
+    every reader take ``recipe.metric_keys`` as returned here."""
     recipe = build_recipe(cfg, schedule=schedule)
+    encoder_keys = tuple(getattr(model, "aux_metric_keys", ()))
+    if encoder_keys:
+        recipe = dataclasses.replace(
+            recipe, metric_keys=tuple(recipe.metric_keys) + encoder_keys)
     state = attach_recipe_slots(
         recipe, model, state, jax.random.key(cfg.seed + 2)
     )

@@ -65,7 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from simclr_pytorch_distributed_tpu.models import (
-    MODEL_DICT,
     SupConResNet,
     infer_architecture_from_variables,
 )
@@ -205,7 +204,7 @@ class EmbeddingEngine:
         self._repl = replicated_sharding(self.mesh)
         self._variables = jax.device_put(variables, self._repl)
         if output == "features":
-            self.feat_dim = MODEL_DICT[model.model_name][1]
+            self.feat_dim = model.encoder_dim
         else:
             self.feat_dim = model.feat_dim
         self._jit_fns: dict = {}  # sharded vs replicated jit objects

@@ -40,7 +40,6 @@ from simclr_pytorch_distributed_tpu.data import device_store
 from simclr_pytorch_distributed_tpu.data.device_store import slice_epoch_step
 from simclr_pytorch_distributed_tpu.data.pipeline import EpochLoader
 from simclr_pytorch_distributed_tpu.models import (
-    MODEL_DICT,
     LinearClassifier,
     SupConResNet,
 )
@@ -109,7 +108,7 @@ def build_probe(cfg: config_lib.LinearConfig, steps_per_epoch: int, encoder_vari
         warm=cfg.warm, warm_epochs=cfg.warm_epochs, warmup_from=cfg.warmup_from,
     )
     tx = make_optimizer(schedule, momentum=cfg.momentum, weight_decay=cfg.weight_decay)
-    feat_dim = MODEL_DICT[cfg.model][1]
+    feat_dim = encoder.encoder_dim
     cls_params = classifier.init(
         jax.random.key(cfg.seed), jnp.zeros((2, feat_dim))
     )["params"]

@@ -39,6 +39,7 @@ from simclr_pytorch_distributed_tpu.data import device_store
 from simclr_pytorch_distributed_tpu.data.device_store import slice_epoch_step
 from simclr_pytorch_distributed_tpu.data.pipeline import EpochLoader
 from simclr_pytorch_distributed_tpu.models import MODEL_DICT, SupConResNet
+from simclr_pytorch_distributed_tpu.models.experts import provisioned_rows
 from simclr_pytorch_distributed_tpu.ops.augment import (
     DATASET_STATS,
     AugmentConfig,
@@ -75,6 +76,7 @@ from simclr_pytorch_distributed_tpu.train.supcon_step import (
     SupConStepConfig,
     build_online_probe,
     epoch_position,
+    extra_columns,
     make_train_step,
     metric_keys,
 )
@@ -292,6 +294,37 @@ def plan_pointwise_bwd(
     return plan
 
 
+def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
+    """What the expert layers of ``model``'s encoder hold, said once in a
+    banner line and one ``expert_plan`` event (track ``compile``), as
+    ``plan_pointwise_bwd`` says its plan, with the ring columns the encoder
+    sows (``scripts/trace_report.py`` reads their names from the event);
+    None for an encoder without experts."""
+    spec = getattr(model.build_encoder(), "spec", None)
+    if spec is None:
+        return None
+    first, count = spec.held
+    rows = 2 * cfg.batch_size * (cfg.size // spec.patch) ** 2
+    plan = {"layers": spec.layers, "held": count, "first": first,
+            "n_experts": spec.n_experts, "per_token": spec.top_k,
+            "rows_per_step": rows, "capacity_factor": spec.capacity_factor,
+            "provisioned_assignments": provisioned_rows(
+                rows * spec.top_k, count, spec.n_experts, spec.capacity_factor),
+            "ring_columns": list(model.aux_metric_keys)}
+    logging.info(
+        "[experts] %d layers hold experts %d-%d of %d, %d a token (routed over "
+        "all %d); %d token rows a step, %.1f%% of their assignments land here "
+        "when the load is balanced; a layer sweeps %d assignments a step (%.4g "
+        "balanced shares) whatever the routing, and more where more land here",
+        spec.layers, first, first + count - 1,
+        spec.n_experts, spec.top_k, spec.n_experts, rows,
+        100.0 * count / spec.n_experts, plan["provisioned_assignments"],
+        spec.capacity_factor,
+    )
+    tracing.event("expert_plan", track=tracing.COMPILE_TRACK, **plan)
+    return plan
+
+
 def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1):
     """Model, schedule, optimizer, initial state, and the fused jitted update."""
     dtype = jnp.bfloat16 if cfg.bf16 else jnp.float32
@@ -325,6 +358,7 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
         pointwise_bwd=any(site["reason"] is None for site in tail_plan),
         **encoder_kwargs,
     )
+    plan_experts(cfg, model)
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the
     # effective-LR consequence (config.ngpu_mismatch_banner)
@@ -572,6 +606,13 @@ def train_one_epoch(
     # Dispatch is asynchronous, so past the enqueue cost (the min) this is
     # back-pressure: the host waiting for room in the device's queue.
     dispatch = [0.0, math.inf, 0.0]
+    # the run's columns beyond the step's own, from the ring itself: the
+    # recipe's and the encoder's (recipes.attach_for_config). The monitor
+    # averages them into its windows; those no tag names go under encoder/
+    extra = extra_columns(telemetry.ring.keys)
+    tb_tags = {**{k: "encoder/" + k for k in extra}, **EXTRA_TB_TAGS}
+    if health_monitor is not None:
+        health_monitor.extra_keys = extra
 
     def submit_window(boundary_idx, step_hint):
         """One ``flush_boundary`` (utils/telemetry.py: meter the window on
@@ -598,7 +639,7 @@ def train_one_epoch(
                     it = (epoch - 1) * steps_per_epoch + idx_f
                     for name in TB_ITER_SCALARS:
                         tb.log_value(f"info/{name}", m[name], it)
-                    for name, tag in EXTRA_TB_TAGS.items():
+                    for name, tag in tb_tags.items():
                         # NaN = the lax.cond sentinel for a non-health step
                         if name in m and math.isfinite(m[name]):
                             tb.log_value(tag, m[name], it)

@@ -123,6 +123,15 @@ def metric_keys(health: bool = False, online_probe: bool = False, extra=()):
     return tuple(sorted(keys))
 
 
+def extra_columns(keys) -> tuple:
+    """What ``extra`` put into a ring layout ``keys``: the columns beyond the
+    step's own and the health and probe families, which are the recipe's and
+    the encoder's (``recipes.attach_for_config``). A reader that holds the
+    run's ring takes them from here and names none."""
+    return tuple(k for k in keys
+                 if k not in METRIC_KEYS and not k.startswith(("health_", "probe_")))
+
+
 def epoch_position(step, steps_per_epoch: int):
     """A step's position within its epoch, derived ON DEVICE from the state's
     global step counter — the resident-data slice index
@@ -289,7 +298,7 @@ class SupConStepConfig:
 
 def two_view_forward(
     model, params, batch_stats, images: jax.Array, *,
-    train: bool = True, with_features: bool = False,
+    train: bool = True, with_features: bool = False, with_aux: bool = False,
 ):
     """Forward both views through the encoder+head as ONE batch.
 
@@ -304,6 +313,11 @@ def two_view_forward(
     ``(projection, encoder_features)`` pair — the online probe's input
     without a second encoder forward. Default callers see the unchanged
     2-tuple.
+
+    ``with_aux=True`` (train mode) appends a third element: the collection
+    ``aux`` that the encoder sowed (models/token_encoder.py: its auxiliary
+    loss and its metric-ring columns). A ResNet sows nothing and is never
+    asked.
     """
     B = images.shape[0]
     with jax.named_scope(SCOPE_AUG):  # the views' layout, not the encoder's time
@@ -314,8 +328,11 @@ def two_view_forward(
     if train:
         feats, mutated = model.apply(
             {"params": params, "batch_stats": batch_stats},
-            flat, train=True, mutable=["batch_stats"], method=method,
+            flat, train=True, method=method,
+            mutable=["batch_stats", "aux"] if with_aux else ["batch_stats"],
         )
+        if with_aux:
+            return feats, mutated["batch_stats"], mutated["aux"]
         return feats, mutated["batch_stats"]
     feats = model.apply(
         {"params": params, "batch_stats": batch_stats}, flat, train=False,
@@ -446,7 +463,11 @@ def make_train_step(
             f"{'missing' if probe is None else 'given'} — the step config "
             "and the OnlineProbe must be built together"
         )
-    recipe_extra = () if recipe is None else tuple(recipe.metric_keys)
+    # an encoder with auxiliary terms (models/token_encoder.py) streams its
+    # own columns: recipes.attach_for_config has put them after the recipe's
+    # own; a step built without a recipe object has the encoder's alone
+    encoder_extra = tuple(getattr(model, "aux_metric_keys", ()))
+    recipe_extra = encoder_extra if recipe is None else tuple(recipe.metric_keys)
     recipe_trainable = recipe is not None and recipe.trainable
     expected_keys = metric_keys(
         health=cfg.health, online_probe=cfg.online_probe, extra=recipe_extra
@@ -464,18 +485,17 @@ def make_train_step(
 
     def loss_fn(params, recipe_params, state: TrainState, images, labels):
         probe_feats = None
+        how = {"with_features": True} if probe is not None else {}
+        if encoder_extra:
+            how["with_aux"] = True
+        feats, new_batch_stats, *sown = two_view_forward(
+            model, params, state.batch_stats, images, train=True, **how
+        )
         if probe is not None:
-            (feats, enc_feats), new_batch_stats = two_view_forward(
-                model, params, state.batch_stats, images, train=True,
-                with_features=True,
-            )
+            feats, enc_feats = feats
             # the probe's whole detachment contract: gradients CANNOT flow
             # from the classifier back into the encoder
             probe_feats = jax.lax.stop_gradient(enc_feats.astype(jnp.float32))
-        else:
-            feats, new_batch_stats = two_view_forward(
-                model, params, state.batch_stats, images, train=True
-            )
         # everything between the head's output and the scalar loss is the
         # loss's device time: norms, the normalize, the contrastive (or the
         # recipe's) term with its custom-VJP backward, the aux ramps and
@@ -529,6 +549,12 @@ def make_train_step(
                 loss = loss + cfg.sec_wei * ramp * loss_sec
             if cfg.l2reg:
                 loss = loss + cfg.l2reg_wei * ramp * loss_l2reg
+            encoder_metrics = {}
+            if encoder_extra:
+                # the encoder's own terms, weighted where they arise: the
+                # expert layers' balance term and the indexers' KL
+                encoder_loss, encoder_metrics = model.read_aux(sown[0])
+                loss = loss + encoder_loss
             # grad-scale fidelity: DDP means over ngpu ranks (module docstring)
             scaled_loss = loss / cfg.grad_div
 
@@ -543,6 +569,7 @@ def make_train_step(
         # recipe extras: metric terms (recipe.metric_keys) + the detached
         # rotation payload ("recipe_embeddings", queue recipes)
         aux.update(recipe_aux)
+        aux.update(encoder_metrics)
         if cfg.health:
             # the loss's OWN normalized, view-major embedding rows — the
             # health diagnostics' input, detached so aux plumbing cannot
