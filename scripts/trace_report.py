@@ -207,7 +207,8 @@ def encoder_section(events):
     """``{"encoder": ...}`` for a run whose encoder has expert layers: what
     ``train.supcon.plan_experts`` said at build (the ``expert_plan`` event on
     track ``compile``) and the newest ``health_window`` means of the
-    encoder's own ring columns, which the event names (``ring_columns``);
+    encoder's own ring columns, which the event names (``ring_columns``),
+    with ``plan_sparse_attention``'s ``sparse_attention_plan`` event;
     nothing for a ResNet's run."""
     plan = next((e["args"] for e in events if e["name"] == "expert_plan"), None)
     if plan is None:
@@ -217,7 +218,10 @@ def encoder_section(events):
         if e["name"] == "health_window":
             last.update({k: e["args"][k] for k in plan.get("ring_columns", ())
                          if k in e.get("args", {})})
-    return {"encoder": {"expert_plan": plan, "ring": last}}
+    section = {"expert_plan": plan, "ring": last}
+    section.update({"attention_plan": e["args"] for e in events
+                    if e["name"] == "sparse_attention_plan"})
+    return {"encoder": section}
 
 
 def render_table(report):
@@ -260,6 +264,13 @@ def render_table(report):
             f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
             "the routing; " + (", ".join(
                 f"{k} {v:.4g}" for k, v in ring.items()) or "no health window yet"))
+        attention = report["encoder"].get("attention_plan")
+        if attention:
+            lines.append(
+                f"sparse attention: {attention['engaged']} layers on the kernel pair, "
+                f"{attention['on_xla']} on XLA's path" + "".join(
+                    f"; {', '.join(names)}: {why}"
+                    for why, names in attention["reasons"].items()))
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

@@ -130,6 +130,9 @@ class SupConResNet(nn.Module):
     # Bottleneck's tail through ops/pointwise_bwd.py's one backward kernel:
     # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
     pointwise_bwd: bool = False
+    # a token encoder's attention through ops/sparse_attention.py's kernel
+    # pair: set by train.supcon.build likewise (models/token_encoder.py)
+    attn_kernel: bool = False
 
     @nn.nowrap
     def build_encoder(self) -> nn.Module:
@@ -141,7 +144,7 @@ class SupConResNet(nn.Module):
             bn_local_groups=self.bn_local_groups,
             bn_group_views=self.bn_group_views,
             remat=self.remat, stem=self.stem, conv_impl=self.conv_impl,
-            pointwise_bwd=self.pointwise_bwd,
+            pointwise_bwd=self.pointwise_bwd, attn_kernel=self.attn_kernel,
         )
 
     def setup(self):

@@ -29,18 +29,29 @@ a time (``lax.map``): a ``[heads, q_chunk, keys]`` score block lives, never
 largest score of a query is found by bisection on the scores' bits (32 counts
 over the block; no sort, no gather), and ties at that threshold are cut by a
 second bisection on the key's index, which runs only where a block has one.
-XLA only.
+
+Two paths from the selection to the output. ``sparse_attention`` is plain
+jnp: every ``[heads, q_chunk, keys]`` block of logits and probabilities is an
+XLA tensor. ``sparse_attention_kernel`` hands whole rows to the kernel pair
+of ``ops/sparse_attention.py``, which keeps a block in VMEM. The layer takes
+the second where its owner says the program is one TPU's (``kernel``) and
+its own dtype and shape allow it (``SparseAttention.kernel_reason``); off
+the TPU XLA's products are exact, and routing there would only make CPU runs
+less precise.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Sequence
+from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
+
+from simclr_pytorch_distributed_tpu.ops import sparse_attention as kernel_ops
 
 SCOPE_INDEXER = "indexer"
 # rows of a batch that the attention layer takes at a time (SparseAttention)
@@ -156,40 +167,70 @@ def index_scores(qi: jax.Array, ki: jax.Array, wi: jax.Array) -> jax.Array:
     return weighted / math.sqrt(qi.shape[1] * qi.shape[2])
 
 
-def _attend_chunk(q, k, v, qi, ki, wi, first: int, topk: int):
-    """One row's queries ``first .. first + Q - 1`` against its keys ``0 ..
-    first + Q - 1``: ``q [Q, H, d]``, ``k``/``v`` ``[S, G, d]``, indexer
-    ``qi [Q, J, dI]``, ``ki [S, dI]``, ``wi [Q, J]``. Returns ``(o [Q, H *
-    d], sum over the queries of the indexer's KL)``."""
-    Q, H, d = q.shape
-    S, G, _ = k.shape
+def _select(qi, ki, wi, first: int, topk: int):
+    """The indexer's ``(scores, chosen)``, both ``[Q, S]``, for one row's
+    queries ``first .. first + Q - 1`` against its keys ``0 .. S - 1``: of a
+    query's keys at or before it, the ``topk`` it scores highest."""
+    Q, S = qi.shape[0], ki.shape[0]
     causal = jnp.arange(S)[None, :] <= first + jnp.arange(Q)[:, None]
     with jax.named_scope(SCOPE_INDEXER):
         scores = index_scores(qi, ki, wi)
         # with no more keys than topk every causal key is selected
         chosen = causal if S <= topk else select_topk(scores, causal, topk)
+    return scores, chosen
+
+
+def _index_kl(scores, chosen, target):
+    """``KL(target || softmax over the chosen keys of scores)`` summed over
+    the queries; ``target [Q, S]`` is the attention probabilities averaged
+    over the heads (rows sum to 1) and carries no gradient."""
+    with jax.named_scope(SCOPE_INDEXER):
+        target = lax.stop_gradient(target)
+        log_index = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), axis=-1)
+        log_target = jnp.log(jnp.where(target > 0, target, 1.0))
+        return jnp.sum(jnp.where(target > 0, target * (log_target - log_index), 0.0))
+
+
+def _attend_selected(q, k, v, chosen):
+    """``(o [Q, H * d], target [Q, S])`` of ``q [Q, H, d]`` against ``k``/``v``
+    ``[S, G, d]`` over the ``chosen [Q, S]`` keys: the heads' outputs and the
+    attention probabilities averaged over the heads. Plain jnp: what
+    ops/sparse_attention.py's kernel pair computes, and its tests' oracle."""
+    Q, H, d = q.shape
+    G = k.shape[1]
     logits = jnp.einsum("qghd,sgd->ghqs", q.reshape(Q, G, H // G, d), k) / math.sqrt(d)
     logits = jnp.where(chosen, logits.astype(jnp.float32), -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("ghqs,sgd->qghd", probs.astype(v.dtype), v).reshape(Q, H * d)
     with jax.named_scope(SCOPE_INDEXER):
-        target = lax.stop_gradient(jnp.sum(probs, axis=(0, 1)) / H)  # rows sum to 1
-        log_index = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), axis=-1)
-        log_target = jnp.log(jnp.where(target > 0, target, 1.0))
-        kl = jnp.sum(jnp.where(target > 0, target * (log_target - log_index), 0.0))
-    return o, kl
+        target = jnp.sum(probs, axis=(0, 1)) / H  # rows sum to 1
+    return o, target
+
+
+def _attend_chunk(q, k, v, qi, ki, wi, first: int, topk: int):
+    """One row's queries ``first .. first + Q - 1`` against its keys ``0 ..
+    first + Q - 1``: ``q [Q, H, d]``, ``k``/``v`` ``[S, G, d]``, indexer
+    ``qi [Q, J, dI]``, ``ki [S, dI]``, ``wi [Q, J]``. Returns ``(o [Q, H *
+    d], sum over the queries of the indexer's KL)``. XLA's path."""
+    scores, chosen = _select(qi, ki, wi, first, topk)
+    o, target = _attend_selected(q, k, v, chosen)
+    return o, _index_kl(scores, chosen, target)
+
+
+def _chunks(T: int, q_chunk: int):
+    """``(first, last)`` of each chunk of queries: ``q_chunk`` at a time, the
+    whole row where that does not cut it."""
+    if T % q_chunk:
+        q_chunk = T
+    return [(first, first + q_chunk) for first in range(0, T, q_chunk)]
 
 
 def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, q_chunk: int):
     """All rows: ``q [R, T, H, d]``, ``k``/``v`` ``[R, T, G, d]``, ``qi [R, T,
     J, dI]``, ``ki [R, T, dI]``, ``wi [R, T, J]`` -> ``(o [R, T, H * d],
     KL summed over rows and queries)``."""
-    T = q.shape[1]
-    if T % q_chunk:
-        q_chunk = T
     outs, kl = [], jnp.zeros((), jnp.float32)
-    for first in range(0, T, q_chunk):
-        last = first + q_chunk
+    for first, last in _chunks(q.shape[1], q_chunk):
         one_row = jax.checkpoint(
             lambda row, first=first: _attend_chunk(*row, first=first, topk=topk))
         o, kls = lax.map(one_row, (q[:, first:last], k[:, :last], v[:, :last],
@@ -197,6 +238,42 @@ def sparse_attention(q, k, v, qi, ki, wi, *, topk: int, q_chunk: int):
         outs.append(o)
         kl = kl + jnp.sum(kls)
     return jnp.concatenate(outs, axis=1), kl
+
+
+def sparse_attention_kernel(q, k, v, qi, ki, wi, *, topk: int, q_chunk: int,
+                            interpret: bool = False):
+    """``sparse_attention`` with everything between the selection and the
+    output in ``ops/sparse_attention.py``'s kernel pair, whole rows a call:
+    the indexer scores and selects a chunk of queries at a time as above
+    (recomputed in the backward pass: its ``[J, Q, S]`` products are the only
+    score-sized tensors left), the chunks' masks are laid side by side into
+    one ``[R, T, T]`` int8 mask, and the KL takes the kernel's head-averaged
+    probabilities chunk by chunk. The two paths share ``_select`` and
+    ``_index_kl`` and nothing else."""
+    R, T, H, d = q.shape
+    chunks = _chunks(T, q_chunk)
+    selected = []
+    for first, last in chunks:
+        one_row = jax.checkpoint(
+            lambda row, first=first: _select(*row, first=first, topk=topk))
+        selected.append(lax.map(one_row, (qi[:, first:last], ki[:, :last], wi[:, first:last])))
+    mask = jnp.concatenate(
+        [jnp.pad(chosen.astype(jnp.int8), ((0, 0), (0, 0), (0, T - last)))
+         for (_, last), (_, chosen) in zip(chunks, selected)], axis=1)
+    # the kernels take queries minor: [R, H*d, T] and [R, keys, queries]
+    o_t, target_t = kernel_ops.attend(
+        q.transpose(0, 2, 3, 1).reshape(R, H * d, T), k.reshape(R, T, -1),
+        v.reshape(R, T, -1), mask.swapaxes(1, 2), n_heads=H, interpret=interpret)
+    kl = jnp.zeros((), jnp.float32)
+    for (first, last), (scores, chosen) in zip(chunks, selected):
+        kl = kl + _index_kl(scores, chosen, target_t[:, :last, first:last].swapaxes(1, 2))
+    return o_t.swapaxes(1, 2), kl
+
+
+def _interpret_kernel() -> bool:
+    """The kernel pair runs compiled on a TPU; anywhere else only its tests
+    call it, interpreted."""
+    return jax.default_backend() != "tpu"
 
 
 class SparseAttention(nn.Module):
@@ -214,6 +291,20 @@ class SparseAttention(nn.Module):
     rope_theta: float
     mrope_section: Sequence[int]
     dtype: Any = jnp.float32
+    # scores, mask, softmax and values through ops/sparse_attention.py's
+    # kernel pair. Set by the owner that knows the mesh holds ONE device and
+    # the backend is a TPU (train.supcon.build); the dtype and the row's
+    # shape can still say no (kernel_reason).
+    kernel: bool = False
+
+    def kernel_reason(self, tokens: int) -> Optional[str]:
+        """Why rows of ``tokens`` keep XLA's path through this layer, or
+        None: the kernel pair is float32 in and out, at a shape it tiles
+        within its VMEM budget. ``__call__`` and ``attention_plan`` both ask
+        here."""
+        if self.dtype != jnp.float32:
+            return f"compute dtype {jnp.dtype(self.dtype).name}"
+        return kernel_ops.unsupported(tokens, self.n_heads, self.n_kv_heads, self.head_dim)
 
     @nn.compact
     def __call__(self, h: jax.Array) -> tuple:
@@ -231,6 +322,9 @@ class SparseAttention(nn.Module):
         w, h = tie_gradients((w, h))
         w = {name: x.astype(self.dtype) for name, x in w.items()}
         cos, sin = rope_tables(grid, d, self.rope_theta, self.mrope_section)
+        attend = sparse_attention
+        if self.kernel and self.kernel_reason(T) is None:
+            attend = functools.partial(sparse_attention_kernel, interpret=_interpret_kernel())
 
         def some_rows(h):
             n = h.shape[0]
@@ -245,7 +339,7 @@ class SparseAttention(nn.Module):
                 qi = (held @ w["index_q"]).reshape(n, T, J, dI)
                 ki = held @ w["index_k"]
                 wi = held @ w["index_w"]
-            o, kl = sparse_attention(q, k, v, qi, ki, wi, topk=self.topk, q_chunk=self.q_chunk)
+            o, kl = attend(q, k, v, qi, ki, wi, topk=self.topk, q_chunk=self.q_chunk)
             return h + (o @ w["o"]).astype(h.dtype), kl
 
         # a few rows at a time, recomputed in the backward pass: the layer's

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from simclr_pytorch_distributed_tpu.models.experts import ExpertLayer
-from simclr_pytorch_distributed_tpu.models.resnet import MODEL_DICT
+from simclr_pytorch_distributed_tpu.models.resnet import MODEL_DICT, build_encoder
 from simclr_pytorch_distributed_tpu.models.sparse_attention import (
     SparseAttention,
     normal_init,
@@ -87,20 +87,28 @@ TOKEN_ENCODERS = {
 }
 
 
+def attention_attrs(s: TokenEncoderSpec, dtype, kernel: bool) -> dict:
+    """The attributes of a block's ``SparseAttention``: what ``Block`` builds
+    its layer from and ``attention_plan`` asks the same layer with."""
+    return dict(
+        n_heads=s.n_heads, n_kv_heads=s.n_kv_heads, head_dim=s.head_dim,
+        index_heads=s.index_heads, index_dim=s.index_dim, topk=s.topk,
+        q_chunk=s.q_chunk, rope_theta=s.rope_theta, mrope_section=s.mrope_section,
+        dtype=dtype, kernel=kernel)
+
+
 class Block(nn.Module):
     spec: TokenEncoderSpec
     dtype: Any = jnp.float32
     remat: bool = False
+    attn_kernel: bool = False
 
     @nn.compact
     def __call__(self, h: jax.Array, train: bool):
         s = self.spec
         wrap = nn.remat if self.remat else (lambda cls: cls)
         h, kl = wrap(SparseAttention)(
-            n_heads=s.n_heads, n_kv_heads=s.n_kv_heads, head_dim=s.head_dim,
-            index_heads=s.index_heads, index_dim=s.index_dim, topk=s.topk,
-            q_chunk=s.q_chunk, rope_theta=s.rope_theta, mrope_section=s.mrope_section,
-            dtype=self.dtype, name="attn")(h)
+            **attention_attrs(s, self.dtype, self.attn_kernel), name="attn")(h)
         h, routed = wrap(ExpertLayer)(
             n_experts=s.n_experts, top_k=s.top_k, width=s.expert_width, held=s.held,
             capacity_factor=s.capacity_factor, dtype=self.dtype, name="moe")(h)
@@ -119,6 +127,10 @@ class TokenEncoder(nn.Module):
     spec: Optional[TokenEncoderSpec] = None
     dtype: Any = jnp.float32
     remat: bool = False  # each block's attention and expert layer recomputed in the backward
+    # attention through ops/sparse_attention.py's kernel pair: set by
+    # train.supcon.build on a one-device TPU mesh; each layer's dtype and
+    # shape can still say no (SparseAttention.kernel_reason)
+    attn_kernel: bool = False
     # the ring columns this encoder sows beside its ``aux_loss``; an encoder
     # without the attribute (a ResNet) sows nothing
     aux_metric_keys = AUX_METRIC_KEYS
@@ -141,7 +153,8 @@ class TokenEncoder(nn.Module):
         h = nn.Dense(s.hidden, kernel_init=normal_init, dtype=self.dtype, name="patch_embed")(u)
         aux_loss, sums = jnp.zeros((), jnp.float32), dict.fromkeys(AUX_METRIC_KEYS, 0.0)
         for k in range(s.layers):
-            h, kl, routed = Block(s, self.dtype, self.remat, name=f"block{k}")(h, train)
+            h, kl, routed = Block(s, self.dtype, self.remat, self.attn_kernel,
+                                  name=f"block{k}")(h, train)
             aux_loss = aux_loss + s.balance_coef * routed["balance"] + s.index_coef * kl
             sums["indexer_kl"] += kl
             sums["moe_held_share"] += routed["held_share"]
@@ -153,6 +166,24 @@ class TokenEncoder(nn.Module):
             self.sow(AUX_COLLECTION, key, jax.lax.stop_gradient(total / s.layers),
                      reduce_fn=keep_last, init_fn=lambda: None)
         return jnp.mean(z.astype(jnp.float32), axis=1)
+
+
+def attention_plan(
+    model: str, size: int, owner_reason: Optional[str] = None, **encoder_kwargs
+) -> list:
+    """One ``{"name", "reason"}`` per attention layer of ``model``: ``reason``
+    is None where the layer runs ops/sparse_attention.py's kernel pair over
+    the patch tokens of ``size x size`` views and otherwise says why it stays
+    XLA's: the owner's
+    (``owner_reason``: mesh size, backend) or the layer's own
+    ``SparseAttention.kernel_reason``, which is what its ``__call__`` asks
+    too. An encoder that is no ``TokenEncoder`` has no such layer."""
+    mod = build_encoder(model, **encoder_kwargs)
+    if not isinstance(mod, TokenEncoder):
+        return []
+    layer = SparseAttention(**attention_attrs(mod.spec, mod.dtype, True))
+    reason = owner_reason or layer.kernel_reason((size // mod.spec.patch) ** 2)
+    return [{"name": f"block{k}", "reason": reason} for k in range(mod.spec.layers)]
 
 
 def match_tree(encoder_params: dict) -> Optional[str]:

@@ -24,7 +24,7 @@ import logging
 import math
 import os
 import time
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -257,6 +257,36 @@ def resolve_conv_impl(
     )
 
 
+def _one_tpu_reason(n_devices: int) -> Optional[str]:
+    """Why a kernel written for one TPU's program stays off this run's path
+    whatever the encoder says, or None on a one-device TPU mesh."""
+    if n_devices > 1:
+        return f"{n_devices} devices in the mesh"
+    if jax.default_backend() != "tpu":
+        return f"non-TPU backend ({jax.default_backend()})"
+    return None
+
+
+def _say_kernel_plan(tag: str, what: str, plan: list) -> list:
+    """One banner line ``[tag] N <what>, M on XLA's path; <names>: <why>`` and
+    one ``<tag>_plan`` event (track ``compile``: ``engaged``, ``on_xla``,
+    ``reasons``) for a ``[{"name", "reason"}]`` plan; returns the plan."""
+    reasons: dict = {}
+    for site in plan:
+        if site["reason"] is not None:
+            reasons.setdefault(site["reason"], []).append(site["name"])
+    on_xla = sum(len(names) for names in reasons.values())
+    logging.info(
+        "[%s] %d %s, %d on XLA's path%s", tag, len(plan) - on_xla, what, on_xla,
+        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()),
+    )
+    tracing.event(
+        f"{tag}_plan", track=tracing.COMPILE_TRACK,
+        engaged=len(plan) - on_xla, on_xla=on_xla, reasons=reasons,
+    )
+    return plan
+
+
 def plan_pointwise_bwd(
     cfg: config_lib.SupConConfig, n_devices: int, **encoder_kwargs
 ) -> list:
@@ -269,29 +299,28 @@ def plan_pointwise_bwd(
     tiles within its VMEM budget)."""
     from simclr_pytorch_distributed_tpu.models.resnet import tail_bwd_plan
 
-    owner_reason = None
-    if n_devices > 1:
-        owner_reason = f"{n_devices} devices in the mesh"
-    elif jax.default_backend() != "tpu":
-        owner_reason = f"non-TPU backend ({jax.default_backend()})"
-    plan = tail_bwd_plan(
-        cfg.model, 2 * cfg.batch_size, owner_reason, **encoder_kwargs
-    )
-    reasons: dict = {}
-    for site in plan:
-        if site["reason"] is not None:
-            reasons.setdefault(site["reason"], []).append(site["name"])
-    on_xla = sum(len(names) for names in reasons.values())
-    logging.info(
-        "[pointwise_bwd] %d Bottleneck tails on one backward kernel, %d on "
-        "XLA's path%s", len(plan) - on_xla, on_xla,
-        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()),
-    )
-    tracing.event(
-        "pointwise_bwd_plan", track=tracing.COMPILE_TRACK,
-        engaged=len(plan) - on_xla, on_xla=on_xla, reasons=reasons,
-    )
-    return plan
+    return _say_kernel_plan(
+        "pointwise_bwd", "Bottleneck tails on one backward kernel",
+        tail_bwd_plan(cfg.model, 2 * cfg.batch_size, _one_tpu_reason(n_devices),
+                      **encoder_kwargs))
+
+
+def plan_sparse_attention(
+    cfg: config_lib.SupConConfig, n_devices: int, **encoder_kwargs
+) -> list:
+    """``models.token_encoder.attention_plan`` for this run, said likewise
+    (``[sparse_attention]``, ``sparse_attention_plan``): how many attention
+    layers run ops/sparse_attention.py's kernel pair, how many stay on XLA's
+    path, and why. No flag chooses: one device, a TPU, and what the layer
+    built with ``encoder_kwargs`` says of itself
+    (``SparseAttention.kernel_reason``: float32, a row the kernels tile
+    within their VMEM budget). Silent for an encoder without such layers."""
+    from simclr_pytorch_distributed_tpu.models.token_encoder import attention_plan
+
+    plan = attention_plan(cfg.model, cfg.size, _one_tpu_reason(n_devices), **encoder_kwargs)
+    if not plan:
+        return plan
+    return _say_kernel_plan("sparse_attention", "attention layers on the kernel pair", plan)
 
 
 def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
@@ -353,9 +382,11 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
         conv_impl=conv_impl,
     )
     tail_plan = plan_pointwise_bwd(cfg, n_devices, **encoder_kwargs)
+    attention_plan = plan_sparse_attention(cfg, n_devices, **encoder_kwargs)
     model = SupConResNet(
         model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
         pointwise_bwd=any(site["reason"] is None for site in tail_plan),
+        attn_kernel=any(layer["reason"] is None for layer in attention_plan),
         **encoder_kwargs,
     )
     plan_experts(cfg, model)
