@@ -110,9 +110,8 @@ def _compile_with_flops(update, *example_args):
 BENCH_WINDOW_BATCHES = 8
 
 
-def _setup_pretrain(mesh, batch, size, stem, data_placement="host",
-                    recipe="simclr", moco_queue=0, conv_impl="xla",
-                    conv_dtype="fp32"):
+def _setup_pretrain(mesh, batch, size, data_placement="host",
+                    recipe="simclr", moco_queue=0):
     """The headline workload: fused SimCLR pretrain step (recipe config).
 
     ``data_placement='device'`` benches the resident-store step instead
@@ -155,31 +154,10 @@ def _setup_pretrain(mesh, batch, size, stem, data_placement="host",
     )
     from simclr_pytorch_distributed_tpu.train.supcon_step import SupConStepConfig
 
-    from simclr_pytorch_distributed_tpu.train.supcon import resolve_conv_impl
-
     steps_per_epoch = 50000 // batch
-    # bf16 compute on the MXU; fp32 params/BN stats/loss. The pallas
-    # conv-block arm runs in --conv_dtype compute: 'fp32' is the round-15
-    # arm (whole-trade vs the recorded bf16 XLA headline — kernel fusion
-    # win minus the bf16 give-back), 'bf16' is the round-19 arm (the
-    # like-for-like dtype comparison the headline runs; fused kernels
-    # accumulate fp32 on the MXU, BN statistics stay fp32). vs_baseline
-    # stays pinned to the recorded bf16 XLA headline for BOTH, so each
-    # arm's number is its whole-trade verdict; the config string names
-    # the arm.
-    if conv_impl == "pallas":
-        conv_impl, conv_reason = resolve_conv_impl(
-            "pallas", "resnet50", batch, size, len(jax.devices()),
-            bf16=conv_dtype == "bf16",
-        )
-    else:
-        conv_reason = "explicit request: bitwise-pinned XLA conv path"
-    print(f"[conv_impl] '{conv_impl}': {conv_reason}")
-    pallas_fp32 = conv_impl == "pallas" and conv_dtype == "fp32"
+    # bf16 compute on the MXU; fp32 params/BN stats/loss
     model = SupConResNet(
-        model_name="resnet50", head="mlp", feat_dim=128,
-        dtype=jnp.float32 if pallas_fp32 else jnp.bfloat16,
-        stem=stem, conv_impl=conv_impl,
+        model_name="resnet50", head="mlp", feat_dim=128, dtype=jnp.bfloat16,
     )
     schedule = make_lr_schedule(
         learning_rate=0.5, epochs=100, steps_per_epoch=steps_per_epoch, cosine=True
@@ -239,14 +217,11 @@ def _setup_pretrain(mesh, batch, size, stem, data_placement="host",
         labels = rng.integers(0, 10, size=(batch,)).astype(np.int32)
         sh_images, sh_labels = shard_host_batch((images, labels), mesh)
 
-    dtype_token = "fp32" if pallas_fp32 else "bf16"
     config = (
-        f"{recipe} rn50 cifar-recipe {dtype_token} fused-aug bsz{batch} "
+        f"{recipe} rn50 cifar-recipe bf16 fused-aug bsz{batch} "
         f"loss={loss_impl}"
         + ("" if not moco_queue else f" moco_queue={moco_queue}")
-        + ("" if stem == "conv" else f" stem={stem}")
         + ("" if data_placement == "host" else f" data={data_placement}")
-        + ("" if conv_impl == "xla" else f" conv={conv_impl}/{conv_dtype}")
     )
     return update, sh_images, sh_labels, state, "pretrain", config
 
@@ -342,10 +317,6 @@ def main(argv=None):
 
     ap = argparse.ArgumentParser("throughput bench")
     ap.add_argument(
-        "--stem", choices=["conv", "s2d"], default="conv",
-        help="s2d = space-to-depth stem repack A/B (docs/PERF.md roofline)",
-    )
-    ap.add_argument(
         "--stage", choices=["pretrain", "linear", "ce"], default="pretrain",
         help="workload: contrastive pretrain (headline), linear probe, or "
              "the CE baseline — same methodology for all three",
@@ -378,24 +349,6 @@ def main(argv=None):
              "(multiple of 2*batch_size; forces the dense loss path)",
     )
     ap.add_argument(
-        "--conv_impl", choices=["xla", "pallas"], default="xla",
-        help="encoder conv-block path (ops/pallas_conv.py): 'pallas' "
-             "benches the fused conv+BN+ReLU stem/BasicBlock/Bottleneck "
-             "kernels (--conv_dtype picks fp32 or bf16 compute); default "
-             "'xla' keeps the gated baseline arm exactly today's path. "
-             "vs_baseline stays pinned to the recorded XLA-path headline "
-             "until a new baseline is committed, so the pallas arm's "
-             "number IS the measured whole-trade win/loss",
-    )
-    ap.add_argument(
-        "--conv_dtype", choices=["fp32", "bf16"], default="fp32",
-        help="compute dtype for the --conv_impl pallas arm: 'fp32' is the "
-             "round-15 whole-trade arm, 'bf16' the round-19 like-for-like "
-             "arm against the bf16 XLA headline (fused kernels accumulate "
-             "fp32 on the MXU; BN statistics stay fp32). The ledger "
-             "fingerprint keys on it for non-xla impls",
-    )
-    ap.add_argument(
         "--ledger", nargs="?", const="docs/perf_ledger.jsonl", default="",
         metavar="PATH",
         help="append this run to the longitudinal perf ledger "
@@ -412,25 +365,11 @@ def main(argv=None):
         help="free-form provenance note on the ledger record",
     )
     args = ap.parse_args(argv)
-    if args.stem != "conv" and args.stage != "pretrain":
-        ap.error("--stem applies to --stage pretrain only")
     if args.data_placement != "host" and args.stage != "pretrain":
         ap.error("--data_placement applies to --stage pretrain only")
     if ((args.recipe != "simclr" or args.moco_queue)
             and args.stage != "pretrain"):
         ap.error("--recipe/--moco_queue apply to --stage pretrain only")
-    if args.conv_impl != "xla" and args.stage != "pretrain":
-        ap.error("--conv_impl applies to --stage pretrain only")
-    if args.conv_dtype != "fp32" and args.conv_impl != "pallas":
-        # the xla arm is always the pinned bf16 headline path; conv_dtype
-        # selects between the pallas arms only
-        ap.error("--conv_dtype applies to --conv_impl pallas only")
-    if args.conv_impl == "pallas" and args.stem != "conv":
-        # honored-or-raise: the fused stem kernel implements the 'conv'
-        # stem only — a pallas-labeled s2d run would record its stem as a
-        # pure-XLA measurement under the pallas ledger fingerprint
-        ap.error("--conv_impl pallas requires the default --stem conv "
-                 "(the fused kernel implements the conv stem only)")
 
     from simclr_pytorch_distributed_tpu.parallel.mesh import create_mesh
     from simclr_pytorch_distributed_tpu.train.supcon import (
@@ -447,9 +386,8 @@ def main(argv=None):
 
     if args.stage == "pretrain":
         setup = _setup_pretrain(
-            mesh, batch, size, args.stem, data_placement=args.data_placement,
+            mesh, batch, size, data_placement=args.data_placement,
             recipe=args.recipe, moco_queue=args.moco_queue,
-            conv_impl=args.conv_impl, conv_dtype=args.conv_dtype,
         )
     elif args.stage == "linear":
         setup = _setup_linear(mesh, batch, size)
@@ -517,21 +455,16 @@ def main(argv=None):
         "value": round(per_chip, 1),
         "unit": "imgs/s/chip",
         # baselines were recorded at the recipe defaults on ONE baseline
-        # chip (256 imgs/chip); a non-default batch/stem, a multi-chip mesh
+        # chip (256 imgs/chip); a non-default batch, a multi-chip mesh
         # (global 256 shards to 256/n imgs/chip — a different per-chip
         # workload, see bench_perchip32_r5.json), or any other accelerator
         # is not a regression signal. A non-default --recipe/--moco_queue
         # arm KEEPS vs_baseline: the comparison against the supcon-family
         # headline is the recipe-overhead measurement (the ratchet bench
         # gate only runs the default arm, so the bar never binds on it).
-        # Likewise --conv_impl pallas (either --conv_dtype arm):
-        # vs_baseline stays pinned to the recorded bf16 XLA headline
-        # until a new baseline is committed, so each pallas arm reports
-        # its measured whole-trade win/loss.
         "vs_baseline": (
             vs_baseline_for(metric_stage, per_chip)
-            if args.batch_size == 256 and args.stem == "conv"
-            and args.data_placement == "host"
+            if args.batch_size == 256 and args.data_placement == "host"
             and n_chips == 1 and device_kind == REPO_BASELINE_DEVICE_KIND
             else 1.0
         ),
@@ -539,12 +472,6 @@ def main(argv=None):
             "global_batch": batch,
             "recipe": getattr(args, "recipe", "simclr"),
             "moco_queue": getattr(args, "moco_queue", 0),
-            # the explicit conv path (honored-or-raise, so the flag IS the
-            # effective impl): the ledger fingerprint keys on it so
-            # regression scans never compare across kernel implementations
-            # (and, for non-xla impls, across compute dtypes)
-            "conv_impl": getattr(args, "conv_impl", "xla"),
-            "conv_dtype": getattr(args, "conv_dtype", "fp32"),
             "chips": n_chips,
             "device_kind": device_kind,
             "total_imgs_per_sec": round(imgs_per_sec, 1),

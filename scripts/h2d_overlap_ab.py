@@ -104,9 +104,7 @@ def main():
         ap.error("--runs must be >= 1")
 
     mesh = create_mesh()
-    update, sh_images, sh_labels, state, _, _ = bench._setup_pretrain(
-        mesh, BATCH, SIZE, "conv"
-    )
+    update, sh_images, sh_labels, state, _, _ = bench._setup_pretrain(mesh, BATCH, SIZE)
     fn, flops, _ = bench._compile_with_flops(
         update, state, sh_images, sh_labels, jax.random.key(0)
     )

@@ -82,12 +82,7 @@ def git_rev(repo=REPO):
 
 def fingerprint_for(stage, detail):
     """The workload identity under which throughput is comparable: stage +
-    bench config string + global batch + device kind + chips — and the
-    conv-kernel implementation, so the regression scan never compares
-    across ``--conv_impl`` arms (a pallas-arm number must not mask or
-    fake an xla-path regression). The default 'xla' (and records predating
-    the flag) key exactly as before, so the committed history's
-    fingerprints stay stable (pure)."""
+    bench config string + global batch + device kind + chips (pure)."""
     ident = {
         "stage": stage,
         "config": detail.get("config"),
@@ -95,14 +90,6 @@ def fingerprint_for(stage, detail):
         "device_kind": detail.get("device_kind"),
         "chips": detail.get("chips"),
     }
-    conv_impl = detail.get("conv_impl", "xla")
-    if conv_impl != "xla":
-        ident["conv_impl"] = conv_impl
-        # the pallas arm exists in fp32 AND bf16 compute (round 19): the
-        # dtype changes the workload, so the scan must not compare across
-        # it. Scoped to non-xla impls so every committed record (all xla
-        # so far) keys exactly as before.
-        ident["conv_dtype"] = detail.get("conv_dtype", "fp32")
     blob = json.dumps(ident, sort_keys=True).encode()
     return hashlib.sha1(blob).hexdigest()[:12]
 

@@ -192,18 +192,6 @@ CONFIGS = {
     # list.
     "perf_ledger": dict(model=None, epochs=0, bar=None, kind="perf_ledger",
                         dataset=None, artifact="docs/perf_ledger.jsonl"),
-    # round 15: the fused conv-block gate (scripts/convblock_ab.py --smoke;
-    # ops/pallas_conv.py). Binds EVERYWHERE on parity_ok — the interpret-
-    # mode fused residual-block kernel matching the bitwise-pinned Flax
-    # block in value, all seven gradients, and BN batch stats (parity is
-    # hardware-independent; it is the contract that lets --conv_impl swap
-    # without touching the accuracy ratchets). The timing claim (the
-    # pallas arm removing the injected per-HBM-traversal delay) is a
-    # CPU-calibrated proxy and pass-skips off-CPU with the reason on
-    # record (the resident_ab/window_ab convention). Seconds, so it rides
-    # the default list.
-    "convblock": dict(model=None, epochs=0, bar=None, kind="convblock_ab",
-                      dataset="synthetic"),
     # round 14: the static invariant-lint gate (docs/ANALYSIS.md). Runs
     # scripts/invariant_lint.py over the tree — stdlib ast, no driver, no
     # device — and binds on the pure lint_gate_record EVERYWHERE: zero
@@ -258,9 +246,10 @@ CONFIGS = {
     # that IVF recall@k cleared the artifact's recall bar on every rung
     # (both are properties of the recorded answers, not the hardware).
     # The >=5x p50 query-speedup claim at the top rung is CPU-calibrated
-    # and pass-skips off-CPU with the reason on record (the convblock
-    # convention). Re-produce the artifact with the A/B script when the
-    # retrieval surface changes; instant, so it rides the default list.
+    # and pass-skips off-CPU with the reason on record (the
+    # resident_ab/window_ab convention). Re-produce the artifact with the
+    # A/B script when the retrieval surface changes; instant, so it rides
+    # the default list.
     "retrieval_ab": dict(model=None, epochs=0, bar=None,
                          kind="retrieval_gate", dataset=None,
                          artifact="docs/evidence/retrieval_ab_r18.json"),
@@ -402,75 +391,6 @@ def window_gate_record(artifact):
         artifact, "window", "window_ms_per_step",
         extra_keys=("window_batches",),
     )
-
-
-def convblock_gate_record(artifact):
-    """Gate decision for one convblock_ab artifact (pure — tested without
-    a kernel run).
-
-    Since round 19 the artifact (schema convblock_ab/v2) carries one
-    section per admitted block kind x compute dtype (basic, proj,
-    bottleneck, each fp32 and bf16). ``parity_ok`` (interpret-mode fused
-    kernel == Flax block: value, ALL gradients, every BN stat pair within
-    that kind's pinned tolerances — fp32 abs, bf16 the derived
-    scaled-maxabs + cosine pins) binds PER KIND on EVERY device — kernel
-    correctness is hardware-independent. The timing claim (the pallas arm
-    beating the xla arm under the injected bytes-scaled per-HBM-traversal
-    delay) binds per kind only on CPU, where the proxy is calibrated;
-    elsewhere the gate pass-skips the timing with the reason on record
-    (the placement A/Bs' convention). One broken kind fails the whole
-    gate — the conv_impl resolution banner admits sites kind-by-kind, so
-    every kind a real run could route through must hold.
-    """
-    record = {
-        "metric": "ratchet_convblock_ab_parity",
-        # value = kinds gated (main's summary table requires the key on
-        # every record; a per-kind gate has no single ms number to report)
-        "value": len(artifact["blocks"]),
-        "parity_ok": artifact["parity_ok"],
-        "device": artifact["device"],
-        "kinds": {},
-    }
-    failures = []
-    timing_bound = artifact["device"] == "cpu"
-    for kind, b in sorted(artifact["blocks"].items()):
-        s = b["summary"]
-        parity = b["parity"]
-        entry = {
-            "parity_ok": parity["parity_ok"],
-            "pallas_ms_per_step": s.get("pallas_ms_per_step"),
-            "xla_ms_per_step": s.get("xla_ms_per_step"),
-            "traversals": b.get("traversals", {}),
-            "max_abs_diffs": parity["max_abs_diffs"],
-        }
-        record["kinds"][kind] = entry
-        if not parity["parity_ok"]:
-            failures.append(
-                f"{kind}: fused kernel diverges from the Flax block "
-                f"(value_ok={parity['value_ok']} "
-                f"grads_ok={parity['grads_ok']} "
-                f"stats_ok={parity['stats_ok']})"
-            )
-            continue
-        if timing_bound and not (
-            s["pallas_ms_per_step"] is not None
-            and s["xla_ms_per_step"] is not None
-            and s["pallas_ms_per_step"] < s["xla_ms_per_step"]
-        ):
-            failures.append(
-                f"{kind}: pallas arm not faster under the injected "
-                f"per-traversal delay"
-            )
-    record["ok"] = not failures
-    if failures:
-        record["error"] = "; ".join(failures)
-    elif not timing_bound:
-        record["skipped"] = (
-            f"device {artifact['device']!r}: injected-delay timing proxy "
-            f"calibrated for CPU only; per-kind kernel parity still "
-            f"enforced"
-        )
-    return record
 
 
 def trace_report_gate_record(artifact):
@@ -880,7 +800,7 @@ def retrieval_gate_record(artifact):
     oracle), and IVF recall@k cleared the artifact's recall bar on every
     rung. The p50 query-speedup claim at the top rung is CPU-calibrated
     (single-row latency against the jitted brute scorer on host) and
-    pass-skips off-CPU with the reason on record (the convblock
+    pass-skips off-CPU with the reason on record (the resident_ab/window_ab
     convention)."""
     summary = artifact.get("summary", {})
     oracle = artifact.get("oracle", {})
@@ -1184,39 +1104,6 @@ def run_config(name, spec, epochs, bar, args):
         gate = (resident_gate_record if kind == "resident_ab"
                 else window_gate_record)
         record = gate(artifact)
-        record["bar"] = bar
-        record["log"] = ab_log
-        print(json.dumps(record), flush=True)
-        return record
-
-    if kind == "convblock_ab":
-        # the fused conv-block gate: per-kind interpret-mode kernel parity
-        # (all six block-kind x dtype sections) + the CPU-proxy traversal
-        # timing (convblock_gate_record); stale artifact removed BEFORE
-        # the producer runs (the PR-14 crashed-producer convention);
-        # --rounds 1 keeps the six-section smoke in gate time — the
-        # committed evidence artifact carries the full-round runs
-        ab_json = _fresh_artifact_path(os.path.join(logs, f"{kind}.json"))
-        ab_log = os.path.join(logs, f"{kind}.log")
-        try:
-            run(
-                [sys.executable, "scripts/convblock_ab.py", "--smoke",
-                 "--rounds", "1", "--json", ab_json],
-                ab_log,
-            )
-        except ConfigFailed:
-            # convblock_ab exits nonzero on broken parity but still
-            # writes the artifact — fall through so the gate record
-            # carries the structured per-tensor diffs (the health_report
-            # convention); re-raise only with no artifact to judge
-            if not os.path.exists(ab_json):
-                raise
-        try:
-            with open(ab_json) as f:
-                artifact = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            raise ConfigFailed(f"{kind} wrote no artifact: {e}") from e
-        record = convblock_gate_record(artifact)
         record["bar"] = bar
         record["log"] = ab_log
         print(json.dumps(record), flush=True)

@@ -18,7 +18,7 @@ property over two region kinds:
 - **Pallas kernel builders**: any local function handed to
   ``pl.pallas_call`` as the kernel — directly, or through a
   ``functools.partial(<kernel>, ...)`` (possibly via an intermediate
-  assignment, the ops/pallas_loss.py / ops/pallas_conv.py shape). A host
+  assignment, the ops/pallas_loss.py / ops/sparse_attention.py shape). A host
   sync inside a kernel body would either fail the TPU lowering or
   silently constant-fold in interpret mode while the compiled path
   diverges — both review-time findings.

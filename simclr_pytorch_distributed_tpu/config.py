@@ -94,15 +94,9 @@ class SupConConfig:
     # 'ring' streams contrast blocks around the data axis with ppermute
     # (parallel/collectives.py) for large-global-batch memory scaling
     loss_impl: str = "auto"
-    # conv-block implementation for the encoder's hot path: 'pallas' routes
-    # the stem, BasicBlocks (identity AND projection/stride-2 shortcuts),
-    # and rn50-family Bottlenecks through the fused conv+BN+ReLU kernels
-    # (ops/pallas_conv.py — the inter-op activation round-trips that fund
-    # XLA's stage-1 BN-backward/residual fusions never touch HBM), in fp32
-    # or bf16 compute (fp32 MXU accumulation, fp32 BN statistics); 'xla'
-    # is the bitwise-pinned default path; 'auto' resolves to 'xla' until a
-    # chip cell shows a fused kind compiling and winning (ROADMAP A1;
-    # train.supcon.resolve_conv_impl, startup banner names the resolution)
+    # retired (PR 30): the encoder has one conv path, XLA's. The field and
+    # the flag stay because benchmark/configs/*.json pass '--conv_impl auto';
+    # nothing reads it (ROADMAP D14)
     conv_impl: str = "auto"
     # 'sgd' is the published recipe (util.py:79-84); 'lars' for the
     # large-global-batch configs (SimCLR ImageNet bs=4096, BASELINE configs[4])
@@ -349,15 +343,13 @@ def supcon_parser() -> argparse.ArgumentParser:
     p.add_argument("--loss_impl", type=str, default=d.loss_impl,
                    choices=["auto", "dense", "fused", "ring"])
     p.add_argument("--conv_impl", type=str, default=d.conv_impl,
-                   choices=["auto", "xla", "pallas"],
-                   help="encoder conv-block path: fused Pallas "
-                        "conv+BN+ReLU kernels (ops/pallas_conv.py) for "
-                        "the stem, BasicBlocks (identity and "
-                        "projection/stride-2 shortcuts), and rn50-family "
-                        "Bottlenecks, fp32 or bf16 compute, vs the "
-                        "bitwise-pinned XLA path; 'auto' = xla (no fused "
-                        "kind has yet compiled and won on the chip; "
-                        "startup banner names the resolution)")
+                   choices=["auto", "xla"],
+                   help="retired: the encoder has one conv path, XLA's, and "
+                        "both values mean it (the whole-block Pallas kernels "
+                        "behind the former 'pallas' were never timed on the "
+                        "chip, and its compiler refused five of the twelve "
+                        "at the launcher's geometry); still accepted because "
+                        "the benchmark's configurations pass it")
     p.add_argument("--optimizer", type=str, default=d.optimizer,
                    choices=["sgd", "lars"],
                    help="lars: layer-adaptive scaling for large global batches")
@@ -534,22 +526,6 @@ def validate_data_placement(dataset: str, data_placement: str) -> None:
         )
 
 
-def validate_conv_impl(cfg: SupConConfig) -> None:
-    """Parse-time seam for --conv_impl interactions (the
-    validate_data_placement convention: reject up front what would
-    otherwise silently no-op far from the flag).
-
-    Deliberately empty since round 19: the fused kernels carry bf16
-    variants (fp32 MXU accumulation, fp32 BN statistics), so
-    ``--conv_impl pallas --bf16`` is a real configuration, admitted
-    site-by-site at RESOLUTION time (train.supcon.resolve_conv_impl —
-    explicit pallas raises there only where zero sites admit, 'auto'
-    degrades with the reason in the startup banner). The seam stays so a
-    future parse-time contradiction has a pinned home and the call site
-    in finalize_supcon keeps its ordering guarantee.
-    """
-
-
 def validate_model(cfg: SupConConfig) -> None:
     """Parse-time check of --model against the encoders there are
     (models/resnet.MODEL_DICT), and of --size against a token encoder's
@@ -572,10 +548,10 @@ def impl_resolution_banner(
     flag: str, requested: str, resolved: str, reason: str
 ) -> str:
     """One-line startup banner for an impl-resolution ladder
-    (``--loss_impl`` / ``--conv_impl`` — the data_placement ladder
-    convention): names the RESOLVED implementation and WHY, so a silent
-    degradation (unsupported geometry, non-TPU backend) is discoverable
-    from the log instead of only from the resolution code."""
+    (``--loss_impl`` — the data_placement ladder convention): names the
+    RESOLVED implementation and WHY, so a silent degradation (unsupported
+    geometry, non-TPU backend) is discoverable from the log instead of only
+    from the resolution code."""
     if requested == resolved:
         return f"[{flag}] '{resolved}': {reason}"
     return f"[{flag}] requested '{requested}' -> resolved '{resolved}': {reason}"
@@ -659,7 +635,6 @@ def parse_supcon(argv=None) -> SupConConfig:
 def finalize_supcon(cfg: SupConConfig, make_dirs: bool = True) -> SupConConfig:
     """Derived fields, replicating main_supcon.py:92-150."""
     validate_data_placement(cfg.dataset, cfg.data_placement)
-    validate_conv_impl(cfg)
     validate_recipe(cfg)
     validate_model(cfg)
     if cfg.dataset == "path":

@@ -122,11 +122,6 @@ class SupConResNet(nn.Module):
     bn_local_groups: int = 1
     bn_group_views: int = 2
     remat: bool = False  # per-block activation remat (models/resnet.py)
-    stem: str = "conv"  # "s2d" = repacked stem experiment (models/resnet.py)
-    # "xla" (bitwise-pinned default) or "pallas": fused conv+BN+ReLU stem/
-    # BasicBlock kernels where the geometry admits (models/resnet.py,
-    # ops/pallas_conv.py); resolve via train.supcon.resolve_conv_impl
-    conv_impl: str = "xla"
     # Bottleneck's tail through ops/pointwise_bwd.py's one backward kernel:
     # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
     pointwise_bwd: bool = False
@@ -143,7 +138,7 @@ class SupConResNet(nn.Module):
             dtype=self.dtype, axis_name=self.axis_name, sync_bn=self.sync_bn,
             bn_local_groups=self.bn_local_groups,
             bn_group_views=self.bn_group_views,
-            remat=self.remat, stem=self.stem, conv_impl=self.conv_impl,
+            remat=self.remat,
             pointwise_bwd=self.pointwise_bwd, attn_kernel=self.attn_kernel,
         )
 

@@ -60,20 +60,21 @@ def running_stats_update(
 
 
 class FusedTrainBN(nn.Module):
-    """Parameter/variable shadow of ``CrossReplicaBatchNorm`` for the fused
-    Pallas conv path (``--conv_impl pallas``, ops/pallas_conv.py).
+    """Parameter/variable shadow of ``CrossReplicaBatchNorm`` for a site
+    whose normalization runs inside a kernel's forward or backward
+    (``models.resnet.Bottleneck._tail_one_backward``, ops/pointwise_bwd.py).
 
-    The fused kernels compute the batch statistics and the normalization
-    INSIDE the conv kernel, so this module only owns what must live in the
-    Flax tree: the affine params and the running-stat variables, under
-    exactly the names/shapes/inits ``CrossReplicaBatchNorm`` creates — the
-    param tree is impl-independent by construction (a ``--conv_impl
-    pallas`` checkpoint restores under ``--conv_impl xla`` and vice versa).
+    The caller computes the batch statistics and the normalization itself,
+    so this module only owns what must live in the Flax tree: the affine
+    params and the running-stat variables, under exactly the
+    names/shapes/inits ``CrossReplicaBatchNorm`` creates — the param tree is
+    the same whether or not the kernel engages, and a checkpoint restores
+    either way.
 
-    Call once with no statistics to fetch ``(scale, bias)`` for the
-    kernel, then AGAIN with the kernel's returned batch moments to apply
-    the running update (``running_stats_update``); train mode only — the
-    eval path stays on the Flax module.
+    Call once with no statistics to fetch ``(scale, bias)``, then AGAIN with
+    the batch moments to apply the running update
+    (``running_stats_update``); train mode only — the eval path stays on
+    the Flax module.
     """
 
     features: int

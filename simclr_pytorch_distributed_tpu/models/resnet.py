@@ -34,7 +34,7 @@ from simclr_pytorch_distributed_tpu.models.norm import (
     CrossReplicaBatchNorm,
     FusedTrainBN,
 )
-from simclr_pytorch_distributed_tpu.ops import pallas_conv, pointwise_bwd
+from simclr_pytorch_distributed_tpu.ops import pointwise_bwd
 
 # planes and first-block stride of the four stages
 _STAGE_WIDTHS = (64, 128, 256, 512)
@@ -45,11 +45,11 @@ conv_kernel_init = nn.initializers.variance_scaling(2.0, "fan_out", "normal")
 
 
 class _ConvKernel(nn.Module):
-    """Parameter shadow of ``nn.Conv`` for the fused Pallas path: owns ONLY
-    the ``kernel`` param, under nn.Conv's name/shape/init/param_dtype, so
-    the param tree is impl-independent (``--conv_impl pallas`` checkpoints
-    restore under ``--conv_impl xla`` and vice versa). Init always traces
-    the XLA branch, so this shadow only ever READS the existing param."""
+    """Parameter shadow of ``nn.Conv`` for a site whose backward is a kernel
+    (``Bottleneck._tail_one_backward``): owns ONLY the ``kernel`` param, under
+    nn.Conv's name/shape/init/param_dtype, so the param tree is the same
+    whether or not the kernel engages. Init always traces the ``nn.Conv``
+    branch, so this shadow only ever READS the existing param."""
 
     shape: Tuple[int, ...]
 
@@ -80,63 +80,10 @@ class BasicBlock(nn.Module):
     expansion: int = 1
     dtype: Any = jnp.float32
     norm: Callable[..., nn.Module] = CrossReplicaBatchNorm
-    # "pallas": route train-mode applies through the fused conv+BN+ReLU
-    # residual-block kernels (ops/pallas_conv.py) when supports_block
-    # admits the geometry — identity-shortcut blocks through
-    # fused_basic_block, projection/stride-2 blocks through
-    # fused_projection_block (the 1x1-conv+BN shortcut rides the same
-    # sequential grid). Everything else (eval mode, init, unsupported
-    # shapes, odd stride-2 dims) stays on the bitwise-pinned XLA path
-    # below. The ResNet owner only passes "pallas" when the BN config is
-    # whole-batch (models/norm.py semantics the kernels implement) and the
-    # compute dtype is fp32 or bf16 (bf16 matmuls accumulate fp32 on the
-    # MXU; BN statistics stay fp32 either way).
-    conv_impl: str = "xla"
 
     @nn.compact
     def __call__(self, x, train: bool = True):  # train is
         # positional-or-keyword so nn.remat can mark it static (argnum 2)
-        if (
-            self.conv_impl == "pallas"
-            and train
-            and not self.is_initializing()
-            and pallas_conv.supports_block(
-                x.shape[0], x.shape[1], x.shape[2], self.planes,
-                stride=self.stride, in_channels=x.shape[-1],
-                dtype=self.dtype,
-            )
-        ):
-            cin = x.shape[-1]
-            k1 = _ConvKernel((3, 3, cin, self.planes), name="Conv_0")()
-            k2 = _ConvKernel((3, 3, self.planes, self.planes), name="Conv_1")()
-            bn1 = FusedTrainBN(self.planes, name="bn1")
-            bn2 = FusedTrainBN(self.planes, name="bn2")
-            g1, b1 = bn1()
-            g2, b2 = bn2()
-            interp = _interpret_pallas()
-            if self.stride == 1 and cin == self.planes:
-                out, m1, v1, m2, v2 = pallas_conv.fused_basic_block(
-                    x, k1, g1, b1, k2, g2, b2, interpret=interp
-                )
-                count = x.shape[0] * x.shape[1] * x.shape[2]
-            else:
-                ks = _ConvKernel((1, 1, cin, self.planes), name="shortcut_conv")()
-                bns = FusedTrainBN(self.planes, name="shortcut_bn")
-                gs, bs = bns()
-                out, m1, v1, m2, v2, mS, vS = pallas_conv.fused_projection_block(
-                    x, k1, g1, b1, k2, g2, b2, ks, gs, bs,
-                    stride=self.stride, interpret=interp,
-                )
-                # all three BNs normalize over the block's OUTPUT grid
-                count = (
-                    x.shape[0]
-                    * (x.shape[1] // self.stride)
-                    * (x.shape[2] // self.stride)
-                )
-                bns(mS, vS, count)
-            bn1(m1, v1, count)  # running-stat update (second call)
-            bn2(m2, v2, count)
-            return out.astype(self.dtype)
         norm = partial(self.norm, use_running_average=not train)
         conv = partial(
             nn.Conv, use_bias=False, kernel_init=conv_kernel_init, dtype=self.dtype,
@@ -168,14 +115,6 @@ class Bottleneck(nn.Module):
     expansion: int = 4
     dtype: Any = jnp.float32
     norm: Callable[..., nn.Module] = CrossReplicaBatchNorm
-    # "pallas": route train-mode applies through fused_bottleneck_block
-    # (ops/pallas_conv.py) — the whole 1x1-3x3-1x1 chain (three BN stages,
-    # plus the 1x1-conv+BN projection shortcut when the shape changes) in
-    # one kernel each way; the 1x1 convs are pure [N·H·W,C]@[C,C']
-    # contractions needing no im2col scratch. Eval mode, init, and
-    # geometries supports_bottleneck rejects stay on the bitwise-pinned
-    # XLA path below.
-    conv_impl: str = "xla"
     # the tail (relu(bn2) -> Conv_2 -> bn3) through ops/pointwise_bwd.py:
     # XLA's forward, one backward kernel. The ResNet owner decides
     # (ResNet.tail_bwd_reason: its attributes and this site's shape); only
@@ -186,53 +125,6 @@ class Bottleneck(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):  # train is
         # positional-or-keyword so nn.remat can mark it static (argnum 2)
-        if (
-            self.conv_impl == "pallas"
-            and train
-            and not self.is_initializing()
-            and self.expansion == 4
-            and pallas_conv.supports_bottleneck(
-                x.shape[0], x.shape[1], x.shape[2], self.planes,
-                stride=self.stride, in_channels=x.shape[-1],
-                dtype=self.dtype,
-            )
-        ):
-            cin = x.shape[-1]
-            c4 = self.expansion * self.planes
-            k1 = _ConvKernel((1, 1, cin, self.planes), name="Conv_0")()
-            k2 = _ConvKernel((3, 3, self.planes, self.planes), name="Conv_1")()
-            k3 = _ConvKernel((1, 1, self.planes, c4), name="Conv_2")()
-            bn1 = FusedTrainBN(self.planes, name="bn1")
-            bn2 = FusedTrainBN(self.planes, name="bn2")
-            bn3 = FusedTrainBN(c4, name="bn3")
-            g1, b1 = bn1()
-            g2, b2 = bn2()
-            g3, b3 = bn3()
-            shortcut = None
-            bns = None
-            if self.stride != 1 or cin != c4:
-                ks = _ConvKernel((1, 1, cin, c4), name="shortcut_conv")()
-                bns = FusedTrainBN(c4, name="shortcut_bn")
-                gs, bs = bns()
-                shortcut = (ks, gs, bs)
-            r = pallas_conv.fused_bottleneck_block(
-                x, k1, g1, b1, k2, g2, b2, k3, g3, b3, shortcut,
-                stride=self.stride, interpret=_interpret_pallas(),
-            )
-            # bn1 sees the input grid (the 1x1 reduce runs pre-stride);
-            # bn2/bn3/shortcut_bn see the strided output grid
-            count1 = x.shape[0] * x.shape[1] * x.shape[2]
-            count2 = (
-                x.shape[0]
-                * (x.shape[1] // self.stride)
-                * (x.shape[2] // self.stride)
-            )
-            bn1(r[1], r[2], count1)  # running-stat update (second call)
-            bn2(r[3], r[4], count2)
-            bn3(r[5], r[6], count2)
-            if bns is not None:
-                bns(r[7], r[8], count2)
-            return r[0].astype(self.dtype)
         norm = partial(self.norm, use_running_average=not train)
         conv = partial(
             nn.Conv, use_bias=False, kernel_init=conv_kernel_init, dtype=self.dtype,
@@ -295,29 +187,10 @@ class ResNet(nn.Module):
     # default per-GPU BatchNorm2d; see models/norm.py); 1 = whole-batch stats
     bn_local_groups: int = 1
     bn_group_views: int = 1
-    # "conv": the reference 3x3/s1 stem. "s2d": 2x2 space-to-depth repacked
-    # stem (throughput experiment, NOT in the reference): the 3-channel conv
-    # wastes ~80% of the MXU's 128 input lanes (K=27 after im2col); repacking
-    # to [H/2, W/2, 12] and convolving 108->256 packed channels halves the
-    # padded MXU work, then depth-to-space restores [H, W, 64] so every later
-    # layer is unchanged. Slightly larger hypothesis class (6x6 receptive
-    # field); not weight-compatible with the reference stem.
-    stem: str = "conv"
     # activation rematerialization per residual block: backward recomputes
     # each block's activations instead of keeping them in HBM — the standard
     # FLOPs-for-memory trade for bigger per-chip batches (identical numerics)
     remat: bool = False
-    # "xla" (default, bitwise-pinned) or "pallas": fused conv+BN+ReLU
-    # kernels (ops/pallas_conv.py) for the stem, BasicBlocks (identity AND
-    # projection/stride-2 shortcuts), and rn50-family Bottlenecks whose
-    # geometry the per-site supports_* gates admit; only effective in
-    # train mode under whole-batch BN statistics and fp32/bf16 compute
-    # (bf16 matmuls accumulate fp32; BN statistics stay fp32) —
-    # everything else falls back per-site to the XLA path. Resolve from
-    # the --conv_impl flag via train.supcon.resolve_conv_impl; the
-    # per-site plan is fused_site_plan below (single-sourced with the
-    # resolution banner).
-    conv_impl: str = "xla"
     # Bottleneck's tail through one backward kernel (ops/pointwise_bwd.py).
     # Set by the owner that knows the mesh holds ONE device and the backend
     # is a TPU (train.supcon.build); the attributes above and each site's
@@ -339,8 +212,6 @@ class ResNet(nn.Module):
         ops/pointwise_bwd.py is float32 whole-batch BN in one program, at a
         shape it tiles (the stride does not enter: the tail is pointwise).
         ``__call__`` and ``tail_bwd_plan`` both ask here."""
-        if self.conv_impl == "pallas":
-            return "--conv_impl pallas takes whole blocks"
         if self.dtype != jnp.float32:
             return f"compute dtype {jnp.dtype(self.dtype).name}"
         if self.axis_name is not None:
@@ -362,64 +233,19 @@ class ResNet(nn.Module):
             if self.remat else self.block_cls
         )
         x = x.astype(self.dtype)
-        # fused kernels implement whole-batch train-mode BN only: the
-        # grouped per-device mode (sync=False, local_groups>1) and explicit
-        # axis_name reductions stay on the Flax path (models/norm.py).
-        # Compute dtype may be fp32 or bf16 (the kernels accumulate fp32
-        # on the MXU and keep BN statistics fp32 either way).
-        fused_ok = (
-            self.conv_impl == "pallas"
-            and self.axis_name is None
-            and (self.sync_bn or self.bn_local_groups == 1)
-            and self.dtype in (jnp.float32, jnp.bfloat16)
-        )
-        block_conv_impl = "pallas" if fused_ok else "xla"
-        if (
-            fused_ok
-            and self.stem == "conv"
-            and train
-            and not self.is_initializing()
-            and pallas_conv.supports_stem(
-                x.shape[0], x.shape[1], x.shape[2], x.shape[3], 64,
-                dtype=self.dtype,
-            )
-        ):
-            kernel = _ConvKernel((3, 3, x.shape[-1], 64), name="conv1")()
-            bn1 = FusedTrainBN(64, name="bn1")
-            g, b = bn1()
-            x, m, v = pallas_conv.fused_conv_bn_relu(
-                x, kernel, g, b, interpret=_interpret_pallas()
-            )
-            count = x.shape[0] * x.shape[1] * x.shape[2]
-            bn1(m, v, count)  # running-stat update (second call)
-            x = x.astype(self.dtype)
-        elif self.stem == "s2d":
-            n, h, w, c = x.shape
-            x = x.reshape(n, h // 2, 2, w // 2, 2, c)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(n, h // 2, w // 2, 4 * c)
-            x = nn.Conv(
-                4 * 64, (3, 3), strides=(1, 1), use_bias=False, padding=PAD3,
-                kernel_init=conv_kernel_init, dtype=self.dtype,
-                param_dtype=jnp.float32, name="conv1_s2d",
-            )(x)
-            x = x.reshape(n, h // 2, w // 2, 2, 2, 64)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(n, h, w, 64)
-        else:
-            x = nn.Conv(
-                64, (3, 3), strides=(1, 1), use_bias=False, padding=PAD3,
-                kernel_init=conv_kernel_init, dtype=self.dtype,
-                param_dtype=jnp.float32, name="conv1",
-            )(x)
-            x = nn.relu(norm(use_running_average=not train, name="bn1")(x))
-        if self.stem == "s2d":
-            x = nn.relu(norm(use_running_average=not train, name="bn1")(x))
+        x = nn.Conv(
+            64, (3, 3), strides=(1, 1), use_bias=False, padding=PAD3,
+            kernel_init=conv_kernel_init, dtype=self.dtype,
+            param_dtype=jnp.float32, name="conv1",
+        )(x)
+        x = nn.relu(norm(use_running_average=not train, name="bn1")(x))
         for name, width, stride in self.block_sites():
             tail = {}
             if self.pointwise_bwd and issubclass(self.block_cls, Bottleneck):
                 tail["tail_bwd"] = self.tail_bwd_reason(x.shape[0], width) is None
             x = block_cls(
                 planes=width, stride=stride, dtype=self.dtype, norm=norm,
-                conv_impl=block_conv_impl, name=name, **tail,
+                name=name, **tail,
             )(x, train)
         x = jnp.mean(x, axis=(1, 2))  # global average pool (AdaptiveAvgPool2d((1,1)))
         return x.astype(jnp.float32)
@@ -468,8 +294,8 @@ MODEL_DICT: dict[str, Tuple[Callable[..., nn.Module], int]] = {
 
 def build_encoder(model: str, **flags) -> nn.Module:
     """The encoder ``model`` names, built with those of ``flags`` that its
-    module declares: a ResNet takes the conv and BN flags, an encoder that has
-    neither is not handed them."""
+    module declares: a ResNet takes the BN flags, an encoder that has no batch
+    norm is not handed them."""
     ctor, _ = MODEL_DICT[model]
     declared = {f.name for f in dataclasses.fields(ctor())}
     return ctor(**{k: v for k, v in flags.items() if k in declared})
@@ -492,73 +318,3 @@ def tail_bwd_plan(
         {"name": name, "reason": owner_reason or mod.tail_bwd_reason(rows, width)}
         for name, width, _ in mod.block_sites()
     ]
-
-
-def fused_site_plan(
-    model: str, rows: int, size: int, dtype: Any = jnp.float32
-) -> list:
-    """The single-sourced per-site geometry walk for ``--conv_impl pallas``.
-
-    Mirrors ``ResNet.__call__``'s stage loop exactly and consults the same
-    ``ops/pallas_conv.supports_*`` gates the block modules call with their
-    runtime input shapes — so the resolution banner
-    (train.supcon.resolve_conv_impl), the per-site module gate, and the
-    kernel wrappers can never disagree about which sites fuse. The
-    supports_* convention is block INPUT spatial dims; the walk tracks the
-    XLA stride-2 output as ``ceil(h/2)`` ((1,1) padding at stride 2), which
-    the kernels' even-dims requirement makes exact (``h//2``) wherever a
-    stride-2 site is actually admitted.
-
-    ``rows`` is the encoder's view-major batch (``2*batch_size`` for the
-    two-crop step). Returns one dict per potential fusion site::
-
-        {"name", "kind": "stem"|"basic"|"proj"|"bottleneck",
-         "h", "w", "in_channels", "width", "stride", "admitted", "desc"}
-    """
-    ctor, _ = MODEL_DICT[model]
-    mod = ctor()
-    sites: list = []
-    h = w = size
-    stem_ok = bool(
-        mod.stem == "conv"
-        and pallas_conv.supports_stem(rows, h, w, mod.in_channel, 64, dtype=dtype)
-    )
-    sites.append({
-        "name": "stem", "kind": "stem", "h": h, "w": w,
-        "in_channels": mod.in_channel, "width": 64, "stride": 1,
-        "admitted": stem_ok, "desc": f"stem {mod.in_channel}->64@{h}x{w}",
-    })
-    expansion = mod.block_cls.expansion
-    in_c = 64
-    for stage, (n_blocks, width, stage_stride) in enumerate(
-        zip(mod.stage_sizes, _STAGE_WIDTHS, _STAGE_STRIDES)
-    ):
-        for block in range(n_blocks):
-            stride = stage_stride if block == 0 else 1
-            name = f"layer{stage + 1}_block{block}"
-            if mod.block_cls is BasicBlock:
-                kind = "basic" if (stride == 1 and in_c == width) else "proj"
-                admitted = bool(pallas_conv.supports_block(
-                    rows, h, w, width, stride=stride, in_channels=in_c,
-                    dtype=dtype,
-                ))
-            elif mod.block_cls is Bottleneck and expansion == 4:
-                kind = "bottleneck"
-                admitted = bool(pallas_conv.supports_bottleneck(
-                    rows, h, w, width, stride=stride, in_channels=in_c,
-                    dtype=dtype,
-                ))
-            else:  # pragma: no cover - no such block class registered
-                kind, admitted = "unknown", False
-            out_c = width * expansion
-            sites.append({
-                "name": name, "kind": kind, "h": h, "w": w,
-                "in_channels": in_c, "width": width, "stride": stride,
-                "admitted": admitted,
-                "desc": f"{name}[{kind}] {in_c}->{out_c}@{h}x{w}/s{stride}",
-            })
-            if stride != 1:
-                h = (h + 1) // 2
-                w = (w + 1) // 2
-            in_c = out_c
-    return sites

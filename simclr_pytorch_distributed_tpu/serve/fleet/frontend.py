@@ -398,7 +398,7 @@ def build_fleet_stack(args):
     def loader(name, ckpt):
         return EmbeddingEngine.from_checkpoint(ckpt, **engine_kwargs(name))
 
-    # the --retrieval_impl ladder (the --loss_impl/--conv_impl convention):
+    # the --retrieval_impl ladder (the --loss_impl convention):
     # resolve ONCE at startup, honored-or-raise for explicit asks, and say
     # why in the banner — the impl decides every /neighbors latency number
     impl, reason = ivf.resolve_retrieval_impl(

@@ -72,7 +72,7 @@ def resolve_retrieval_impl(
     impl: str, capacity: int, nlist: int = 0
 ) -> Tuple[str, str]:
     """``(resolved_impl, reason)`` for the ``--retrieval_impl`` ladder —
-    the ``resolve_loss_impl``/``resolve_conv_impl`` convention: ``auto``
+    the ``resolve_loss_impl`` convention: ``auto``
     picks by corpus bound, an explicit choice is honored or raises (a
     silently ignored flag would misreport every latency number built on
     it), and the reason feeds ``config.impl_resolution_banner``."""

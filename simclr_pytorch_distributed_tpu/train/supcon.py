@@ -24,7 +24,7 @@ import logging
 import math
 import os
 import time
-from typing import List, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -182,81 +182,6 @@ def resolve_loss_impl(
     return impl
 
 
-def conv_fused_sites(
-    model: str, rows: int, size: int, dtype=jnp.float32
-) -> List[str]:
-    """The encoder sites ``--conv_impl pallas`` would fuse at this
-    geometry and compute dtype: the admitted subset of
-    ``models.resnet.fused_site_plan`` — the single-sourced walk the block
-    modules' own gates mirror, so banner and runtime dispatch can never
-    disagree. ``rows`` is the encoder's view-major batch (``2*batch_size``
-    for the two-crop step)."""
-    from simclr_pytorch_distributed_tpu.models.resnet import fused_site_plan
-
-    return [
-        site["desc"]
-        for site in fused_site_plan(model, rows, size, dtype=dtype)
-        if site["admitted"]
-    ]
-
-
-def resolve_conv_impl(
-    conv_impl: str, model: str, batch_size: int, size: int,
-    n_devices: int, bf16: bool = False,
-) -> tuple:
-    """``(resolved_impl, reason)`` for ``--conv_impl`` — the
-    ``resolve_loss_impl`` ladder convention applied to the encoder's conv
-    path (ops/pallas_conv.py).
-
-    'auto' resolves to 'xla' on every backend: Mosaic refuses several of
-    the fused conv kernels at the launcher's geometry and none has been
-    shown to win a chip cell (ROADMAP A1), so no default run selects them.
-    Explicit 'pallas' is honored on any backend (interpret mode off-TPU —
-    tests and the checkpoint round-trip smoke, not throughput; on TPU a
-    kernel the compiler refuses raises the compiler's own error), with
-    ``--bf16`` admitted site-by-site exactly like fp32 (the kernels carry
-    bf16 variants with fp32 accumulation), but raises loudly where it
-    could only be a silent no-op (multi-device mesh, zero admitted
-    sites) — the placement ladder's honored-or-raise rule.
-    """
-    if conv_impl == "xla":
-        return "xla", "explicit request: bitwise-pinned XLA conv path"
-    if conv_impl == "auto":
-        return "xla", (
-            "auto: the fused conv kernels are not yet shown to compile/win "
-            "on the chip (ROADMAP A1)"
-        )
-    # explicit 'pallas': honored or raise
-    if n_devices > 1:
-        raise ValueError(
-            f"--conv_impl pallas requires a single-device mesh, got "
-            f"{n_devices} devices: the fused kernels compute whole-"
-            "batch BN statistics inside one program (per-device BN "
-            "groups / GSPMD partitioning of the pallas_call are the "
-            "recorded open edge, docs/PERF.md round 15)"
-        )
-    rows = 2 * batch_size
-    dtype_tag = "bf16" if bf16 else "fp32"
-    sites = conv_fused_sites(
-        model, rows, size, dtype=jnp.bfloat16 if bf16 else jnp.float32
-    )
-    if not sites:
-        raise ValueError(
-            f"--conv_impl pallas admits no site for {model} at "
-            f"[{rows},{size},{size}] {dtype_tag} (see "
-            "ops/pallas_conv.supports_*) — use auto or xla"
-        )
-    backend = jax.default_backend()
-    mode = (
-        "compiled" if backend == "tpu"
-        else f"INTERPRET mode on {backend} (correctness only, slow)"
-    )
-    return "pallas", (
-        f"explicit request, {mode}, compute dtype {dtype_tag}; "
-        f"fused sites: {', '.join(sites)}"
-    )
-
-
 def _one_tpu_reason(n_devices: int) -> Optional[str]:
     """Why a kernel written for one TPU's program stays off this run's path
     whatever the encoder says, or None on a one-device TPU mesh."""
@@ -362,24 +287,9 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
     # BN statistics are scoped to the data-parallel device slices, not the
     # global batch (models/norm.py grouped mode).
     data_parallel = max(1, n_devices // max(1, cfg.model_parallel))
-    # --conv_impl: the encoder's conv-block path (ops/pallas_conv.py).
-    # Resolved HERE, with the startup banner naming the resolution and the
-    # reason (the data_placement ladder convention) — a silent degradation
-    # must be discoverable from the log
-    conv_impl, conv_reason = resolve_conv_impl(
-        cfg.conv_impl, cfg.model, cfg.batch_size, cfg.size, n_devices,
-        bf16=cfg.bf16,
-    )
-    logging.info(
-        "%s",
-        config_lib.impl_resolution_banner(
-            "conv_impl", cfg.conv_impl, conv_impl, conv_reason
-        ),
-    )
     encoder_kwargs = dict(
         dtype=dtype, sync_bn=cfg.syncBN, remat=cfg.remat,
         bn_local_groups=1 if cfg.syncBN else data_parallel,
-        conv_impl=conv_impl,
     )
     tail_plan = plan_pointwise_bwd(cfg, n_devices, **encoder_kwargs)
     attention_plan = plan_sparse_attention(cfg, n_devices, **encoder_kwargs)
@@ -885,7 +795,7 @@ def run(cfg: config_lib.SupConConfig) -> TrainState:
         obs.close(exit_code=exit_code_for(e))
         raise
     obs.staged()  # staging done: reset the watchdog deadline (utils/obs.py)
-    # build() emits the loss_impl/conv_impl resolution banners
+    # build() emits the loss_impl resolution banner
     model, schedule, tx, state, step_cfg = build(cfg, steps_per_epoch, mesh.size)
     # --recipe: the SSL loss head + its TrainState slots (recipes/). Attach
     # BEFORE any resume restore so the abstract state carries the recipe

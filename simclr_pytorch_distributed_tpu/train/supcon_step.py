@@ -412,7 +412,7 @@ def contrastive_loss_terms(
             # Mosaic compiles only on TPU; anywhere else (CPU tests) the
             # kernel runs under the Pallas interpreter. What rules out a
             # silent interpret run on the chip is chip_smoke.py's platform
-            # check plus the loss_impl/conv_impl banners, not an option here.
+            # check plus the loss_impl banner, not an option here.
             interpret=jax.default_backend() != "tpu",
         )
     else:

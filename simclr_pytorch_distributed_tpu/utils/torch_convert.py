@@ -215,8 +215,8 @@ def variables_to_torch_state_dict(variables: dict) -> Dict[str, np.ndarray]:
     ``'module.'`` prefix. ``num_batches_tracked`` is emitted as 0 for every BN
     (torch's fresh-module value; the reference's momentum=0.1 BNs never read
     it) so ``load_state_dict(strict=True)`` sees a complete dict. Raises on
-    any tree node it cannot represent in the reference layout (e.g. the
-    ``--stem s2d`` repacked stem), so a lossy export cannot pass silently."""
+    any tree node it cannot represent in the reference layout (e.g. a token
+    encoder's blocks), so a lossy export cannot pass silently."""
     params = variables["params"]
     stats = variables.get("batch_stats", {})
     sd: Dict[str, np.ndarray] = {}
@@ -260,8 +260,7 @@ def variables_to_torch_state_dict(variables: dict) -> Dict[str, np.ndarray]:
                     )
         else:
             raise ValueError(
-                f"cannot express encoder/{name} in the reference layout "
-                f"(e.g. '--stem s2d' checkpoints are not exportable)"
+                f"cannot express encoder/{name} in the reference layout"
             )
 
     head = params["proj_head"]

@@ -11,6 +11,7 @@ import pytest
 
 from simclr_pytorch_distributed_tpu.config import (
     config_dict,
+    impl_resolution_banner,
     linear_parser,
     parse_linear,
     parse_supcon,
@@ -257,6 +258,53 @@ def test_ngpu_auto_and_banner_in_build(tmp_path, caplog):
 
     banner = ngpu_mismatch_banner(2, 4, 0.5)
     assert "4/2" in banner and "~1" in banner  # 0.5 * 4/2 = 1.0
+
+
+def test_resolve_loss_impl_reasoned_names_degradations(monkeypatch):
+    from simclr_pytorch_distributed_tpu.train import supcon
+
+    impl, reason = supcon.resolve_loss_impl_reasoned("auto", 256, 1)
+    assert impl == "dense" and "non-TPU" in reason
+    impl, reason = supcon.resolve_loss_impl_reasoned("dense", 256, 1)
+    assert impl == "dense" and reason == "explicit request"
+    impl, reason = supcon.resolve_loss_impl_reasoned(
+        "auto", 256, 1, moco_queue=512
+    )
+    assert impl == "dense" and "moco_queue" in reason
+    monkeypatch.setattr(supcon.jax, "default_backend", lambda: "tpu")
+    impl, reason = supcon.resolve_loss_impl_reasoned("auto", 256, 1)
+    assert impl == "fused" and "single-chip" in reason
+    impl, reason = supcon.resolve_loss_impl_reasoned("auto", 3, 1)
+    assert impl == "dense" and "tile" in reason
+
+
+def test_impl_resolution_banner_format():
+    line = impl_resolution_banner(
+        "loss_impl", "auto", "dense", "non-TPU backend (cpu)"
+    )
+    assert line == (
+        "[loss_impl] requested 'auto' -> resolved 'dense': non-TPU backend (cpu)"
+    )
+    same = impl_resolution_banner(
+        "loss_impl", "dense", "dense", "explicit request"
+    )
+    assert same == "[loss_impl] 'dense': explicit request"
+
+
+def test_build_logs_resolution_banners(tmp_path, caplog):
+    """The loss's resolution is said at build; the encoder has one conv path
+    and nothing to resolve, so no ``[conv_impl]`` line."""
+    import logging
+
+    from simclr_pytorch_distributed_tpu.train.supcon import build
+
+    cfg = parse_supcon(
+        ["--model", "resnet10", "--dataset", "synthetic", "--batch_size", "8",
+         "--size", "8", "--workdir", str(tmp_path)]
+    )
+    with caplog.at_level(logging.INFO):
+        build(cfg, steps_per_epoch=4, n_devices=1)
+    assert "[loss_impl]" in caplog.text and "[conv_impl]" not in caplog.text
 
 
 def test_telemetry_flag_both_parsers(tmp_path):

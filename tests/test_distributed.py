@@ -14,8 +14,6 @@ import numpy as np
 import optax
 import pytest
 
-pytestmark = pytest.mark.slow  # compile-heavy: sharded-step programs on the 1-core CPU host
-
 from simclr_pytorch_distributed_tpu.models import SupConResNet
 from simclr_pytorch_distributed_tpu.ops.losses import supcon_loss
 from simclr_pytorch_distributed_tpu.ops.schedules import make_lr_schedule
@@ -130,7 +128,7 @@ def test_two_view_forward_layout():
     from simclr_pytorch_distributed_tpu.train.supcon_step import two_view_forward
 
     class Identity:
-        def apply(self, variables, x, train=False, mutable=None):
+        def apply(self, variables, x, train=False, mutable=None, method=None):
             out = x.reshape(x.shape[0], -1)
             return (out, {"batch_stats": {}}) if mutable else out
 

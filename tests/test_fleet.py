@@ -476,48 +476,6 @@ def test_ledger_record_schema_and_fingerprint_identity():
     assert rec["fingerprint"] != other["fingerprint"]
 
 
-def test_ledger_fingerprint_keys_on_conv_impl():
-    """A --conv_impl pallas bench run must never land in an xla-path
-    fingerprint group (the regression scan would compare across kernel
-    implementations); records predating the flag — and the explicit
-    default 'xla' — keep their committed fingerprints."""
-    pl = _load("perf_ledger")
-    base = _bench_record()
-    pre_flag = pl.record_from_bench(base, "abc", 1722.0)
-    explicit_xla = _bench_record()
-    explicit_xla["detail"]["conv_impl"] = "xla"
-    xla_rec = pl.record_from_bench(explicit_xla, "abc", 1722.0)
-    pallas = _bench_record()
-    pallas["detail"]["conv_impl"] = "pallas"
-    pallas_rec = pl.record_from_bench(pallas, "abc", 1722.0)
-    assert pre_flag["fingerprint"] == xla_rec["fingerprint"]
-    assert pallas_rec["fingerprint"] != xla_rec["fingerprint"]
-
-
-def test_ledger_fingerprint_keys_on_conv_dtype_for_pallas_only():
-    """The pallas arm exists in fp32 AND bf16 compute (round 19): the
-    dtype changes the workload, so the scan keys on it — but ONLY inside
-    non-xla impls, so every committed record (all xla, no conv_dtype key)
-    fingerprints exactly as before."""
-    pl = _load("perf_ledger")
-
-    def rec(**detail):
-        b = _bench_record()
-        b["detail"].update(detail)
-        return pl.record_from_bench(b, "abc", 1722.0)
-
-    pallas_fp32_implicit = rec(conv_impl="pallas")
-    pallas_fp32 = rec(conv_impl="pallas", conv_dtype="fp32")
-    pallas_bf16 = rec(conv_impl="pallas", conv_dtype="bf16")
-    assert pallas_fp32["fingerprint"] == pallas_fp32_implicit["fingerprint"]
-    assert pallas_bf16["fingerprint"] != pallas_fp32["fingerprint"]
-    # an xla record ignores conv_dtype entirely: the committed history
-    # (which never carried the key) keeps its fingerprints
-    xla_plain = rec(conv_impl="xla")
-    xla_tagged = rec(conv_impl="xla", conv_dtype="bf16")
-    assert xla_plain["fingerprint"] == xla_tagged["fingerprint"]
-
-
 def _ledger(values, suspects=None, shares=None):
     pl = _load("perf_ledger")
     suspects = suspects or [False] * len(values)

@@ -9,8 +9,6 @@ a handful of steps — compile time dominates, so keep program count low.
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # compile-heavy: sharded-step programs on the 1-core CPU host
-
 from simclr_pytorch_distributed_tpu import config as config_lib
 from simclr_pytorch_distributed_tpu.data import cifar as cifar_lib
 from simclr_pytorch_distributed_tpu.train import ce as ce_driver

@@ -202,17 +202,17 @@ def test_pallas_kernel_sync_fires_once():
 
 
 def test_real_pallas_kernel_modules_are_clean():
-    """The production kernel modules (ops/pallas_loss.py,
-    ops/pallas_conv.py) pass the extended hot-loop rule: their kernel
-    builders contain no sync-forcing host ops."""
+    """The kernel modules a benchmark cell runs (ops/pallas_loss.py,
+    ops/pointwise_bwd.py, ops/sparse_attention.py) pass the extended
+    hot-loop rule: their kernel builders contain no sync-forcing host ops."""
     pkg = os.path.join(REPO, "simclr_pytorch_distributed_tpu", "ops")
     expected = {
         # every kernel builder must be under coverage — the builders all
         # reuse the local name 'kernel =' for their partial, so a
         # last-binding-wins resolution would silently drop most of them
         "pallas_loss.py": {"_fwd_kernel", "_bwd_kernel"},
-        "pallas_conv.py": {"_stem_fwd_kernel", "_stem_bwd_kernel",
-                           "_block_fwd_kernel", "_block_bwd_kernel"},
+        "pointwise_bwd.py": {"_kernel"},
+        "sparse_attention.py": {"_fwd_kernel", "_bwd_kernel"},
     }
     for name, want in expected.items():
         mod = core.load_module(os.path.join(pkg, name), repo_root=REPO)
@@ -458,10 +458,8 @@ def test_ratchet_default_list_includes_lint_gate():
 def test_committed_evidence_passes_gate():
     """The committed docs/evidence artifact re-verifies under the pure
     gate record — the acceptance-criteria bind."""
-    # r19: regenerated after the fused-conv ladder round (bf16 kernels,
-    # projection/Bottleneck blocks) reshaped ops/pallas_conv.py,
-    # models/resnet.py, and scripts/convblock_ab.py in place (101 files —
-    # no new files joined the surface, the scanned set's contents moved)
+    # regenerated in place whenever the scanned surface or the allowlist
+    # moves (last: PR 30, after the fused-conv ladder's files went)
     path = os.path.join(REPO, "docs", "evidence", "invariant_lint_r19.json")
     with open(path) as f:
         artifact = json.load(f)

@@ -155,21 +155,3 @@ def test_remat_identical_numerics():
     np.testing.assert_allclose(outs[True][0], outs[False][0], rtol=1e-6)
     for a, b in zip(jax.tree.leaves(outs[False][1]), jax.tree.leaves(outs[True][1])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-
-def test_s2d_stem_variant_shapes():
-    """The space-to-depth stem experiment (models/resnet.py) preserves every
-    downstream shape: same feature dim, same head output."""
-    import jax
-    import jax.numpy as jnp
-
-    from simclr_pytorch_distributed_tpu.models import SupConResNet
-
-    m = SupConResNet(model_name="resnet10", stem="s2d")
-    v = m.init(jax.random.key(0), jnp.zeros((2, 32, 32, 3)))
-    out, _ = m.apply(v, jnp.ones((2, 32, 32, 3)), mutable=["batch_stats"])
-    assert out.shape == (2, 128)
-    feats = m.apply(v, jnp.ones((2, 32, 32, 3)), train=False,
-                    method=SupConResNet.encode)
-    assert feats.shape == (2, 512)
-    assert "conv1_s2d" in v["params"]["encoder"]

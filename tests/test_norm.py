@@ -6,7 +6,11 @@ import numpy as np
 import pytest
 import torch
 
-from simclr_pytorch_distributed_tpu.models.norm import CrossReplicaBatchNorm
+from simclr_pytorch_distributed_tpu.models.norm import (
+    CrossReplicaBatchNorm,
+    FusedTrainBN,
+    running_stats_update,
+)
 
 
 def torch_bn_reference(x_nhwc, n_steps=1):
@@ -103,7 +107,6 @@ def test_grouped_bn_init_with_tiny_example_batch():
     assert variables["batch_stats"]["mean"].shape == (3,)
 
 
-@pytest.mark.slow
 def test_grouped_bn_identical_under_sharded_jit(rng):
     """The grouped math is layout-independent: jit over the 8-device mesh with
     the batch sharded on 'data' produces the same outputs and running stats."""
@@ -128,7 +131,6 @@ def test_grouped_bn_identical_under_sharded_jit(rng):
     )
 
 
-@pytest.mark.slow
 def test_shard_map_sync_equals_full_batch(rng):
     """pmean-synced per-device BN == BN over the concatenated batch — the
     SyncBatchNorm semantic (reference main_supcon.py:223-224) mesh-natively."""
@@ -162,7 +164,6 @@ def test_shard_map_sync_equals_full_batch(rng):
     np.testing.assert_allclose(np.asarray(rv), np.asarray(mut_full["batch_stats"]["var"]), rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.slow
 def test_unsynced_bn_uses_local_stats(rng):
     """sync=False reproduces the reference's non---syncBN per-device BN."""
     from jax.sharding import Mesh, PartitionSpec as P
@@ -197,3 +198,20 @@ def test_unsynced_bn_uses_local_stats(rng):
     )(jnp.asarray(x))
     y_s = np.asarray(y_s)
     assert y_s[:8].mean() > 0.5 and y_s[8:].mean() < -0.5
+
+
+def test_fused_train_bn_running_update_matches_norm():
+    """FusedTrainBN's second call applies EXACTLY the norm.py running
+    update (single-sourced via running_stats_update)."""
+    bn = FusedTrainBN(4)
+    v = bn.init(jax.random.key(0))
+    m = jnp.asarray([1.0, 2.0, 3.0, 4.0])
+    var = jnp.asarray([0.5, 1.5, 2.5, 3.5])
+    (scale, bias), mut = bn.apply(v, m, var, 100, mutable=["batch_stats"])
+    exp_m, exp_v = running_stats_update(
+        jnp.zeros((4,)), jnp.ones((4,)), m, var, 100, 0.1
+    )
+    np.testing.assert_allclose(np.asarray(mut["batch_stats"]["mean"]), exp_m)
+    np.testing.assert_allclose(np.asarray(mut["batch_stats"]["var"]), exp_v)
+    np.testing.assert_array_equal(np.asarray(scale), np.ones(4))
+    np.testing.assert_array_equal(np.asarray(bias), np.zeros(4))

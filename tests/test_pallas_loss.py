@@ -173,7 +173,6 @@ def test_unsupported_size_raises(rng):
         fused_supcon_loss(f, interpret=True)
 
 
-@pytest.mark.slow
 def test_fused_train_step_single_device(rng):
     """make_train_step with loss_impl='fused' runs and matches the dense step."""
     import optax

@@ -136,7 +136,7 @@ def _small_encoder(**kw):
 
 
 def _pallas_calls(fn, *args) -> int:
-    """Calls of this kernel (``--conv_impl pallas`` has calls of its own)."""
+    """Calls of this kernel."""
     return str(jax.make_jaxpr(fn)(*args)).count("name=pointwise_bwd")
 
 
@@ -196,7 +196,6 @@ def test_routed_gradient_is_the_unrouted_one_to_bf16(small_setup):
     ("bf16", {"dtype": jnp.bfloat16}, {}, 0),
     ("grouped-bn", {"sync_bn": False, "bn_local_groups": 2}, {}, 0),
     ("axis-name", {"axis_name": "data"}, {"train": False}, 0),
-    ("conv-impl-pallas", {"conv_impl": "pallas"}, {}, 0),
     ("owner-says-no", {"pointwise_bwd": False}, {}, 0),
 ], ids=lambda v: v if isinstance(v, str) else None)
 def test_fallbacks_take_xlas_path(small_setup, case, encoder_kw, apply_kw, calls):
@@ -309,7 +308,6 @@ def test_build_plans_and_routes(monkeypatch, case, cfg_kw, n_devices, backend,
     ({"sync_bn": False, "bn_local_groups": 2}, "groups"),
     ({"axis_name": "data"}, "axis"),
     ({"dtype": jnp.bfloat16}, "bfloat16"),
-    ({"conv_impl": "pallas"}, "--conv_impl pallas"),
 ], ids=lambda v: str(v))
 def test_tail_bwd_reason_is_the_plans_and_the_modules(encoder_kw, why):
     """One predicate: what the plan says of a site is what the encoder's

@@ -588,224 +588,6 @@ def test_window_ab_smoke_window_arm_amortizes_per_step_transfer(tmp_path):
     assert artifact["equivalence"]["equivalence_ok"]
 
 
-# ------------------------------------------------------- convblock_ab
-
-
-def _convblock_parity(ok=True):
-    return {
-        "parity_ok": ok, "value_ok": ok, "grads_ok": True,
-        "stats_ok": True, "max_abs_diffs": {"out": 1e-6 if ok else 0.5},
-        "tolerances": {"value_atol": 3e-5, "grad_rtol": 1e-4,
-                       "grad_atol": 1e-3},
-    }
-
-
-def test_convblock_ab_build_output_schema():
-    """The committed docs/evidence/convblock_ab_r19.json schema (v2: one
-    section per block kind x compute dtype), pinned without running the
-    measurement (the window_ab pattern)."""
-    convblock_ab = _load("convblock_ab")
-    from simclr_pytorch_distributed_tpu.ops import pallas_conv
-
-    runs = [
-        {"xla": [120.0, 118.0], "pallas": [65.0, 64.0]},
-        {"xla": [119.0, 121.0], "pallas": [66.0, 63.0]},
-    ]
-    blocks = {}
-    for kind in ("basic", "bottleneck_bf16"):
-        geo = convblock_ab.kind_geometry(kind, 32, 16, 16)
-        # the kinds the artifact times must be kinds the resolution-time
-        # gates actually admit (the full-config geometry)
-        assert convblock_ab.kind_supported(kind, geo)
-        base = kind.split("_bf16")[0]
-        blocks[kind] = {
-            "geometry": geo,
-            "dtype": "bf16" if kind.endswith("_bf16") else "fp32",
-            "bytes_scale": 0.5 if kind.endswith("_bf16") else 1.0,
-            "traversals": convblock_ab.TRAVERSALS[base],
-            "parity": _convblock_parity(), "runs": runs,
-        }
-    out = convblock_ab.build_output("cpu", 5.0, 8, blocks)
-    assert out["schema"] == convblock_ab.SCHEMA == "convblock_ab/v2"
-    assert out["metric"] == "convblock_ab_ms_per_step"
-    assert out["parity_ok"] and "ABBA" in out["arm_order"]
-    # traversal counts are the kernels' own constants, not free parameters
-    assert convblock_ab.TRAVERSALS["basic"] == {"xla": 21, "pallas": 11}
-    assert convblock_ab.TRAVERSALS["basic"]["pallas"] == (
-        pallas_conv.FWD_HBM_TRAVERSALS_BLOCK
-        + pallas_conv.BWD_HBM_TRAVERSALS_BLOCK
-    )
-    assert convblock_ab.TRAVERSALS["proj"] == {
-        "xla": (pallas_conv.FWD_HBM_TRAVERSALS_PROJ_XLA
-                + pallas_conv.BWD_HBM_TRAVERSALS_PROJ_XLA),
-        "pallas": (pallas_conv.FWD_HBM_TRAVERSALS_PROJ
-                   + pallas_conv.BWD_HBM_TRAVERSALS_PROJ),
-    }
-    assert convblock_ab.TRAVERSALS["bottleneck"] == {"xla": 32, "pallas": 14}
-    b = out["blocks"]["basic"]
-    assert b["runs"] == runs and b["parity"]["parity_ok"]
-    s = b["summary"]
-    assert s["xla_ms_per_step"] == 119.5  # median of the 4 xla arms
-    assert s["pallas_ms_per_step"] == 64.5
-    assert s["traversal_removed_ms_per_step"] == 55.0
-    assert s["expected_removed_ms_per_step"] == 5.0 * (21 - 11)
-    # the bf16 kind's expectation is bytes-scaled: half the bytes per
-    # traversal is the reason the bf16 kernels exist
-    s = out["blocks"]["bottleneck_bf16"]["summary"]
-    assert s["expected_removed_ms_per_step"] == 5.0 * 0.5 * (32 - 14)
-    # the committed artifact: same key set, ALL SIX kinds, parity green
-    # and the traversal reduction realized per kind
-    with open(os.path.join(
-        os.path.dirname(SCRIPTS), "docs", "evidence", "convblock_ab_r19.json"
-    )) as f:
-        committed = json.load(f)
-    assert set(out) == set(committed)
-    assert set(committed["blocks"]) == set(convblock_ab.BLOCK_KINDS)
-    for kind, cb in committed["blocks"].items():
-        assert cb["parity"]["parity_ok"], kind
-        cs = cb["summary"]
-        assert cs["pallas_ms_per_step"] < cs["xla_ms_per_step"], kind
-        assert cs["traversal_removed_ms_per_step"] > \
-            cs["expected_removed_ms_per_step"] / 3, kind
-        if kind.endswith("_bf16"):
-            # bf16 parity binds on the derived agreement metrics
-            m = cb["parity"]["bf16_metrics"]
-            assert m["out"]["cos"] >= convblock_ab.BF16_VAL_COS_FLOOR, kind
-            assert cb["parity"]["tolerances"]["grad_cos_floor"] == \
-                convblock_ab.BF16_GRAD_COS_FLOOR
-
-
-def test_convblock_ab_build_output_tolerates_broken_parity():
-    """A broken-parity kind carries no timed rounds but must still write
-    its artifact section (the ratchet gate carries the structured diffs):
-    empty records produce None timing summaries, never a raise — and one
-    broken kind poisons only the top-level parity_ok, not the healthy
-    kinds' summaries."""
-    convblock_ab = _load("convblock_ab")
-    runs = [{"xla": [120.0, 118.0], "pallas": [65.0, 64.0]}]
-    blocks = {
-        "basic": {
-            "geometry": convblock_ab.kind_geometry("basic", 16, 8, 8),
-            "dtype": "fp32", "bytes_scale": 1.0,
-            "traversals": convblock_ab.TRAVERSALS["basic"],
-            "parity": _convblock_parity(), "runs": runs,
-        },
-        "proj_bf16": {
-            "geometry": convblock_ab.kind_geometry("proj_bf16", 16, 8, 8),
-            "dtype": "bf16", "bytes_scale": 0.5,
-            "traversals": convblock_ab.TRAVERSALS["proj"],
-            "parity": _convblock_parity(ok=False), "runs": [],
-        },
-    }
-    out = convblock_ab.build_output("cpu", 5.0, 4, blocks)
-    assert not out["parity_ok"]
-    s = out["blocks"]["proj_bf16"]["summary"]
-    assert s["xla_ms_per_step"] is None
-    assert s["pallas_ms_per_step"] is None
-    assert s["traversal_removed_ms_per_step"] is None
-    assert s["speedup"] is None
-    assert out["blocks"]["basic"]["summary"]["pallas_ms_per_step"] == 64.5
-    # and the gate fails it on the parity verdict, everywhere, naming
-    # the broken kind
-    ratchet = _load("ratchet")
-    rec = ratchet.convblock_gate_record(out)
-    assert not rec["ok"] and "diverges" in rec["error"]
-    assert "proj_bf16" in rec["error"]
-    rec = ratchet.convblock_gate_record({**out, "device": "TPU v4"})
-    assert not rec["ok"] and "proj_bf16" in rec["error"]
-
-
-@pytest.mark.kernel
-def test_convblock_ab_smoke_parity_and_traversal_removal(tmp_path):
-    """Tier-1 guard on the committed-artifact path: the real script
-    end-to-end on the tiny config — interpret-mode kernel parity gating
-    each kind's timing, both timed arms, the ABBA loop, and the JSON
-    artifact. One kind per base shape (the full six-kind sweep is the
-    committed-artifact run): the identity BasicBlock in fp32 plus the two
-    NEW round-19 fusions on their bf16 arms. Under the injected
-    bytes-scaled per-traversal delay the pallas arm pays ~40% of the
-    traversals, so most of the modeled delta must materialize."""
-    convblock_ab = _load("convblock_ab")
-    out_path = tmp_path / "convblock_ab.json"
-    out = convblock_ab.main([
-        "--smoke", "--rounds", "1", "--steps", "2", "--hbm_delay_ms", "15",
-        "--kinds", "basic", "proj_bf16", "bottleneck_bf16",
-        "--json", str(out_path),
-    ])
-    assert out["parity_ok"]
-    assert set(out["blocks"]) == {"basic", "proj_bf16", "bottleneck_bf16"}
-    for kind, b in out["blocks"].items():
-        assert b["parity"]["parity_ok"], kind
-        s = b["summary"]
-        assert s["pallas_ms_per_step"] < s["xla_ms_per_step"], kind
-        # e.g. basic: removal = 15 * (21 - 11) = 150 ms at these
-        # settings; require a third (generous vs 1-core contention noise)
-        assert s["traversal_removed_ms_per_step"] > \
-            s["expected_removed_ms_per_step"] / 3, kind
-    # bf16 sections carry the agreement metrics next to the raw diffs
-    assert "bf16_metrics" in out["blocks"]["proj_bf16"]["parity"]
-    assert "bf16_metrics" not in out["blocks"]["basic"]["parity"]
-    artifact = json.loads(out_path.read_text())
-    assert artifact["schema"] == convblock_ab.SCHEMA
-    assert artifact["parity_ok"]
-
-
-def test_ratchet_convblock_gate_decision():
-    """The fused conv-block gate rides the default config list: per-kind
-    kernel parity binds on EVERY device, the CPU-calibrated
-    traversal-delay timing claim binds per kind on CPU and pass-skips
-    off-CPU with the reason on record."""
-    ratchet = _load("ratchet")
-    assert "convblock" in ratchet.CONFIGS
-    assert ratchet.CONFIGS["convblock"]["kind"] == "convblock_ab"
-
-    def kind_section(xla=120.0, pallas=65.0, parity_ok=True):
-        return {
-            "summary": {"xla_ms_per_step": xla,
-                        "pallas_ms_per_step": pallas},
-            "parity": {"parity_ok": parity_ok, "value_ok": parity_ok,
-                       "grads_ok": parity_ok, "stats_ok": parity_ok,
-                       "max_abs_diffs": {"out": 1e-6}},
-            "traversals": {"xla": 21, "pallas": 11},
-        }
-
-    def art(device="cpu", **kinds):
-        kinds = kinds or {"basic": kind_section()}
-        return {
-            "blocks": kinds,
-            "parity_ok": all(k["parity"]["parity_ok"]
-                             for k in kinds.values()),
-            "device": device,
-        }
-
-    r = ratchet.convblock_gate_record(
-        art(basic=kind_section(), proj_bf16=kind_section(xla=60, pallas=30))
-    )
-    assert r["ok"] and "skipped" not in r
-    assert r["metric"] == "ratchet_convblock_ab_parity"
-    assert set(r["kinds"]) == {"basic", "proj_bf16"}
-    # main()'s summary table requires "value" on every record
-    assert r["value"] == 2
-    # ONE broken kind's parity fails EVERYWHERE, even where timing
-    # pass-skips, and the record names it
-    r = ratchet.convblock_gate_record(art(
-        device="TPU v4", basic=kind_section(),
-        bottleneck_bf16=kind_section(parity_ok=False),
-    ))
-    assert not r["ok"] and "diverges" in r["error"]
-    assert "bottleneck_bf16" in r["error"] and "basic:" not in r["error"]
-    # an accelerator: parity enforced, CPU-calibrated timing skipped
-    r = ratchet.convblock_gate_record(
-        art(device="TPU v4", basic=kind_section(xla=64.9, pallas=65.2))
-    )
-    assert r["ok"] and "calibrated" in r["skipped"]
-    # on CPU the timing claim binds per kind
-    r = ratchet.convblock_gate_record(art(
-        basic=kind_section(), proj=kind_section(xla=65.0, pallas=65.0),
-    ))
-    assert not r["ok"] and "not faster" in r["error"] and "proj" in r["error"]
-
-
 # ------------------------------------------------------- ratchet bench gate
 
 

@@ -498,7 +498,6 @@ def test_all_drivers_flush_boundary_smoke(tmp_path, tiny_drivers, placement):
                    else config_lib.finalize_linear(lcfg))
 
 
-@pytest.mark.slow
 def test_supcon_tb_stream_bitwise_equal(tmp_path, tiny_drivers):
     supcon_driver, _, _ = tiny_drivers
     from simclr_pytorch_distributed_tpu import config as config_lib
@@ -520,7 +519,6 @@ def test_supcon_tb_stream_bitwise_equal(tmp_path, tiny_drivers):
     assert {r[2] for r in info_tags} == set(range(10))  # all 10 global steps
 
 
-@pytest.mark.slow
 def test_linear_and_ce_tb_streams_bitwise_equal(tmp_path, tiny_drivers):
     _, linear_driver, ce_driver = tiny_drivers
     from simclr_pytorch_distributed_tpu import config as config_lib

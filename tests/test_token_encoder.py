@@ -344,7 +344,7 @@ def test_resnet_op_names_are_what_they_were(name):
 
 
 def test_build_encoder_hands_each_encoder_the_flags_it_declares():
-    flags = dict(dtype=jnp.bfloat16, remat=True, sync_bn=False, conv_impl="xla", stem="conv")
+    flags = dict(dtype=jnp.bfloat16, remat=True, sync_bn=False, pointwise_bwd=False)
     rn = build_encoder("resnet18", **flags)
     assert (rn.sync_bn, rn.remat, rn.dtype) == (False, True, jnp.bfloat16)
     tok = build_encoder(TINY, **flags)

@@ -59,6 +59,15 @@ def test_state_dict_mapping_roundtrip_bit_identical(rn18_variables):
         )
 
 
+def test_export_rejects_an_encoder_the_reference_lacks():
+    """A token encoder's blocks have no reference equivalent; export must
+    fail loudly rather than write a silently-wrong .pth."""
+    model = SupConResNet(model_name="keye-vl2-tiny")
+    variables = model.init(jax.random.key(7), jnp.zeros((2, 16, 16, 3)), train=False)
+    with pytest.raises(ValueError, match="cannot express encoder/"):
+        variables_to_torch_state_dict(jax.tree.map(np.asarray, dict(variables)))
+
+
 def test_export_import_roundtrip_bit_identical(tmp_path, rn18_variables):
     """Full on-disk loop through the reference's torch.save layout."""
     pytest.importorskip("torch")

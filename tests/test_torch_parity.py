@@ -657,22 +657,6 @@ def test_missing_batch_stats_raise_named_value_error():
         variables_to_torch_state_dict(broken)
 
 
-def test_export_rejects_s2d_stem():
-    """The repacked '--stem s2d' layout has no reference equivalent; export
-    must fail loudly rather than write a silently-wrong .pth."""
-    from simclr_pytorch_distributed_tpu.models import SupConResNet
-    from simclr_pytorch_distributed_tpu.utils.torch_convert import (
-        variables_to_torch_state_dict,
-    )
-
-    fm = SupConResNet(model_name="resnet18", stem="s2d")
-    variables = fm.init(jax.random.key(7), jnp.zeros((2, 32, 32, 3)))
-    with pytest.raises(ValueError, match="s2d"):
-        variables_to_torch_state_dict(
-            jax.tree.map(np.asarray, dict(variables))
-        )
-
-
 def test_topk_accuracy_matches_reference(ref_util):
     """ops.metrics.topk_accuracy vs the reference's accuracy() (util.py:37-51).
 

@@ -5,10 +5,6 @@ these tests say only whether Mosaic/XLA:TPU accept the main path's kernels at
 the launcher's geometry (``run_supcon.sh``: rn50, batch 256 -> 512 view rows,
 32x32). Interpret-mode parity tests cannot see a VMEM overflow; these can.
 
-The refused conv combinations are ``xfail(strict=True)``: they are why
-``--conv_impl auto`` resolves to ``xla`` (ROADMAP A1). Whoever repairs or
-deletes a kernel kind has to touch its case here.
-
 Rules this file keeps (libtpu is loaded by ONE process, and pytest-xdist
 workers each import every test file): the topology is described inside a
 module-scoped fixture, never at import; every compile runs in the test's own
@@ -32,7 +28,7 @@ from jax import lax
 
 from simclr_pytorch_distributed_tpu.models import sparse_attention as attention_layer
 from simclr_pytorch_distributed_tpu.models import token_encoder
-from simclr_pytorch_distributed_tpu.ops import pallas_conv, pallas_loss, pointwise_bwd, sparse_attention
+from simclr_pytorch_distributed_tpu.ops import pallas_loss, pointwise_bwd, sparse_attention
 
 ROWS, SIZE, FEAT_DIM = 512, 32, 128  # 2 * batch 256 view rows, CIFAR, head out
 
@@ -108,66 +104,6 @@ def test_sharded_fused_loss_compiles_on_four_chip_mesh(data_mesh, grad):
     )
     text = _compile(_maybe_grad(loss, grad), feats)
     assert "all-gather" in text  # the contrast side is gathered over 'data'
-
-
-def _conv_program(kind: str, dtype, sharding):
-    """``(fn, arg_shapes)``: the kernel entry point reduced to a scalar, at
-    the rn50 launcher geometry of its first site."""
-
-    def sds(*shape, dt=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-
-    def bn(c):
-        return [sds(c), sds(c)]
-
-    if kind == "stem":  # 3 -> 64
-        entry = pallas_conv.fused_conv_bn_relu
-        args = [sds(ROWS, SIZE, SIZE, 3, dt=dtype), sds(3, 3, 3, 64), *bn(64)]
-    elif kind == "basic":  # identity 64 -> 64
-        entry = pallas_conv.fused_basic_block
-        args = [sds(ROWS, SIZE, SIZE, 64, dt=dtype),
-                sds(3, 3, 64, 64), *bn(64), sds(3, 3, 64, 64), *bn(64)]
-    else:  # identity bottleneck 256 -> 64 -> 256
-        entry = pallas_conv.fused_bottleneck_block
-        args = [sds(ROWS, SIZE, SIZE, 256, dt=dtype),
-                sds(1, 1, 256, 64), *bn(64), sds(3, 3, 64, 64), *bn(64),
-                sds(1, 1, 64, 256), *bn(256)]
-
-    def scalar(*a):
-        return jnp.sum(entry(*a)[0].astype(jnp.float32))
-
-    return scalar, args
-
-
-# What Mosaic refuses today (scoped VMEM over the 16 MiB limit), although the
-# ``supports_*`` gates admit it: the verdict table of CHANGES.md, PR 23.
-REFUSED = {
-    ("stem", "float32", "fwd"), ("stem", "float32", "grad"),
-    ("stem", "bfloat16", "grad"),
-    ("basic", "float32", "grad"),
-    ("bottleneck", "float32", "grad"),
-}
-CONV_CASES = [
-    pytest.param(
-        kind, dtype, mode, id=f"{kind}-{dtype}-{mode}",
-        marks=[pytest.mark.xfail(
-            strict=True, raises=jax.errors.JaxRuntimeError,
-            reason="Mosaic refuses it: scoped VMEM over 16 MiB (ROADMAP A1)",
-        )] if (kind, dtype, mode) in REFUSED else [],
-    )
-    for kind in ("stem", "basic", "bottleneck")
-    for dtype in ("float32", "bfloat16")
-    for mode in ("fwd", "grad")
-]
-
-
-@pytest.mark.parametrize("kind,dtype,mode", CONV_CASES)
-def test_fused_conv_kernel_compiles(one_chip, kind, dtype, mode):
-    """Every case here is one ``supports_*`` ADMITS at this geometry."""
-    fn, args = _conv_program(kind, jnp.dtype(dtype), one_chip)
-    if mode == "grad":
-        fn = jax.grad(fn, argnums=tuple(range(len(args))))
-    _compile(fn, *args)
 
 
 # ---- Bottleneck's tail on one backward kernel (ops/pointwise_bwd.py): the
