@@ -262,7 +262,8 @@ def render_table(report):
             f"{plan['n_experts']}, {plan['per_token']} a token, "
             f"{plan['rows_per_step']} token rows a step, "
             f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
-            "the routing; " + (", ".join(
+            f"the routing in {plan.get('provisioned_trips', '?')} trips of "
+            f"{plan.get('rows_per_trip', '?')} rows; " + (", ".join(
                 f"{k} {v:.4g}" for k, v in ring.items()) or "no health window yet"))
         attention = report["encoder"].get("attention_plan")
         if attention:

@@ -12,7 +12,11 @@ What the absent experts would add is left out; no code stands in for the
 other chips or their exchange. No token is dropped, whatever the imbalance:
 the held assignments are sorted by expert and taken a chunk at a time,
 each chunk one grouped product a projection (``lax.ragged_dot``), added back
-onto its tokens. The loop (``lax.while_loop``) sweeps the rows the layer is
+onto its tokens. A chunk is as many rows as a byte budget holds
+(``TRIP_BYTES``, ``balanced_chunk_rows``): what a trip moves that is the
+size of the weights and not of its rows (their reads, their gradients'
+partial sums) is then paid a few times a layer and not once every few
+thousand rows. The loop (``lax.while_loop``) sweeps the rows the layer is
 provisioned for, ``capacity_factor`` balanced shares, whatever the routing
 (rows past the held assignments enter as zeros), and goes on past them for as
 long as held assignments are left: up to that load every step does the same
@@ -49,11 +53,31 @@ from simclr_pytorch_distributed_tpu.models.sparse_attention import (
 SCOPE_EXPERTS = "experts"
 
 
-def balanced_chunk_rows(assignments: int, held: int, n_experts: int) -> int:
-    """Rows of a chunk of sorted assignments, in tiles of 512: a quarter of
-    the held experts' share of a balanced load."""
-    share = assignments * held / n_experts
-    return max(512, -(-int(share / 4) // 512) * 512)
+# What one trip of the backward sweep may hold at once, in bytes. A trip holds
+# nine tensors the size of a held weight matrix whatever its rows (the three
+# gradients' partial sums, the trip's own three, the three weights re-laid for
+# the input-gradient products) and, a row, about three vectors as wide as a
+# token and three as wide as an expert (the chip's compiler keeps 33.8 kB a
+# row alive at 2048 x 768 float32, which is this count; it is held to it in
+# tests/test_tpu_aot_compile.py). At the benchmark's shapes the budget gives
+# 32,768 rows, two trips over the provision; the program with the provision
+# in one trip does not load beside the driver's state (PERF.md, PR 31).
+TRIP_BYTES = 2 << 30
+_TILE = 512  # rows: a chunk is whole tiles of the grouped products
+
+
+def balanced_chunk_rows(assignments: int, held: int, n_experts: int, provisioned: int,
+                        hidden: int, width: int, dtype) -> int:
+    """Rows of a chunk of sorted assignments, one trip of the loop: the
+    ``provisioned`` rows (the held experts' share of a balanced load where
+    nothing is provisioned) in the fewest equal trips whose working set
+    ``TRIP_BYTES`` holds, for ``held`` experts of ``hidden x width`` in
+    ``dtype``; whole tiles, and never under one."""
+    size = jnp.dtype(dtype).itemsize
+    fit = (TRIP_BYTES - 9 * held * hidden * width * size) // (3 * (hidden + width) * size)
+    sweep = provisioned or assignments * held // n_experts
+    trips = max(1, -(-sweep // max(_TILE, fit // _TILE * _TILE)))
+    return max(_TILE, -(-sweep // (trips * _TILE)) * _TILE)
 
 
 def provisioned_rows(assignments: int, held: int, n_experts: int,
@@ -79,20 +103,58 @@ def routing_statistics(probs: jax.Array, top_e: jax.Array):
     return counts / top_e.size, jnp.mean(probs, axis=0)
 
 
-def _chunk_out(x, w_gate, w_up, w_down, gate, expert):
-    """One chunk of sorted assignments: token rows ``x [C, D]``, their gates
-    and (local) experts, ``count`` for a row that is no held assignment. Such
+def _group_sizes(expert, count: int):
+    """Rows of a chunk for each of ``count`` held experts, given the rows'
+    (local) experts, ``count`` for a row that is no held assignment. Such
     rows come last; they enter as zeros and count as the last expert's, so
     that every chunk is a full one to the grouped products."""
-    count = w_gate.shape[0]
     sizes = jnp.sum(expert[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
-    sizes = sizes.at[count - 1].add(expert.shape[0] - jnp.sum(sizes))
+    return sizes.at[count - 1].add(expert.shape[0] - jnp.sum(sizes))
+
+
+def _chunk_out(x, w_gate, w_up, w_down, gate, expert):
+    """One chunk of sorted assignments: token rows ``x [C, D]``, their gates
+    and (local) experts (``_group_sizes``)."""
+    count = w_gate.shape[0]
+    sizes = _group_sizes(expert, count)
     x = jnp.where((expert < count)[:, None], x, 0)
     with jax.named_scope(SCOPE_EXPERTS):
         hidden = (jax.nn.silu(lax.ragged_dot(x, w_gate, sizes))
                   * lax.ragged_dot(x, w_up, sizes))
         out = lax.ragged_dot(hidden, w_down, sizes)
     return out * gate[:, None]
+
+
+# [C, A] x [C, B] -> [count, A, B]: the rows of each group contracted, a
+# weight's gradient from a chunk (what ``ragged_dot``'s own transpose takes)
+_ROWS_CONTRACTED = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _chunk_back(x, weights, transposed, gate, expert, dy):
+    """``_chunk_out``'s backward written out over the same grouped products:
+    the chunk recomputed, then ``(dx, [dw_gate, dw_up, dw_down], dgate)`` for
+    the cotangent ``dy [C, D]`` of its result. The input-gradient products
+    take the weights with their last two axes swapped, ``transposed``, which
+    the caller makes once for all chunks (``jax.vjp`` of ``_chunk_out``
+    swaps them inside every trip)."""
+    w_gate, w_up, w_down = weights
+    gate_t, up_t, down_t = transposed
+    count = w_gate.shape[0]
+    sizes = _group_sizes(expert, count)
+    held = (expert < count)[:, None]
+    x = jnp.where(held, x, 0)
+    d_out = dy * gate[:, None]
+    with jax.named_scope(SCOPE_EXPERTS):
+        pre = lax.ragged_dot(x, w_gate, sizes), lax.ragged_dot(x, w_up, sizes)
+        hidden, back = jax.vjp(lambda a, u: jax.nn.silu(a) * u, *pre)
+        out = lax.ragged_dot(hidden, w_down, sizes)
+        d_a, d_u = back(lax.ragged_dot(d_out, down_t, sizes))
+        dx = lax.ragged_dot(d_a, gate_t, sizes) + lax.ragged_dot(d_u, up_t, sizes)
+        dw = [lax.ragged_dot_general(rows, d, sizes, _ROWS_CONTRACTED)
+              for rows, d in ((x, d_a), (x, d_u), (hidden, d_out))]
+    return jnp.where(held, dx, 0), dw, jnp.sum(out * dy, axis=-1)
 
 
 def _sweep(step, carry, rows, chunk: int):
@@ -108,7 +170,7 @@ def _mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
     """``y [N, D]``: the first ``rows`` sorted assignments a chunk at a time
     (``_sweep``), each chunk through ``_chunk_out`` and added onto its tokens.
     The loop's length is not static, so the backward pass is written as the
-    same sweep over ``jax.vjp`` of a chunk: it recomputes the chunk and keeps
+    same sweep over ``_chunk_back``: it recomputes the chunk and keeps
     nothing."""
     def step(start, y):
         cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
@@ -125,21 +187,21 @@ def _mix_sorted_fwd(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
 
 def _mix_sorted_bwd(chunk, kept, dy):
     b, w_gate, w_up, w_down, gate, token, expert, rows = kept
+    weights = (w_gate, w_up, w_down)
+    transposed = tuple(jnp.swapaxes(w, 1, 2) for w in weights)  # once, not a trip
 
     def step(start, carry):
         db, dw, dgate = carry
         cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
-        tok, experts = cut(token), cut(expert)
-        _, back = jax.vjp(lambda x, wg, wu, wd, g: _chunk_out(x, wg, wu, wd, g, experts),
-                          b[tok], w_gate, w_up, w_down, cut(gate))
-        dx, *dw_chunk, dg = back(dy[tok])
+        tok = cut(token)
+        dx, dw_chunk, dg = _chunk_back(b[tok], weights, transposed, cut(gate), cut(expert),
+                                       dy[tok])
         return (db.at[tok].add(dx), [a + c for a, c in zip(dw, dw_chunk)],
                 lax.dynamic_update_slice_in_dim(dgate, dg, start, 0))
 
     zeros = jnp.zeros_like
-    db, dw, dgate = _sweep(
-        step, (zeros(b), [zeros(w_gate), zeros(w_up), zeros(w_down)], zeros(gate)),
-        rows, chunk)
+    db, dw, dgate = _sweep(step, (zeros(b), [zeros(w) for w in weights], zeros(gate)),
+                           rows, chunk)
     return (db, *dw, dgate, None, None, None)
 
 
@@ -151,8 +213,9 @@ def held_mix(b, top_e, gates, w_gate, w_up, w_down, first: int, chunk: int,
     """The held experts' part of the mix for tokens ``b [N, D]``: weights
     ``[count, D, F]``, ``[count, D, F]``, ``[count, F, D]`` of experts
     ``first .. first + count - 1``, ``chunk`` sorted assignments a trip of
-    the loop, which sweeps ``provisioned`` rows (to the end of their chunk) at
-    the least. Returns ``(y [N, D], held assignments)``."""
+    the loop (``balanced_chunk_rows`` has the size a layer takes), which
+    sweeps ``provisioned`` rows (to the end of their chunk) at the least.
+    Returns ``(y [N, D], held assignments)``."""
     N, k = top_e.shape
     count = w_gate.shape[0]
     local = top_e.reshape(-1) - first
@@ -199,11 +262,13 @@ class ExpertLayer(nn.Module):
         logits = jnp.dot(b.astype(jnp.float32), w["router"], precision=HIGHEST)
         probs, top_e, gates = route(logits, self.top_k)
         load, prob = routing_statistics(probs, top_e)
+        provisioned = provisioned_rows(top_e.size, count, self.n_experts, self.capacity_factor)
         y, n_held = held_mix(
             b.astype(self.dtype), top_e, gates,
             *(w[name].astype(self.dtype) for name in ("w_gate", "w_up", "w_down")), first=first,
-            chunk=balanced_chunk_rows(top_e.size, count, self.n_experts),
-            provisioned=provisioned_rows(top_e.size, count, self.n_experts, self.capacity_factor))
+            chunk=balanced_chunk_rows(top_e.size, count, self.n_experts, provisioned, D,
+                                      self.width, self.dtype),
+            provisioned=provisioned)
         return h + y.reshape(h.shape).astype(h.dtype), {
             "load": load, "prob": prob, "balance": self.n_experts * jnp.sum(load * prob),
             "held_share": n_held.astype(jnp.float32) / top_e.size}

@@ -39,7 +39,7 @@ from simclr_pytorch_distributed_tpu.data import device_store
 from simclr_pytorch_distributed_tpu.data.device_store import slice_epoch_step
 from simclr_pytorch_distributed_tpu.data.pipeline import EpochLoader
 from simclr_pytorch_distributed_tpu.models import MODEL_DICT, SupConResNet
-from simclr_pytorch_distributed_tpu.models.experts import provisioned_rows
+from simclr_pytorch_distributed_tpu.models.experts import balanced_chunk_rows, provisioned_rows
 from simclr_pytorch_distributed_tpu.ops.augment import (
     DATASET_STATS,
     AugmentConfig,
@@ -259,21 +259,26 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
         return None
     first, count = spec.held
     rows = 2 * cfg.batch_size * (cfg.size // spec.patch) ** 2
+    provisioned = provisioned_rows(rows * spec.top_k, count, spec.n_experts, spec.capacity_factor)
+    trip = min(rows * spec.top_k, balanced_chunk_rows(
+        rows * spec.top_k, count, spec.n_experts, provisioned, spec.hidden, spec.expert_width,
+        model.dtype))
     plan = {"layers": spec.layers, "held": count, "first": first,
             "n_experts": spec.n_experts, "per_token": spec.top_k,
             "rows_per_step": rows, "capacity_factor": spec.capacity_factor,
-            "provisioned_assignments": provisioned_rows(
-                rows * spec.top_k, count, spec.n_experts, spec.capacity_factor),
+            "provisioned_assignments": provisioned,
+            "rows_per_trip": trip, "provisioned_trips": -(-provisioned // trip),
             "ring_columns": list(model.aux_metric_keys)}
     logging.info(
         "[experts] %d layers hold experts %d-%d of %d, %d a token (routed over "
         "all %d); %d token rows a step, %.1f%% of their assignments land here "
         "when the load is balanced; a layer sweeps %d assignments a step (%.4g "
-        "balanced shares) whatever the routing, and more where more land here",
+        "balanced shares, in trips of %d rows: %d) whatever the routing, and "
+        "more where more land here",
         spec.layers, first, first + count - 1,
         spec.n_experts, spec.top_k, spec.n_experts, rows,
-        100.0 * count / spec.n_experts, plan["provisioned_assignments"],
-        spec.capacity_factor,
+        100.0 * count / spec.n_experts, provisioned, spec.capacity_factor,
+        trip, plan["provisioned_trips"],
     )
     tracing.event("expert_plan", track=tracing.COMPILE_TRACK, **plan)
     return plan

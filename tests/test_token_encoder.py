@@ -207,7 +207,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(uncut_layer):
     assert sum(s for _, s in parts) == pytest.approx(1.0) == float(stats["held_share"])
 
 
-def mix_and_grad(params, h, first, count, chunk):
+def mix_and_grad(params, h, first, count, chunk, provisioned=0):
     """``held_mix`` itself, its value and its gradients, for experts ``first
     .. first + count - 1`` of the uncut layer at ``chunk`` rows a trip."""
     b = h.reshape(-1, h.shape[-1])
@@ -215,25 +215,44 @@ def mix_and_grad(params, h, first, count, chunk):
     held = [params[n][first: first + count] for n in ("w_gate", "w_up", "w_down")]
 
     def f(b, gates, *w):
-        return jnp.sum(jnp.sin(experts.held_mix(b, top_e, gates, *w, first=first, chunk=chunk)[0]))
+        return jnp.sum(jnp.sin(experts.held_mix(b, top_e, gates, *w, first=first, chunk=chunk,
+                                                provisioned=provisioned)[0]))
 
     return jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4))(b, gates, *held)
 
 
-@pytest.mark.parametrize("chunk", [3, 6, 12, 16, 1000])
-def test_no_token_is_dropped_whatever_the_chunk(uncut_layer, chunk):
+@pytest.mark.parametrize("chunk,provisioned", [(3, 0), (6, 0), (12, 0), (16, 0), (1000, 0),
+                                               (120, 120)])
+def test_no_token_is_dropped_whatever_the_chunk(uncut_layer, chunk, provisioned):
     """Values and gradients do not move with the length of the loop: 40, 20,
-    10 and 8 trips over the 120 assignments, or one that holds them all."""
+    10 and 8 trips over the 120 assignments, one that holds them all, or the
+    provision in one trip."""
     _, params, h = uncut_layer
-    want, got = mix_and_grad(params, h, 0, 16, 120), mix_and_grad(params, h, 0, 16, chunk)
+    want = mix_and_grad(params, h, 0, 16, 120)
+    got = mix_and_grad(params, h, 0, 16, chunk, provisioned)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
-def test_a_chunk_is_a_quarter_of_the_balanced_share():
-    rows = experts.balanced_chunk_rows(8 * 4096 * 8, 16, 128)  # the benchmark's step
-    assert rows == 8192 and 4 * rows == 32768
-    assert experts.balanced_chunk_rows(32, 4, 8) == 512  # never under a tile
+def test_a_chunk_is_as_many_rows_as_the_trip_budget_holds():
+    """``experts.TRIP_BYTES`` beside the nine weight-sized tensors of a trip,
+    in whole tiles, evened out over the rows the layer sweeps."""
+    spec, assignments = TOKEN_ENCODERS[REAL], 8 * 4096 * 8  # the benchmark's step
+    widths = (spec.hidden, spec.expert_width)
+    provisioned = experts.provisioned_rows(assignments, 16, 128, spec.capacity_factor)
+    rows = experts.balanced_chunk_rows(assignments, 16, 128, provisioned, *widths, jnp.float32)
+    assert rows == 32768 and rows % 512 == 0 and provisioned == 2 * rows
+    # nothing provisioned: no more than the balanced share
+    assert experts.balanced_chunk_rows(assignments, 16, 128, 0, *widths, jnp.float32) == 32768
+    assert experts.balanced_chunk_rows(32, 4, 8, 0, 32, 16, jnp.float32) == 512  # never under a tile
+    # under one budget 2-byte rows are more rows; the trips are equal, the
+    # last one short of full by less than a tile a trip
+    few, many = (experts.balanced_chunk_rows(2 ** 22, 16, 128, 2 ** 20, *widths, dtype)
+                 for dtype in (jnp.float32, jnp.bfloat16))
+    assert few == 36352 < many and few % 512 == 0 == many % 512
+    assert 0 <= -(-2 ** 20 // few) * few - 2 ** 20 < 512 * -(-2 ** 20 // few)
+    held = 9 * 16 * 2048 * 768 * 4 + 3 * (2048 + 768) * 4 * few  # what the budget counts
+    assert held <= experts.TRIP_BYTES < held + 3 * (2048 + 768) * 4 * 512
 
 
 def sweep_trips(rows, chunk):
@@ -454,6 +473,7 @@ def test_build_says_what_the_expert_layers_hold(one_step):
     assert plans[0]["args"] == {"layers": 2, "held": 4, "first": 0, "n_experts": 8,
                                 "per_token": 2, "rows_per_step": 8 * 16,
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
+                                "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
                                 "ring_columns": list(token_encoder.AUX_METRIC_KEYS)}
 
 
@@ -488,6 +508,7 @@ def test_trace_report_prints_the_expert_plan_and_the_ring_columns():
             "args": {}}
     plan = {"layers": 4, "held": 16, "first": 0, "n_experts": 128, "per_token": 8,
             "rows_per_step": 32768, "capacity_factor": 2.0, "provisioned_assignments": 65536,
+            "rows_per_trip": 32768, "provisioned_trips": 2,
             "ring_columns": ["moe_held_share", "moe_load_max_over_mean"]}
     events = [span,
               {"name": "expert_plan", "track": "compile", "ph": "i", "ts": 0.1, "args": plan},
@@ -498,5 +519,6 @@ def test_trace_report_prints_the_expert_plan_and_the_ring_columns():
         "moe_held_share": 0.124, "moe_load_max_over_mean": 1.3}}
     table = trace_report.render_table(report)
     assert "experts: 4 layers hold 16 of 128, 8 a token" in table
-    assert "65536 assignments a layer swept whatever the routing" in table
+    assert ("65536 assignments a layer swept whatever the routing in 2 trips of 32768 rows"
+            in table)
     assert "encoder" not in trace_report.build_report([span])  # a ResNet's run

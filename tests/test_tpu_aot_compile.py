@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from jax import lax
 
+from simclr_pytorch_distributed_tpu.models import experts
 from simclr_pytorch_distributed_tpu.models import sparse_attention as attention_layer
 from simclr_pytorch_distributed_tpu.models import token_encoder
 from simclr_pytorch_distributed_tpu.ops import pallas_loss, pointwise_bwd, sparse_attention
@@ -356,10 +357,11 @@ def test_sparse_attention_budget_is_the_compilers(one_chip):
     _compile(_ATTENTION_CALLS["fwd"], *_attention_kernel_shapes(one_chip, 5632)[0])
 
 
-def _top_level_arrays(text):
+def _top_level_arrays(text, within=None):
     """``[(opcode, dtype, dims)]`` of every array in the result type of every
     instruction outside fused computations and reducers: what goes through
-    HBM between the compiled program's kernels."""
+    HBM between the compiled program's kernels. ``within``: of the named
+    computations alone."""
     fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
     found, computation = [], None
     for line in text.splitlines():
@@ -368,7 +370,7 @@ def _top_level_arrays(text):
             computation = header.group(1)
             continue
         m = _HLO_LINE.match(line)
-        if not m or computation in fused:
+        if not m or computation in fused or (within is not None and computation not in within):
             continue
         for dtype, dims in re.findall(r"\b([a-z]+\d+|pred)\[([\d,]+)\]", m.group(2)):
             found.append((m.group(3), dtype, tuple(int(x) for x in dims.split(","))))
@@ -441,3 +443,70 @@ def test_no_layout_copy_around_the_attention_kernels(attention_layer_texts):
              for kernel, text in attention_layer_texts.items()}
     assert not moved[True], moved[True]
     assert len(moved[False]) <= 1, moved[False]
+
+
+# ---- the expert layer's sweep (models/experts.py): what a trip moves that
+# does not depend on its rows, and what the trip's size costs in memory
+
+
+@pytest.fixture(scope="module")
+def expert_layer_compiled(one_chip):
+    """The compiled gradient of one ``ExpertLayer`` at the cell's widths over
+    a step's 8 rows of 4,096 tokens."""
+    spec = token_encoder.TOKEN_ENCODERS["keye-vl2-a3b-ep8"]
+    layer = experts.ExpertLayer(
+        n_experts=spec.n_experts, top_k=spec.top_k, width=spec.expert_width, held=spec.held,
+        capacity_factor=spec.capacity_factor)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.key(0), jnp.zeros((2, 16, spec.hidden))))["params"])
+
+    def loss(params, h):
+        out, stats = layer.apply({"params": params}, h)
+        return jnp.sum(jnp.square(out)) + stats["balance"]
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, jax.ShapeDtypeStruct((8, 4096, spec.hidden), jnp.float32,
+                                     sharding=one_chip)).compile()
+
+
+def test_no_weight_is_laid_out_again_inside_the_expert_loops(expert_layer_compiled):
+    """The backward sweep's input-gradient products take the weights with
+    their last axes swapped; the swap is made once before the loop. With
+    ``jax.vjp`` of a chunk there were three ``copy`` instructions of a
+    ``[16, 2048, 768]`` float32 tensor in the backward loop's body, 0.6 GB a
+    trip (ISSUE 31). What is left there of that size and moves bytes in the
+    trip's own time: the three weight gradients out of their grouped products
+    and their three adds onto the carry. (The forward body's ``copy-start``
+    and ``slice-start`` of a weight are the compiler's prefetches into its
+    nearer memory, same layout, asynchronous: PERF.md section 6, PR 31.)"""
+    text = expert_layer_compiled.as_text()
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    assert len(bodies) == 2  # the forward sweep and the backward one
+    weight = 16 * 2048 * 768
+    inside = [(opcode, dims) for opcode, dtype, dims in _top_level_arrays(text, within=bodies)
+              if dtype == "f32" and math.prod(dims) >= weight and dims[0] == 16]
+    assert [found for found in inside if found[0] in ("copy", "transpose", "fusion")] == [], inside
+    assert [opcode for opcode, _ in inside].count("add") == 3, inside
+
+
+def test_a_trip_of_the_expert_sweep_holds_what_its_budget_says(expert_layer_compiled):
+    """At the cell's shapes ``experts.TRIP_BYTES`` gives 32,768 rows a trip,
+    and the rule counts 2.01 GB for such a trip: nine weight-sized tensors,
+    0.91 GB (three of them the gradients' sums, which are the layer's
+    results and no temporaries), and 1.11 GB of rows. The chip's compiler
+    holds the whole layer's gradient in 2.544 GB of temporaries there: the
+    trip's 1.71 GB and, beside it, the layer's own input, result and
+    cotangents (1.722 GB at the 8,192 rows of before, 3.651 GB with the
+    provision in one trip; PR 31)."""
+    spec = token_encoder.TOKEN_ENCODERS["keye-vl2-a3b-ep8"]
+    rows = experts.balanced_chunk_rows(
+        8 * 4096 * spec.top_k, spec.held[1], spec.n_experts, 65536, spec.hidden,
+        spec.expert_width, jnp.float32)
+    assert rows == 32768
+    weight = spec.held[1] * spec.hidden * spec.expert_width * 4
+    trip = 6 * weight + 3 * (spec.hidden + spec.expert_width) * 4 * rows
+    assert trip + 3 * weight <= experts.TRIP_BYTES
+    temp = expert_layer_compiled.memory_analysis().temp_size_in_bytes
+    assert trip < temp < 1.1 * 2.544e9, temp
