@@ -208,8 +208,9 @@ def encoder_section(events):
     ``train.supcon.plan_experts`` said at build (the ``expert_plan`` event on
     track ``compile``) and the newest ``health_window`` means of the
     encoder's own ring columns, which the event names (``ring_columns``),
-    with ``plan_sparse_attention``'s ``sparse_attention_plan`` event;
-    nothing for a ResNet's run."""
+    with ``plan_sparse_attention``'s ``sparse_attention_plan`` event or
+    ``plan_latent_attention``'s ``latent_attention_plan`` event; nothing for
+    a ResNet's run."""
     plan = next((e["args"] for e in events if e["name"] == "expert_plan"), None)
     if plan is None:
         return {}
@@ -221,6 +222,8 @@ def encoder_section(events):
     section = {"expert_plan": plan, "ring": last}
     section.update({"attention_plan": e["args"] for e in events
                     if e["name"] == "sparse_attention_plan"})
+    section.update({"latent_attention_plan": e["args"] for e in events
+                    if e["name"] == "latent_attention_plan"})
     return {"encoder": section}
 
 
@@ -259,8 +262,10 @@ def render_table(report):
         plan, ring = report["encoder"]["expert_plan"], report["encoder"]["ring"]
         lines.append(
             f"experts: {plan['layers']} layers hold {plan['held']} of "
-            f"{plan['n_experts']}, {plan['per_token']} a token, "
-            f"{plan['rows_per_step']} token rows a step, "
+            f"{plan['n_experts']}, {plan['per_token']} a token"
+            + (f" ({plan['router']}-routed, after {plan['dense_layers']} dense layers, "
+               f"shared experts of width {plan['shared_width']})" if "router" in plan else "")
+            + f", {plan['rows_per_step']} token rows a step, "
             f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
             f"the routing in {plan.get('provisioned_trips', '?')} trips of "
             f"{plan.get('rows_per_trip', '?')} rows; " + (", ".join(
@@ -272,6 +277,13 @@ def render_table(report):
                 f"{attention['on_xla']} on XLA's path" + "".join(
                     f"; {', '.join(names)}: {why}"
                     for why, names in attention["reasons"].items()))
+        latent = report["encoder"].get("latent_attention_plan")
+        if latent:
+            lines.append(
+                f"latent attention: {latent['layers']} layers of {latent['heads']} heads "
+                f"({latent['nope_dim']} + {latent['rope_dim']} shared rotary / "
+                f"{latent['v_dim']}), latent of {latent['kv_rank']}, {latent['tokens']} "
+                f"tokens a row, on {latent['path']}'s path: {latent['reason']}")
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

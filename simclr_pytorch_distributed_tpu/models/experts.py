@@ -1,12 +1,23 @@
-"""A layer of routed experts that is told which experts it holds.
+"""A layer of routed experts that is told which experts it holds, the
+shared experts beside them, and the dense feed-forward layer of the same form.
 
 Expert parallelism's layer as one chip runs it: the router scores every
-token over all ``n_experts`` (softmax, the ``top_k`` largest, renormalised
-over those), and this layer computes the part of the result that its own
-experts give, ``held = (first, count)``:
+token over all ``n_experts`` and chooses ``top_k`` of them by one of two
+rules (``route``), and this layer computes the part of the result that its
+own experts give, ``held = (first, count)``, and what every chip computes
+alike for its own rows, the shared experts (``shared_width``, none at 0):
 
-    y_t = sum over e in (top_k of t) and in held of gate[t, e] * f_e(b_t)
+    y_t = sum over e in (top_k of t) and in held of gate[t, e] * f_e(b_t) + f_sh(b_t)
     f_e(x) = (silu(x W_gate[e]) * (x W_up[e])) W_down[e]
+
+The rules: ``softmax`` takes the ``top_k`` largest probabilities and
+renormalises them over those; ``sigmoid`` scores each expert on its own,
+chooses the ``top_k`` of largest score plus a load-correcting bias, and
+weighs them by ``gate_scale`` times their unbiased scores over those scores'
+sum: the bias chooses and never weighs. The bias is state and no parameter
+(``route_bias`` in ``batch_stats``, zeros at rest): in train mode each step
+moves it by ``bias_rate`` towards the experts that took less than a balanced
+share of this step's assignments, and no gradient reaches it.
 
 What the absent experts would add is left out; no code stands in for the
 other chips or their exchange. No token is dropped, whatever the imbalance:
@@ -28,9 +39,14 @@ long as the data.
 
 Beside ``y`` the layer returns what the step and the tracing need of the
 routing, over all ``n_experts``: the share of assignments each expert took
-(``load``), its mean router probability (``prob``), the balance term
-``n_experts * sum(load * prob)`` and the share of assignments that landed on
-held experts.
+(``load``), its mean router probability (``prob``: for ``sigmoid`` the
+scores as shares of a token's sum), the balance term (``n_experts * sum(load
+* prob)`` over the batch or, ``sequence_balance``, the same product row by
+row, averaged over the rows), the share of assignments that landed on held
+experts and the bias's largest component.
+
+``DenseLayer`` is the layer a model's leading blocks have in the experts'
+place: one ``f`` for every token, a few rows at a time.
 """
 
 from __future__ import annotations
@@ -45,12 +61,15 @@ from jax import lax
 
 from simclr_pytorch_distributed_tpu.models.sparse_attention import (
     HIGHEST,
+    RMS_EPS,
+    map_row_groups,
     normal_init,
     rms_norm,
     tie_gradients,
 )
 
 SCOPE_EXPERTS = "experts"
+SCOPE_SHARED = "shared"
 
 
 # What one trip of the backward sweep may hold at once, in bytes. A trip holds
@@ -87,12 +106,23 @@ def provisioned_rows(assignments: int, held: int, n_experts: int,
     return min(int(capacity_factor * assignments * held / n_experts), assignments)
 
 
-def route(logits: jax.Array, top_k: int):
+def route(logits: jax.Array, top_k: int, rule: str = "softmax", bias=None, scale: float = 1.0):
     """``[N, E]`` float32 router logits -> ``(probabilities [N, E], the chosen
-    experts [N, k], their gates [N, k] renormalised over the k)``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_p, top_e = lax.top_k(probs, top_k)
-    return probs, top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    experts [N, k], their gates [N, k])`` by the module docstring's ``rule``;
+    ``sigmoid`` takes the ``bias [E]`` it chooses by and the gates' ``scale``,
+    and its probabilities are a token's scores over their sum. Ties go to the
+    lower index."""
+    if rule == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top_p, top_e = lax.top_k(probs, top_k)
+        return probs, top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if rule != "sigmoid":
+        raise ValueError(f"no router rule {rule!r}")
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, top_e = lax.top_k(scores + bias, top_k)
+    top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+    gates = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return scores / jnp.sum(scores, axis=-1, keepdims=True), top_e, gates
 
 
 def routing_statistics(probs: jax.Array, top_e: jax.Array):
@@ -101,6 +131,25 @@ def routing_statistics(probs: jax.Array, top_e: jax.Array):
     n_experts = probs.shape[-1]
     counts = jnp.zeros((n_experts,), jnp.float32).at[top_e.reshape(-1)].add(1.0)
     return counts / top_e.size, jnp.mean(probs, axis=0)
+
+
+def sequence_balance(probs: jax.Array, top_e: jax.Array, rows: int):
+    """The balance term row by row (DeepSeek-V3's sequence-wise form): for
+    each of the ``rows`` sequences that the ``N`` tokens are, ``n_experts *
+    sum_e(load_r[e] * prob_r[e])`` over that row's assignments and mean
+    probabilities; the rows' mean."""
+    n_experts, k = probs.shape[-1], top_e.shape[-1]
+    chosen = top_e.reshape(rows, -1)
+    counts = jnp.zeros((rows, n_experts), jnp.float32).at[
+        jnp.arange(rows)[:, None], chosen].add(1.0)
+    prob = jnp.mean(probs.reshape(rows, -1, n_experts), axis=1)
+    return jnp.mean(jnp.sum(counts * (n_experts / chosen.shape[1]) * prob, axis=-1))
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """``f(x) = (silu(x W_gate) * (x W_up)) W_down``: an expert's form, for
+    weights that every token meets."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def _group_sizes(expert, count: int):
@@ -235,9 +284,10 @@ def held_mix(b, top_e, gates, w_gate, w_up, w_down, first: int, chunk: int,
 
 class ExpertLayer(nn.Module):
     """The layer with its pre-norm and its residual: router over
-    ``n_experts`` and the ``held`` experts' weights. Takes tokens ``h [...,
-    D]``; returns ``(h + y, statistics)`` with the module docstring's
-    ``load``, ``prob``, ``balance`` and ``held_share``."""
+    ``n_experts``, the ``held`` experts' weights and the shared experts'.
+    Takes tokens ``h [R, T, D]``; returns ``(h + y, statistics)`` with the
+    module docstring's ``load``, ``prob``, ``balance``, ``held_share`` and,
+    where the router has a bias, ``bias_max_abs``."""
 
     n_experts: int
     top_k: int
@@ -245,9 +295,15 @@ class ExpertLayer(nn.Module):
     held: Tuple[int, int]
     capacity_factor: float = 0.0  # balanced shares every step sweeps; 0: as long as the data
     dtype: Any = jnp.float32
+    router: str = "softmax"  # or "sigmoid": ``route``'s rule
+    gate_scale: float = 1.0
+    bias_rate: float = 0.0  # the step of ``route_bias`` in train mode
+    sequence_balance: bool = False  # the balance term row by row
+    shared_width: int = 0
+    rms_eps: float = RMS_EPS
 
     @nn.compact
-    def __call__(self, h: jax.Array) -> tuple:
+    def __call__(self, h: jax.Array, train: bool = False) -> tuple:
         D = h.shape[-1]
         first, count = self.held
         if not (0 <= first and first + count <= self.n_experts and count > 0):
@@ -255,12 +311,21 @@ class ExpertLayer(nn.Module):
         w = {name: self.param(name, normal_init, shape) for name, shape in (
             ("router", (D, self.n_experts)), ("w_gate", (count, D, self.width)),
             ("w_up", (count, D, self.width)), ("w_down", (count, self.width, D)))}
+        if self.shared_width:
+            w.update({name: self.param(name, normal_init, shape) for name, shape in (
+                ("shared_gate", (D, self.shared_width)), ("shared_up", (D, self.shared_width)),
+                ("shared_down", (self.shared_width, D)))})
         w["norm"] = self.param("norm", nn.initializers.ones, (D,))
         w, h = tie_gradients((w, h))
-        b = rms_norm(h, w["norm"]).reshape(-1, D)
+        b = rms_norm(h, w["norm"], self.rms_eps).reshape(-1, D)
         # float32 at highest: a rounded operand must not flip a choice
         logits = jnp.dot(b.astype(jnp.float32), w["router"], precision=HIGHEST)
-        probs, top_e, gates = route(logits, self.top_k)
+        bias = None
+        if self.router != "softmax":
+            bias = self.variable("batch_stats", "route_bias", jnp.zeros,
+                                 (self.n_experts,), jnp.float32)
+        probs, top_e, gates = route(logits, self.top_k, self.router,
+                                    None if bias is None else bias.value, self.gate_scale)
         load, prob = routing_statistics(probs, top_e)
         provisioned = provisioned_rows(top_e.size, count, self.n_experts, self.capacity_factor)
         y, n_held = held_mix(
@@ -269,6 +334,45 @@ class ExpertLayer(nn.Module):
             chunk=balanced_chunk_rows(top_e.size, count, self.n_experts, provisioned, D,
                                       self.width, self.dtype),
             provisioned=provisioned)
-        return h + y.reshape(h.shape).astype(h.dtype), {
-            "load": load, "prob": prob, "balance": self.n_experts * jnp.sum(load * prob),
-            "held_share": n_held.astype(jnp.float32) / top_e.size}
+        if self.shared_width:
+            with jax.named_scope(SCOPE_SHARED):
+                y = y + gated_mlp(b.astype(self.dtype), *(
+                    w[name].astype(self.dtype)
+                    for name in ("shared_gate", "shared_up", "shared_down")))
+        out = h + y.reshape(h.shape).astype(h.dtype)
+        stats = {"load": load, "prob": prob,
+                 "balance": (sequence_balance(probs, top_e, h.shape[0]) if self.sequence_balance
+                             else self.n_experts * jnp.sum(load * prob)),
+                 "held_share": n_held.astype(jnp.float32) / top_e.size}
+        if bias is not None:
+            if train and not self.is_initializing():
+                bias.value = bias.value + self.bias_rate * jnp.sign(1.0 / self.n_experts - load)
+            stats["bias_max_abs"] = jnp.max(jnp.abs(bias.value))
+        return out, stats
+
+
+class DenseLayer(nn.Module):
+    """A feed-forward layer without experts, with its pre-norm and its
+    residual: ``h + f(rms(h))`` over tokens ``h [R, T, D]``, ``f`` of
+    ``width``. A few rows at a time, recomputed in the backward pass
+    (``map_row_groups``): the ``[tokens, width]`` intermediates are a
+    group's (1.5 GB each over 8 rows of 4,096 tokens at width 11,264)."""
+
+    width: int
+    rms_eps: float = RMS_EPS
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jax.Array) -> jax.Array:
+        D = h.shape[-1]
+        w = {name: self.param(name, normal_init, shape) for name, shape in (
+            ("w_gate", (D, self.width)), ("w_up", (D, self.width)), ("w_down", (self.width, D)))}
+        w["norm"] = self.param("norm", nn.initializers.ones, (D,))
+        w, h = tie_gradients((w, h))
+
+        def some_rows(h):
+            b = rms_norm(h, w["norm"], self.rms_eps).astype(self.dtype)
+            return h + gated_mlp(b, *(w[name].astype(self.dtype)
+                                      for name in ("w_gate", "w_up", "w_down"))).astype(h.dtype)
+
+        return map_row_groups(some_rows, h).reshape(h.shape)

@@ -78,6 +78,15 @@ tie_gradients.defvjp(lambda tree: (tree, None),
                      lambda _, ct: (lax.optimization_barrier(ct),))
 
 
+def map_row_groups(fn, h: jax.Array):
+    """``fn`` over ``h [R, ...]`` a group of ``ROW_GROUP`` rows at a time
+    (``lax.map``), each group recomputed in the backward pass
+    (``jax.checkpoint``): a layer's working set is then a group's. Returns
+    ``fn``'s results stacked, ``[R / group, ...]`` a leaf."""
+    group = math.gcd(h.shape[0], ROW_GROUP)
+    return lax.map(jax.checkpoint(fn), h.reshape(h.shape[0] // group, group, *h.shape[1:]))
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = RMS_EPS) -> jax.Array:
     """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis, in float32,
     returned in ``x``'s dtype."""
@@ -345,6 +354,5 @@ class SparseAttention(nn.Module):
         # a few rows at a time, recomputed in the backward pass: the layer's
         # working set (q, its rotation, the heads' outputs and their
         # cotangents, 0.5 GB each over 8 rows of 4,096 tokens) is a group's
-        group = math.gcd(R, ROW_GROUP)
-        out, kl = lax.map(jax.checkpoint(some_rows), h.reshape(R // group, group, T, D))
+        out, kl = map_row_groups(some_rows, h)
         return out.reshape(R, T, D), jnp.sum(kl) / (R * T)

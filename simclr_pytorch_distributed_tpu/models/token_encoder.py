@@ -1,21 +1,32 @@
-"""Encoders over patch tokens: a language model's block stack (sparse
-attention, routed experts) as an image encoder.
+"""Encoders over patch tokens: a language model's block stack as an image
+encoder. Two blocks so far, each a preset's data:
+
+- Keye-VL-2.0's: grouped-query attention behind a learned top-k key indexer
+  (``models/sparse_attention.py``), then softmax-routed experts
+  (``models/experts.py``), in every layer;
+- Moonlight-16B-A3B's (``deepseek_v3``): multi-head latent attention with one
+  shared rotary key (``models/latent_attention.py``), then a dense
+  feed-forward layer in the leading ``dense_layers`` blocks and, in the rest,
+  sigmoid-routed experts under a load-correcting bias beside shared experts.
 
 ``[N, H, W, 3]`` views are cut into non-overlapping ``patch x patch`` patches
-in raster order, embedded linearly, run through ``layers`` pre-norm blocks
-(``models/sparse_attention.py``, then ``models/experts.py``), RMS-normed and
-averaged over the tokens: ``[N, hidden]`` float32 features, what
-``SupConResNet`` hands its projection head. The widths of each preset live
-in ``TOKEN_ENCODERS`` and nowhere else; ``models/resnet.MODEL_DICT`` gets one
-entry a preset.
+in raster order, embedded linearly, run through ``layers`` pre-norm blocks,
+RMS-normed and averaged over the tokens: ``[N, hidden]`` float32 features,
+what ``SupConResNet`` hands its projection head. What a layer is made of
+(the attention's kind and widths, how many leading layers are dense and how
+wide, the router's rule, the shared experts' width, the gates' scale) lives
+in ``TOKEN_ENCODERS`` and nowhere else; ``models/resnet.MODEL_DICT`` gets
+one entry a preset. Module names are ``block<k>/attn`` and ``block<k>/moe``
+(``block<k>/mlp`` in a dense layer).
 
-Beside the features the encoder keeps, a layer, two running statistics in
-``batch_stats`` (``prob_mean``, ``load_mean`` over all experts, updated in
-train mode with ``STATS_MOMENTUM``) and sows into the collection ``aux``
-what the train step adds to its loss (``aux_loss``: the sum over the layers
-of ``balance_coef * balance + index_coef * indexer's KL``) and what it
-writes to the metric ring (``TokenEncoder.aux_metric_keys``, each the
-layers' mean; ``TokenEncoder.read_aux`` takes both out again).
+Beside the features the encoder keeps, an expert layer, running statistics
+in ``batch_stats`` (``prob_mean``, ``load_mean`` over all experts, updated in
+train mode with ``STATS_MOMENTUM``; under ``moe``, where the router has one,
+its bias ``route_bias``) and sows into the collection ``aux`` what the train
+step adds to its loss (``aux_loss``: the sum over the layers of
+``balance_coef * balance + index_coef * indexer's KL``) and what it writes to
+the metric ring (``ring_columns`` of the preset, each the mean over the
+layers that have it; ``TokenEncoder.read_aux`` takes both out again).
 """
 
 from __future__ import annotations
@@ -28,15 +39,18 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from simclr_pytorch_distributed_tpu.models.experts import ExpertLayer
+from simclr_pytorch_distributed_tpu.models.experts import DenseLayer, ExpertLayer
+from simclr_pytorch_distributed_tpu.models.latent_attention import LatentAttention
 from simclr_pytorch_distributed_tpu.models.resnet import MODEL_DICT, build_encoder
 from simclr_pytorch_distributed_tpu.models.sparse_attention import (
+    RMS_EPS,
     SparseAttention,
     normal_init,
     rms_norm,
 )
 
 AUX_COLLECTION = "aux"
+# the ring columns of a block with sparse attention and a softmax router
 AUX_METRIC_KEYS = ("indexer_kl", "moe_held_share", "moe_load_max_over_mean")
 # weight of the new batch in the running statistics: BatchNorm's, which the
 # ResNets' statistics move with (models/norm.py)
@@ -49,24 +63,48 @@ class TokenEncoderSpec:
     hidden: int
     layers: int
     n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    index_heads: int
-    index_dim: int
-    topk: int
     q_chunk: int
     rope_theta: float
-    mrope_section: Tuple[int, int, int]
     n_experts: int
     top_k: int
     expert_width: int
     held: Tuple[int, int]  # (first, count) of n_experts
+    # "sparse": SparseAttention, which takes the next six; "latent":
+    # LatentAttention, which takes the four after them
+    attention: str = "sparse"
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    topk: int = 0
+    mrope_section: Tuple[int, ...] = ()
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    # leading layers with a dense feed-forward layer in the experts' place
+    dense_layers: int = 0
+    dense_width: int = 0
+    # experts.route's rule, and what the "sigmoid" rule takes
+    router: str = "softmax"
+    gate_scale: float = 1.0
+    bias_rate: float = 0.0
+    sequence_balance: bool = False
+    shared_width: int = 0
+    rms_eps: float = RMS_EPS
     # balanced shares of assignments an expert layer sweeps every step,
     # whatever the routing (models/experts.py): twice the balanced load, the
     # capacity factor of GShard's training runs, and beyond it as the data asks
     capacity_factor: float = 2.0
     balance_coef: float = 0.001
     index_coef: float = 1.0
+
+    @property
+    def ring_columns(self) -> Tuple[str, ...]:
+        """What an encoder of this preset sows for the metric ring."""
+        return ((("indexer_kl",) if self.attention == "sparse" else ())
+                + ("moe_held_share", "moe_load_max_over_mean")
+                + (("route_bias_max_abs",) if self.router != "softmax" else ()))
 
 
 TOKEN_ENCODERS = {
@@ -84,6 +122,28 @@ TOKEN_ENCODERS = {
         patch=4, hidden=32, layers=2, n_heads=4, n_kv_heads=2, head_dim=8,
         index_heads=2, index_dim=4, topk=6, q_chunk=4, rope_theta=1e7,
         mrope_section=(1, 1, 2), n_experts=8, top_k=2, expert_width=16, held=(0, 4)),
+    # Moonlight-16B-A3B's block (model_type deepseek_v3) at its published widths
+    # (https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json),
+    # one chip's share of an eight-way expert split: 8 of the 64 routed
+    # experts, the shared experts whole, the leading dense layer and 4 of the
+    # 26 that follow (benchmark/configs/moonlight-16b-a3b-ep8.json has the cut);
+    # bias_rate and the balance term are DeepSeek-V3's, whose method the
+    # config names
+    "moonlight-16b-a3b-ep8": TokenEncoderSpec(
+        patch=16, hidden=2048, layers=5, n_heads=16, q_chunk=512, rope_theta=5e4,
+        attention="latent", kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        dense_layers=1, dense_width=11264, n_experts=64, top_k=6, expert_width=1408,
+        held=(0, 8), router="sigmoid", gate_scale=2.446, bias_rate=0.001,
+        sequence_balance=True, shared_width=2816, rms_eps=1e-5, balance_coef=1e-4),
+    # the same block at test size: one dense layer and one of experts, 16
+    # tokens, query/key heads of 12 beside value heads of 8, half of the
+    # experts held
+    "moonlight-tiny": TokenEncoderSpec(
+        patch=4, hidden=32, layers=2, n_heads=4, q_chunk=4, rope_theta=5e4,
+        attention="latent", kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+        dense_layers=1, dense_width=48, n_experts=8, top_k=2, expert_width=16,
+        held=(0, 4), router="sigmoid", gate_scale=2.446, bias_rate=0.001,
+        sequence_balance=True, shared_width=24, rms_eps=1e-5, balance_coef=1e-4),
 }
 
 
@@ -97,21 +157,45 @@ def attention_attrs(s: TokenEncoderSpec, dtype, kernel: bool) -> dict:
         dtype=dtype, kernel=kernel)
 
 
+def latent_attrs(s: TokenEncoderSpec, dtype) -> dict:
+    """The attributes of a block's ``LatentAttention``."""
+    return dict(
+        n_heads=s.n_heads, kv_rank=s.kv_rank, nope_dim=s.nope_dim, rope_dim=s.rope_dim,
+        v_dim=s.v_dim, q_chunk=s.q_chunk, rope_theta=s.rope_theta, rms_eps=s.rms_eps,
+        dtype=dtype)
+
+
 class Block(nn.Module):
+    """Layer ``index`` of the preset: its attention, then its dense layer or
+    its experts. Returns ``(h, the indexer's KL or None, the expert layer's
+    statistics or None)``."""
+
     spec: TokenEncoderSpec
     dtype: Any = jnp.float32
     remat: bool = False
     attn_kernel: bool = False
+    index: int = 0
 
     @nn.compact
     def __call__(self, h: jax.Array, train: bool):
         s = self.spec
-        wrap = nn.remat if self.remat else (lambda cls: cls)
-        h, kl = wrap(SparseAttention)(
-            **attention_attrs(s, self.dtype, self.attn_kernel), name="attn")(h)
-        h, routed = wrap(ExpertLayer)(
+        wrap = nn.remat if self.remat else (lambda cls, **_: cls)
+        kl = None
+        if s.attention == "sparse":
+            h, kl = wrap(SparseAttention)(
+                **attention_attrs(s, self.dtype, self.attn_kernel), name="attn")(h)
+        elif s.attention == "latent":  # recomputes its row groups itself, remat or not
+            h = LatentAttention(**latent_attrs(s, self.dtype), name="attn")(h)
+        else:
+            raise ValueError(f"no attention of kind {s.attention!r}")
+        if self.index < s.dense_layers:  # likewise
+            return DenseLayer(s.dense_width, s.rms_eps, self.dtype, name="mlp")(h), kl, None
+        h, routed = wrap(ExpertLayer, static_argnums=(2,))(
             n_experts=s.n_experts, top_k=s.top_k, width=s.expert_width, held=s.held,
-            capacity_factor=s.capacity_factor, dtype=self.dtype, name="moe")(h)
+            capacity_factor=s.capacity_factor, dtype=self.dtype, router=s.router,
+            gate_scale=s.gate_scale, bias_rate=s.bias_rate,
+            sequence_balance=s.sequence_balance, shared_width=s.shared_width,
+            rms_eps=s.rms_eps, name="moe")(h, train)
         for name in ("prob", "load"):  # the forward pass's order
             mean = self.variable("batch_stats", f"{name}_mean", jnp.zeros,
                                  (s.n_experts,), jnp.float32)
@@ -126,20 +210,24 @@ class TokenEncoder(nn.Module):
 
     spec: Optional[TokenEncoderSpec] = None
     dtype: Any = jnp.float32
-    remat: bool = False  # each block's attention and expert layer recomputed in the backward
+    # each block's sparse attention and expert layer recomputed in the backward
+    remat: bool = False
     # attention through ops/sparse_attention.py's kernel pair: set by
     # train.supcon.build on a one-device TPU mesh; each layer's dtype and
     # shape can still say no (SparseAttention.kernel_reason)
     attn_kernel: bool = False
-    # the ring columns this encoder sows beside its ``aux_loss``; an encoder
-    # without the attribute (a ResNet) sows nothing
-    aux_metric_keys = AUX_METRIC_KEYS
 
-    @staticmethod
-    def read_aux(sown: dict):
+    @property
+    def aux_metric_keys(self) -> Tuple[str, ...]:
+        """The ring columns this encoder sows beside its ``aux_loss``; an
+        encoder without the attribute (a ResNet) sows nothing."""
+        return self.spec.ring_columns
+
+    @nn.nowrap
+    def read_aux(self, sown: dict):
         """``(aux_loss, {ring column: value})`` from what a train-mode
         ``apply`` sowed into the collection ``aux`` under this module."""
-        return sown["aux_loss"], {k: sown[k] for k in AUX_METRIC_KEYS}
+        return sown["aux_loss"], {k: sown[k] for k in self.aux_metric_keys}
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = True) -> jax.Array:
@@ -151,19 +239,29 @@ class TokenEncoder(nn.Module):
         u = x.astype(self.dtype).reshape(n, height // p, p, width // p, p, c)
         u = u.transpose(0, 1, 3, 2, 4, 5).reshape(n, (height // p) * (width // p), p * p * c)
         h = nn.Dense(s.hidden, kernel_init=normal_init, dtype=self.dtype, name="patch_embed")(u)
-        aux_loss, sums = jnp.zeros((), jnp.float32), dict.fromkeys(AUX_METRIC_KEYS, 0.0)
+        aux_loss, sums = jnp.zeros((), jnp.float32), dict.fromkeys(s.ring_columns, 0.0)
         for k in range(s.layers):
-            h, kl, routed = Block(s, self.dtype, self.remat, self.attn_kernel,
+            h, kl, routed = Block(s, self.dtype, self.remat, self.attn_kernel, k,
                                   name=f"block{k}")(h, train)
-            aux_loss = aux_loss + s.balance_coef * routed["balance"] + s.index_coef * kl
-            sums["indexer_kl"] += kl
-            sums["moe_held_share"] += routed["held_share"]
-            sums["moe_load_max_over_mean"] += jnp.max(routed["load"]) * s.n_experts
-        z = rms_norm(h, self.param("final_norm", nn.initializers.ones, (s.hidden,)))
+            # the loss's terms before the columns' sums, balance before KL: the
+            # order of the first block's program, which must not move
+            if routed is not None:
+                aux_loss = aux_loss + s.balance_coef * routed["balance"]
+            if kl is not None:
+                aux_loss = aux_loss + s.index_coef * kl
+                sums["indexer_kl"] += kl
+            if routed is not None:
+                sums["moe_held_share"] += routed["held_share"]
+                sums["moe_load_max_over_mean"] += jnp.max(routed["load"]) * s.n_experts
+                if "bias_max_abs" in routed:
+                    sums["route_bias_max_abs"] += routed["bias_max_abs"]
+        z = rms_norm(h, self.param("final_norm", nn.initializers.ones, (s.hidden,)), s.rms_eps)
         keep_last = lambda _, value: value  # noqa: E731
         self.sow(AUX_COLLECTION, "aux_loss", aux_loss, reduce_fn=keep_last, init_fn=lambda: None)
         for key, total in sums.items():
-            self.sow(AUX_COLLECTION, key, jax.lax.stop_gradient(total / s.layers),
+            # the mean over the layers that have the column
+            over = s.layers if key == "indexer_kl" else s.layers - s.dense_layers
+            self.sow(AUX_COLLECTION, key, jax.lax.stop_gradient(total / over),
                      reduce_fn=keep_last, init_fn=lambda: None)
         return jnp.mean(z.astype(jnp.float32), axis=1)
 
@@ -171,15 +269,15 @@ class TokenEncoder(nn.Module):
 def attention_plan(
     model: str, size: int, owner_reason: Optional[str] = None, **encoder_kwargs
 ) -> list:
-    """One ``{"name", "reason"}`` per attention layer of ``model``: ``reason``
-    is None where the layer runs ops/sparse_attention.py's kernel pair over
-    the patch tokens of ``size x size`` views and otherwise says why it stays
-    XLA's: the owner's
-    (``owner_reason``: mesh size, backend) or the layer's own
-    ``SparseAttention.kernel_reason``, which is what its ``__call__`` asks
-    too. An encoder that is no ``TokenEncoder`` has no such layer."""
+    """One ``{"name", "reason"}`` per sparse-attention layer of ``model``:
+    ``reason`` is None where the layer runs ops/sparse_attention.py's kernel
+    pair over the patch tokens of ``size x size`` views and otherwise says
+    why it stays XLA's: the owner's (``owner_reason``: mesh size, backend) or
+    the layer's own ``SparseAttention.kernel_reason``, which is what its
+    ``__call__`` asks too. An encoder that is no ``TokenEncoder``, or whose
+    preset has another attention, has no such layer."""
     mod = build_encoder(model, **encoder_kwargs)
-    if not isinstance(mod, TokenEncoder):
+    if not isinstance(mod, TokenEncoder) or mod.spec.attention != "sparse":
         return []
     layer = SparseAttention(**attention_attrs(mod.spec, mod.dtype, True))
     reason = owner_reason or layer.kernel_reason((size // mod.spec.patch) ** 2)
@@ -187,14 +285,22 @@ def attention_plan(
 
 
 def match_tree(encoder_params: dict) -> Optional[str]:
-    """The preset whose parameter tree ``encoder_params`` is, or None."""
+    """The preset whose parameter tree ``encoder_params`` is, or None: by the
+    number of blocks, how many of them are dense, the query projection's
+    shape, and the router's and the held experts' of the last block (every
+    preset's last layer has experts)."""
     if "patch_embed" not in encoder_params:
         return None
-    layers = sum(1 for name in encoder_params if name.startswith("block"))
-    moe = encoder_params["block0"]["moe"]
-    shape = (layers, *moe["router"].shape, *moe["w_gate"].shape)
+    blocks = [encoder_params[name] for name in encoder_params if name.startswith("block")]
+    moe = encoder_params[f"block{len(blocks) - 1}"].get("moe", {})
+    if "router" not in moe:
+        return None
+    got = (len(blocks), sum("mlp" in b for b in blocks), *blocks[0]["attn"]["q"].shape,
+           *moe["router"].shape, *moe["w_gate"].shape)
     for name, s in TOKEN_ENCODERS.items():
-        if shape == (s.layers, s.hidden, s.n_experts, s.held[1], s.hidden, s.expert_width):
+        q_cols = s.n_heads * (s.head_dim if s.attention == "sparse" else s.nope_dim + s.rope_dim)
+        if got == (s.layers, s.dense_layers, s.hidden, q_cols, s.hidden, s.n_experts,
+                   s.held[1], s.hidden, s.expert_width):
             return name
     return None
 

@@ -248,12 +248,37 @@ def plan_sparse_attention(
     return _say_kernel_plan("sparse_attention", "attention layers on the kernel pair", plan)
 
 
+def plan_latent_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
+    """What the latent-attention layers of ``model``'s encoder are, said once
+    in a banner line and one ``latent_attention_plan`` event (track
+    ``compile``): layers, heads, the three head widths, the latent's rank,
+    and the path with its reason. Today the path is always XLA's:
+    ops/sparse_attention.py's kernel pair takes one head width for q, k and
+    v. None for an encoder without such layers."""
+    spec = getattr(model.build_encoder(), "spec", None)
+    if spec is None or spec.attention != "latent":
+        return None
+    plan = {"layers": spec.layers, "heads": spec.n_heads, "nope_dim": spec.nope_dim,
+            "rope_dim": spec.rope_dim, "v_dim": spec.v_dim, "kv_rank": spec.kv_rank,
+            "tokens": (cfg.size // spec.patch) ** 2, "path": "xla",
+            "reason": "no kernel for query/key heads of "
+                      f"{spec.nope_dim + spec.rope_dim} beside value heads of {spec.v_dim}"}
+    logging.info(
+        "[latent_attention] %d layers of %d heads (%d + %d shared rotary / %d), "
+        "keys and values out of a latent of %d, %d causal tokens a row; on XLA's "
+        "path: %s", plan["layers"], plan["heads"], plan["nope_dim"], plan["rope_dim"],
+        plan["v_dim"], plan["kv_rank"], plan["tokens"], plan["reason"])
+    tracing.event("latent_attention_plan", track=tracing.COMPILE_TRACK, **plan)
+    return plan
+
+
 def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
     """What the expert layers of ``model``'s encoder hold, said once in a
     banner line and one ``expert_plan`` event (track ``compile``), as
-    ``plan_pointwise_bwd`` says its plan, with the ring columns the encoder
-    sows (``scripts/trace_report.py`` reads their names from the event);
-    None for an encoder without experts."""
+    ``plan_pointwise_bwd`` says its plan, with the dense layers before them,
+    the router's rule, the shared experts' width and the ring columns the
+    encoder sows (``scripts/trace_report.py`` reads their names from the
+    event); None for an encoder without experts."""
     spec = getattr(model.build_encoder(), "spec", None)
     if spec is None:
         return None
@@ -263,20 +288,24 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
     trip = min(rows * spec.top_k, balanced_chunk_rows(
         rows * spec.top_k, count, spec.n_experts, provisioned, spec.hidden, spec.expert_width,
         model.dtype))
-    plan = {"layers": spec.layers, "held": count, "first": first,
+    plan = {"layers": spec.layers - spec.dense_layers, "held": count, "first": first,
             "n_experts": spec.n_experts, "per_token": spec.top_k,
             "rows_per_step": rows, "capacity_factor": spec.capacity_factor,
             "provisioned_assignments": provisioned,
             "rows_per_trip": trip, "provisioned_trips": -(-provisioned // trip),
+            "dense_layers": spec.dense_layers, "router": spec.router,
+            "shared_width": spec.shared_width,
             "ring_columns": list(model.aux_metric_keys)}
     logging.info(
-        "[experts] %d layers hold experts %d-%d of %d, %d a token (routed over "
-        "all %d); %d token rows a step, %.1f%% of their assignments land here "
+        "[experts] %d layers hold experts %d-%d of %d, %d a token (%s-routed "
+        "over all %d) after %d dense layers, shared experts of width %d beside "
+        "them; %d token rows a step, %.1f%% of their assignments land here "
         "when the load is balanced; a layer sweeps %d assignments a step (%.4g "
         "balanced shares, in trips of %d rows: %d) whatever the routing, and "
         "more where more land here",
-        spec.layers, first, first + count - 1,
-        spec.n_experts, spec.top_k, spec.n_experts, rows,
+        plan["layers"], first, first + count - 1,
+        spec.n_experts, spec.top_k, spec.router, spec.n_experts, spec.dense_layers,
+        spec.shared_width, rows,
         100.0 * count / spec.n_experts, provisioned, spec.capacity_factor,
         trip, plan["provisioned_trips"],
     )
@@ -304,6 +333,7 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
         attn_kernel=any(layer["reason"] is None for layer in attention_plan),
         **encoder_kwargs,
     )
+    plan_latent_attention(cfg, model)
     plan_experts(cfg, model)
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the

@@ -1,5 +1,6 @@
-"""The token encoder (models/token_encoder.py: sparse attention behind a
-learned key indexer, routed experts of which this chip holds a share) against
+"""The token encoder's first block (models/token_encoder.py: sparse attention
+behind a learned key indexer, routed experts of which this chip holds a
+share; tests/test_latent_encoder.py has the second block) against
 its plain reference (benchmark/reference_tokens.py), at the tiny preset on
 the CPU with seeded weights; the selection and the expert share on their own;
 and the ResNets through the encoder protocol that the token encoder brought.
@@ -207,11 +208,13 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(uncut_layer):
     assert sum(s for _, s in parts) == pytest.approx(1.0) == float(stats["held_share"])
 
 
-def mix_and_grad(params, h, first, count, chunk, provisioned=0):
+def mix_and_grad(params, h, first, count, chunk, provisioned=0, rule="softmax"):
     """``held_mix`` itself, its value and its gradients, for experts ``first
-    .. first + count - 1`` of the uncut layer at ``chunk`` rows a trip."""
+    .. first + count - 1`` of the uncut layer at ``chunk`` rows a trip, routed
+    by ``rule`` (the second under a bias that moves the choice)."""
     b = h.reshape(-1, h.shape[-1])
-    _, top_e, gates = experts.route(b @ params["router"], 4)
+    bias = 0.2 * jax.random.normal(jax.random.key(8), (params["router"].shape[1],))
+    _, top_e, gates = experts.route(b @ params["router"], 4, rule, bias, 2.446)
     held = [params[n][first: first + count] for n in ("w_gate", "w_up", "w_down")]
 
     def f(b, gates, *w):
@@ -221,15 +224,16 @@ def mix_and_grad(params, h, first, count, chunk, provisioned=0):
     return jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4))(b, gates, *held)
 
 
+@pytest.mark.parametrize("rule", ["softmax", "sigmoid"])
 @pytest.mark.parametrize("chunk,provisioned", [(3, 0), (6, 0), (12, 0), (16, 0), (1000, 0),
                                                (120, 120)])
-def test_no_token_is_dropped_whatever_the_chunk(uncut_layer, chunk, provisioned):
+def test_no_token_is_dropped_whatever_the_chunk(uncut_layer, chunk, provisioned, rule):
     """Values and gradients do not move with the length of the loop: 40, 20,
     10 and 8 trips over the 120 assignments, one that holds them all, or the
-    provision in one trip."""
+    provision in one trip; under either rule of the router."""
     _, params, h = uncut_layer
-    want = mix_and_grad(params, h, 0, 16, 120)
-    got = mix_and_grad(params, h, 0, 16, chunk, provisioned)
+    want = mix_and_grad(params, h, 0, 16, 120, rule=rule)
+    got = mix_and_grad(params, h, 0, 16, chunk, provisioned, rule)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
@@ -474,6 +478,7 @@ def test_build_says_what_the_expert_layers_hold(one_step):
                                 "per_token": 2, "rows_per_step": 8 * 16,
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
                                 "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
+                                "dense_layers": 0, "router": "softmax", "shared_width": 0,
                                 "ring_columns": list(token_encoder.AUX_METRIC_KEYS)}
 
 

@@ -510,3 +510,69 @@ def test_a_trip_of_the_expert_sweep_holds_what_its_budget_says(expert_layer_comp
     assert trip + 3 * weight <= experts.TRIP_BYTES
     temp = expert_layer_compiled.memory_analysis().temp_size_in_bytes
     assert trip < temp < 1.1 * 2.544e9, temp
+
+
+# ---- the whole step of the cell moonlight-16b-a3b-ep8.pretrain-1024px-b4
+# (latent attention, a dense leading layer, shared and routed experts): 8 rows
+# of 4,096 tokens through train.supcon's own program
+
+
+def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, tmp_path):
+    """``ring_update`` as ``benchmark/run.py`` builds it (the configuration's
+    flags, global batch 4, the resident store's 64-step epoch buffer), lowered
+    from shapes for one described v5e. The chip's compiler holds it in 13.2 GB
+    of arguments and temporaries (Keye's step, which loads, in 13.6); the dense
+    layer's ``[tokens, 11264]`` intermediates are a 2-row group's (369 MB),
+    never the batch's (1.48 GB); the expert sweep makes 2 trips of 24,576
+    rows over its provision."""
+    import json
+
+    from simclr_pytorch_distributed_tpu import config as config_lib
+    from simclr_pytorch_distributed_tpu import recipes as recipes_lib
+    from simclr_pytorch_distributed_tpu.ops.metrics import MetricRing
+    from simclr_pytorch_distributed_tpu.parallel.mesh import (
+        create_mesh, replicated_sharding, state_sharding)
+    from simclr_pytorch_distributed_tpu.train import supcon
+    from simclr_pytorch_distributed_tpu.train.supcon_step import metric_keys
+
+    name = "moonlight-16b-a3b-ep8"
+    spec = token_encoder.TOKEN_ENCODERS[name]
+    assert experts.balanced_chunk_rows(8 * 4096 * spec.top_k, spec.held[1], spec.n_experts,
+                                       49152, spec.hidden, spec.expert_width,
+                                       jnp.float32) == 24576
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
+        flags = json.load(f)["flags"]
+    cfg = config_lib.parse_supcon(flags + [
+        "--batch_size", "4", "--dataset", "synthetic", "--workdir", str(tmp_path)])
+    mesh = create_mesh(devices=[topo.devices[0]])
+    built = {}
+
+    def abstract_state():
+        model, schedule, tx, state, step_cfg = supcon.build(cfg, 64, 1)
+        built.update(model=model, schedule=schedule, tx=tx, step_cfg=step_cfg)
+        return state
+
+    state = jax.eval_shape(abstract_state)
+    state, recipe = recipes_lib.attach_for_config(cfg, built["model"], state,
+                                                  schedule=built["schedule"])
+    ring = MetricRing(cfg.print_freq, metric_keys(
+        health=built["step_cfg"].health, online_probe=built["step_cfg"].online_probe,
+        extra=recipe.metric_keys))
+    update = supcon.make_fused_update(
+        built["model"], built["tx"], built["schedule"], built["step_cfg"],
+        supcon.make_augment_config(cfg), mesh, state, metric_ring=ring, resident=True,
+        recipe=recipe)
+    repl = replicated_sharding(mesh)
+    placed = lambda x, s=repl: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)  # noqa: E731
+    compiled = update.lower(
+        jax.tree.map(placed, state, state_sharding(mesh, state)),
+        jax.tree.map(placed, jax.eval_shape(ring.init_buffer)),
+        placed(jax.ShapeDtypeStruct((64, 4, 1024, 1024, 3), jnp.uint8)),
+        placed(jax.ShapeDtypeStruct((64, 4), jnp.int32)),
+        jax.tree.map(placed, jax.eval_shape(lambda: jax.random.key(0)))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.7e9
+    wide = [dims for _, dtype, dims in _top_level_arrays(compiled.as_text())
+            if spec.dense_width in dims]
+    assert wide and max(math.prod(dims) for dims in wide) <= 2 * 4096 * spec.dense_width, wide
