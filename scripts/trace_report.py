@@ -268,7 +268,11 @@ def render_table(report):
             + f", {plan['rows_per_step']} token rows a step, "
             f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
             f"the routing in {plan.get('provisioned_trips', '?')} trips of "
-            f"{plan.get('rows_per_trip', '?')} rows; " + (", ".join(
+            f"{plan.get('rows_per_trip', '?')} rows"
+            + (f", grouped products on {plan['product_operands']} operands"
+               + (f" ({plan['product_reason']})" if plan.get("product_reason") else "")
+               if "product_operands" in plan else "")
+            + "; " + (", ".join(
                 f"{k} {v:.4g}" for k, v in ring.items()) or "no health window yet"))
         attention = report["encoder"].get("attention_plan")
         if attention:

@@ -37,6 +37,16 @@ pays for the rows its routing gave these experts, and nothing is sized for
 the worst case. ``capacity_factor`` 0 provisions nothing: the loop is then as
 long as the data.
 
+The grouped products read their operands in a type of their own
+(``ExpertLayer.product_dtype``, the layer's where None) and give the layer's.
+A TPU's product at default precision rounds float32 operands to bfloat16
+inside every call, after reading them at four bytes an element; with
+bfloat16 given as the products' type the same rounding is done once where
+an operand is made (tokens and weights once a sweep, before the loop; a
+chunk's intermediates as the elementwise pass that makes them writes them),
+inside the custom VJP, so that no cotangent is rounded, and the layer's
+results are the same to the bit while every call reads half the bytes.
+
 Beside ``y`` the layer returns what the step and the tracing need of the
 routing, over all ``n_experts``: the share of assignments each expert took
 (``load``), its mean router probability (``prob``: for ``sigmoid`` the
@@ -163,14 +173,17 @@ def _group_sizes(expert, count: int):
 
 def _chunk_out(x, w_gate, w_up, w_down, gate, expert):
     """One chunk of sorted assignments: token rows ``x [C, D]``, their gates
-    and (local) experts (``_group_sizes``)."""
+    and (local) experts (``_group_sizes``). Rows and weights come in the type
+    the grouped products read (``held_mix``'s ``product``); the products'
+    results and all arithmetic on them are of the gates' type, the layer's,
+    and ``hidden`` is rounded to the products' as it is written."""
     count = w_gate.shape[0]
     sizes = _group_sizes(expert, count)
     x = jnp.where((expert < count)[:, None], x, 0)
+    dot = functools.partial(lax.ragged_dot, group_sizes=sizes, preferred_element_type=gate.dtype)
     with jax.named_scope(SCOPE_EXPERTS):
-        hidden = (jax.nn.silu(lax.ragged_dot(x, w_gate, sizes))
-                  * lax.ragged_dot(x, w_up, sizes))
-        out = lax.ragged_dot(hidden, w_down, sizes)
+        hidden = (jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)).astype(x.dtype)
+        out = dot(hidden, w_down)
     return out * gate[:, None]
 
 
@@ -187,21 +200,28 @@ def _chunk_back(x, weights, transposed, gate, expert, dy):
     the cotangent ``dy [C, D]`` of its result. The input-gradient products
     take the weights with their last two axes swapped, ``transposed``, which
     the caller makes once for all chunks (``jax.vjp`` of ``_chunk_out``
-    swaps them inside every trip)."""
+    swaps them inside every trip). ``dy`` and the gates are of the layer's
+    type, and so is everything returned; ``x`` and the weights of the
+    products', to which ``hidden``, ``d_out``, ``d_a`` and ``d_u`` are rounded
+    as they are written: where a product at default precision rounds them."""
     w_gate, w_up, w_down = weights
     gate_t, up_t, down_t = transposed
     count = w_gate.shape[0]
     sizes = _group_sizes(expert, count)
     held = (expert < count)[:, None]
     x = jnp.where(held, x, 0)
-    d_out = dy * gate[:, None]
+    as_operand = lambda a: a.astype(x.dtype)  # noqa: E731
+    dot = functools.partial(lax.ragged_dot, group_sizes=sizes, preferred_element_type=gate.dtype)
+    d_out = as_operand(dy * gate[:, None])
     with jax.named_scope(SCOPE_EXPERTS):
-        pre = lax.ragged_dot(x, w_gate, sizes), lax.ragged_dot(x, w_up, sizes)
+        pre = dot(x, w_gate), dot(x, w_up)
         hidden, back = jax.vjp(lambda a, u: jax.nn.silu(a) * u, *pre)
-        out = lax.ragged_dot(hidden, w_down, sizes)
-        d_a, d_u = back(lax.ragged_dot(d_out, down_t, sizes))
-        dx = lax.ragged_dot(d_a, gate_t, sizes) + lax.ragged_dot(d_u, up_t, sizes)
-        dw = [lax.ragged_dot_general(rows, d, sizes, _ROWS_CONTRACTED)
+        hidden = as_operand(hidden)
+        out = dot(hidden, w_down)
+        d_a, d_u = map(as_operand, back(dot(d_out, down_t)))
+        dx = dot(d_a, gate_t) + dot(d_u, up_t)
+        dw = [lax.ragged_dot_general(rows, d, sizes, _ROWS_CONTRACTED,
+                                     preferred_element_type=gate.dtype)
               for rows, d in ((x, d_a), (x, d_u), (hidden, d_out))]
     return jnp.where(held, dx, 0), dw, jnp.sum(out * dy, axis=-1)
 
@@ -214,42 +234,49 @@ def _sweep(step, carry, rows, chunk: int):
                           (jnp.int32(0), carry))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk, product):
     """``y [N, D]``: the first ``rows`` sorted assignments a chunk at a time
     (``_sweep``), each chunk through ``_chunk_out`` and added onto its tokens.
     The loop's length is not static, so the backward pass is written as the
     same sweep over ``_chunk_back``: it recomputes the chunk and keeps
-    nothing."""
+    nothing. The grouped products read ``b`` and the weights in the type
+    ``product``, to which each is rounded here, once a sweep and before the
+    loop (a cast before this function would be transposed into a rounding of
+    its cotangent)."""
+    rounded = b.astype(product)
+    weights = [w.astype(product) for w in (w_gate, w_up, w_down)]
+
     def step(start, y):
         cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
         tok = cut(token)
-        return y.at[tok].add(_chunk_out(b[tok], w_gate, w_up, w_down, cut(gate), cut(expert)))
+        return y.at[tok].add(_chunk_out(rounded[tok], *weights, cut(gate), cut(expert)))
 
     return _sweep(step, jnp.zeros_like(b), rows, chunk)
 
 
-def _mix_sorted_fwd(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk):
-    return (_mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk),
+def _mix_sorted_fwd(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk, product):
+    return (_mix_sorted(b, w_gate, w_up, w_down, gate, token, expert, rows, chunk, product),
             (b, w_gate, w_up, w_down, gate, token, expert, rows))
 
 
-def _mix_sorted_bwd(chunk, kept, dy):
+def _mix_sorted_bwd(chunk, product, kept, dy):
     b, w_gate, w_up, w_down, gate, token, expert, rows = kept
-    weights = (w_gate, w_up, w_down)
+    rounded = b.astype(product)
+    weights = tuple(w.astype(product) for w in (w_gate, w_up, w_down))
     transposed = tuple(jnp.swapaxes(w, 1, 2) for w in weights)  # once, not a trip
 
     def step(start, carry):
         db, dw, dgate = carry
         cut = lambda a: lax.dynamic_slice_in_dim(a, start, chunk)  # noqa: E731
         tok = cut(token)
-        dx, dw_chunk, dg = _chunk_back(b[tok], weights, transposed, cut(gate), cut(expert),
-                                       dy[tok])
+        dx, dw_chunk, dg = _chunk_back(rounded[tok], weights, transposed, cut(gate),
+                                       cut(expert), dy[tok])
         return (db.at[tok].add(dx), [a + c for a, c in zip(dw, dw_chunk)],
                 lax.dynamic_update_slice_in_dim(dgate, dg, start, 0))
 
     zeros = jnp.zeros_like
-    db, dw, dgate = _sweep(step, (zeros(b), [zeros(w) for w in weights], zeros(gate)),
+    db, dw, dgate = _sweep(step, (zeros(b), [zeros(w) for w in kept[1:4]], zeros(gate)),
                            rows, chunk)
     return (db, *dw, dgate, None, None, None)
 
@@ -258,13 +285,15 @@ _mix_sorted.defvjp(_mix_sorted_fwd, _mix_sorted_bwd)
 
 
 def held_mix(b, top_e, gates, w_gate, w_up, w_down, first: int, chunk: int,
-             provisioned: int = 0):
+             provisioned: int = 0, product=None):
     """The held experts' part of the mix for tokens ``b [N, D]``: weights
     ``[count, D, F]``, ``[count, D, F]``, ``[count, F, D]`` of experts
     ``first .. first + count - 1``, ``chunk`` sorted assignments a trip of
     the loop (``balanced_chunk_rows`` has the size a layer takes), which
     sweeps ``provisioned`` rows (to the end of their chunk) at the least.
-    Returns ``(y [N, D], held assignments)``."""
+    ``product`` is the type of the grouped products' operands, ``b``'s where
+    None; their results, ``y`` and every gradient are of ``b``'s type
+    whatever it is. Returns ``(y [N, D], held assignments)``."""
     N, k = top_e.shape
     count = w_gate.shape[0]
     local = top_e.reshape(-1) - first
@@ -278,7 +307,8 @@ def held_mix(b, top_e, gates, w_gate, w_up, w_down, first: int, chunk: int,
         jnp.pad(gates.reshape(-1)[order].astype(b.dtype), (0, pad)),
         jnp.pad(order // k, (0, pad)),
         jnp.pad(expert[order], (0, pad), constant_values=count),
-        jnp.maximum(n_held, min(provisioned, N * k)), chunk)
+        jnp.maximum(n_held, min(provisioned, N * k)), chunk,
+        jnp.dtype(b.dtype if product is None else product))
     return y, n_held
 
 
@@ -295,6 +325,10 @@ class ExpertLayer(nn.Module):
     held: Tuple[int, int]
     capacity_factor: float = 0.0  # balanced shares every step sweeps; 0: as long as the data
     dtype: Any = jnp.float32
+    # the type of the held experts' grouped products' operands, ``dtype`` where
+    # None: train.supcon.build sets bfloat16 beside a float32 ``dtype`` on a
+    # TPU, where default precision rounds the same operands inside every call
+    product_dtype: Any = None
     router: str = "softmax"  # or "sigmoid": ``route``'s rule
     gate_scale: float = 1.0
     bias_rate: float = 0.0  # the step of ``route_bias`` in train mode
@@ -333,7 +367,7 @@ class ExpertLayer(nn.Module):
             *(w[name].astype(self.dtype) for name in ("w_gate", "w_up", "w_down")), first=first,
             chunk=balanced_chunk_rows(top_e.size, count, self.n_experts, provisioned, D,
                                       self.width, self.dtype),
-            provisioned=provisioned)
+            provisioned=provisioned, product=self.product_dtype)
         if self.shared_width:
             with jax.named_scope(SCOPE_SHARED):
                 y = y + gated_mlp(b.astype(self.dtype), *(

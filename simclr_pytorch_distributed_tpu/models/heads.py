@@ -128,6 +128,9 @@ class SupConResNet(nn.Module):
     # a token encoder's attention through ops/sparse_attention.py's kernel
     # pair: set by train.supcon.build likewise (models/token_encoder.py)
     attn_kernel: bool = False
+    # the operands of a token encoder's grouped expert products, ``dtype``
+    # where None: set by train.supcon.build likewise (models/experts.py)
+    expert_product_dtype: Any = None
 
     @nn.nowrap
     def build_encoder(self) -> nn.Module:
@@ -140,6 +143,7 @@ class SupConResNet(nn.Module):
             bn_group_views=self.bn_group_views,
             remat=self.remat,
             pointwise_bwd=self.pointwise_bwd, attn_kernel=self.attn_kernel,
+            expert_product_dtype=self.expert_product_dtype,
         )
 
     def setup(self):

@@ -165,6 +165,16 @@ def latent_attrs(s: TokenEncoderSpec, dtype) -> dict:
         dtype=dtype)
 
 
+def expert_attrs(s: TokenEncoderSpec, dtype, product_dtype=None) -> dict:
+    """The attributes of a block's ``ExpertLayer``; ``product_dtype`` is the
+    type of its grouped products' operands (``dtype`` where None)."""
+    return dict(
+        n_experts=s.n_experts, top_k=s.top_k, width=s.expert_width, held=s.held,
+        capacity_factor=s.capacity_factor, dtype=dtype, product_dtype=product_dtype,
+        router=s.router, gate_scale=s.gate_scale, bias_rate=s.bias_rate,
+        sequence_balance=s.sequence_balance, shared_width=s.shared_width, rms_eps=s.rms_eps)
+
+
 class Block(nn.Module):
     """Layer ``index`` of the preset: its attention, then its dense layer or
     its experts. Returns ``(h, the indexer's KL or None, the expert layer's
@@ -175,6 +185,7 @@ class Block(nn.Module):
     remat: bool = False
     attn_kernel: bool = False
     index: int = 0
+    expert_product_dtype: Any = None
 
     @nn.compact
     def __call__(self, h: jax.Array, train: bool):
@@ -191,11 +202,7 @@ class Block(nn.Module):
         if self.index < s.dense_layers:  # likewise
             return DenseLayer(s.dense_width, s.rms_eps, self.dtype, name="mlp")(h), kl, None
         h, routed = wrap(ExpertLayer, static_argnums=(2,))(
-            n_experts=s.n_experts, top_k=s.top_k, width=s.expert_width, held=s.held,
-            capacity_factor=s.capacity_factor, dtype=self.dtype, router=s.router,
-            gate_scale=s.gate_scale, bias_rate=s.bias_rate,
-            sequence_balance=s.sequence_balance, shared_width=s.shared_width,
-            rms_eps=s.rms_eps, name="moe")(h, train)
+            **expert_attrs(s, self.dtype, self.expert_product_dtype), name="moe")(h, train)
         for name in ("prob", "load"):  # the forward pass's order
             mean = self.variable("batch_stats", f"{name}_mean", jnp.zeros,
                                  (s.n_experts,), jnp.float32)
@@ -216,6 +223,9 @@ class TokenEncoder(nn.Module):
     # train.supcon.build on a one-device TPU mesh; each layer's dtype and
     # shape can still say no (SparseAttention.kernel_reason)
     attn_kernel: bool = False
+    # the type of the expert layers' grouped products' operands, ``dtype``
+    # where None: set by train.supcon.build likewise (ExpertLayer.product_dtype)
+    expert_product_dtype: Any = None
 
     @property
     def aux_metric_keys(self) -> Tuple[str, ...]:
@@ -242,7 +252,7 @@ class TokenEncoder(nn.Module):
         aux_loss, sums = jnp.zeros((), jnp.float32), dict.fromkeys(s.ring_columns, 0.0)
         for k in range(s.layers):
             h, kl, routed = Block(s, self.dtype, self.remat, self.attn_kernel, k,
-                                  name=f"block{k}")(h, train)
+                                  self.expert_product_dtype, name=f"block{k}")(h, train)
             # the loss's terms before the columns' sums, balance before KL: the
             # order of the first block's program, which must not move
             if routed is not None:

@@ -272,12 +272,29 @@ def plan_latent_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
     return plan
 
 
-def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
+def expert_product_operands(dtype) -> tuple:
+    """``(type, reason)`` of the operands that the expert layers' grouped
+    products read: bfloat16 with no reason where the layers are float32 and
+    the program a TPU's, which is where default precision rounds the same
+    operands to bfloat16 inside every call, after reading them at four bytes
+    an element; else ``dtype`` itself and why. No flag chooses, and the number
+    of devices does not matter: the call is XLA's own on every chip."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return dtype, "--bf16"
+    if jax.default_backend() != "tpu":
+        return dtype, f"non-TPU backend ({jax.default_backend()})"
+    return jnp.bfloat16, None
+
+
+def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet,
+                 product_reason: Optional[str] = None):
     """What the expert layers of ``model``'s encoder hold, said once in a
     banner line and one ``expert_plan`` event (track ``compile``), as
     ``plan_pointwise_bwd`` says its plan, with the dense layers before them,
-    the router's rule, the shared experts' width and the ring columns the
-    encoder sows (``scripts/trace_report.py`` reads their names from the
+    the router's rule, the shared experts' width, the type of the grouped
+    products' operands (``product_operands``, with ``product_reason`` where
+    ``expert_product_operands`` left them as they were) and the ring columns
+    the encoder sows (``scripts/trace_report.py`` reads their names from the
     event); None for an encoder without experts."""
     spec = getattr(model.build_encoder(), "spec", None)
     if spec is None:
@@ -295,6 +312,10 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
             "rows_per_trip": trip, "provisioned_trips": -(-provisioned // trip),
             "dense_layers": spec.dense_layers, "router": spec.router,
             "shared_width": spec.shared_width,
+            "product_operands": jnp.dtype(
+                model.dtype if model.expert_product_dtype is None
+                else model.expert_product_dtype).name,
+            "product_reason": product_reason,
             "ring_columns": list(model.aux_metric_keys)}
     logging.info(
         "[experts] %d layers hold experts %d-%d of %d, %d a token (%s-routed "
@@ -302,12 +323,13 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet):
         "them; %d token rows a step, %.1f%% of their assignments land here "
         "when the load is balanced; a layer sweeps %d assignments a step (%.4g "
         "balanced shares, in trips of %d rows: %d) whatever the routing, and "
-        "more where more land here",
+        "more where more land here; the grouped products read %s operands%s",
         plan["layers"], first, first + count - 1,
         spec.n_experts, spec.top_k, spec.router, spec.n_experts, spec.dense_layers,
         spec.shared_width, rows,
         100.0 * count / spec.n_experts, provisioned, spec.capacity_factor,
-        trip, plan["provisioned_trips"],
+        trip, plan["provisioned_trips"], plan["product_operands"],
+        f" ({product_reason})" if product_reason else "",
     )
     tracing.event("expert_plan", track=tracing.COMPILE_TRACK, **plan)
     return plan
@@ -327,14 +349,15 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
     )
     tail_plan = plan_pointwise_bwd(cfg, n_devices, **encoder_kwargs)
     attention_plan = plan_sparse_attention(cfg, n_devices, **encoder_kwargs)
+    product_dtype, product_reason = expert_product_operands(dtype)
     model = SupConResNet(
         model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
         pointwise_bwd=any(site["reason"] is None for site in tail_plan),
         attn_kernel=any(layer["reason"] is None for layer in attention_plan),
-        **encoder_kwargs,
+        expert_product_dtype=product_dtype, **encoder_kwargs,
     )
     plan_latent_attention(cfg, model)
-    plan_experts(cfg, model)
+    plan_experts(cfg, model, product_reason)
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the
     # effective-LR consequence (config.ngpu_mismatch_banner)

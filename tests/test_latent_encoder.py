@@ -493,6 +493,8 @@ def test_build_says_what_the_layers_are(one_step):
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
                                 "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
                                 "dense_layers": 1, "router": "sigmoid", "shared_width": 24,
+                                "product_operands": "float32",
+                                "product_reason": "non-TPU backend (cpu)",
                                 "ring_columns": list(TOKEN_ENCODERS[TINY].ring_columns)}
     latent = [r for r in one_step["events"] if r["name"] == "latent_attention_plan"]
     assert len(latent) == 1 and latent[0]["track"] == "compile"
@@ -502,6 +504,38 @@ def test_build_says_what_the_layers_are(one_step):
         "tokens": 16, "path": "xla"}
     assert "value heads of 8" in latent[0]["args"]["reason"]
     assert not [r for r in one_step["events"] if r["name"] == "sparse_attention_plan"]
+
+
+@pytest.mark.parametrize("backend,flags,operands,reason", [
+    ("cpu", [], "float32", "non-TPU backend (cpu)"),
+    ("tpu", [], "bfloat16", None),
+    ("tpu", ["--bf16"], "bfloat16", "--bf16"),
+    ("cpu", ["--bf16"], "bfloat16", "--bf16")])
+@pytest.mark.parametrize("name", [TINY, "keye-vl2-tiny"])
+def test_build_gives_the_grouped_products_what_default_precision_would_round_to(
+        monkeypatch, tmp_path, name, backend, flags, operands, reason):
+    """bfloat16 operands exactly where the layers are float32 and the program
+    is a TPU's (there default precision rounds the same operands inside every
+    call); off the TPU a float32 product is exact and stays, and under
+    ``--bf16`` the operands are bfloat16 already. The model, the banner and
+    the ``expert_plan`` event say the same."""
+    from simclr_pytorch_distributed_tpu.train import supcon
+    from simclr_pytorch_distributed_tpu.utils import tracing
+
+    monkeypatch.setattr(supcon.jax, "default_backend", lambda: backend)
+    cfg = config_lib.parse_supcon([
+        "--dataset", "synthetic", "--workdir", str(tmp_path), "--batch_size", "4", "--size", "16",
+        "--model", name, "--loss_impl", "dense", "--health_freq", "0", "--ngpu", "auto", *flags])
+    recorder, built = tracing.FlightRecorder(), []
+    tracing.install(recorder)
+    try:  # traced, nothing run; four devices: their number does not stand it down
+        jax.eval_shape(lambda: built.extend(supcon.build(cfg, 5, 4)))
+    finally:
+        tracing.uninstall()
+    (plan,) = [r["args"] for r in recorder.snapshot() if r["name"] == "expert_plan"]
+    assert (plan["product_operands"], plan["product_reason"]) == (operands, reason)
+    assert jnp.dtype(built[0].build_encoder().expert_product_dtype).name == operands
+    assert plan["rows_per_trip"] == 8 * 16 * 2 and plan["provisioned_trips"] == 1
 
 
 def test_step_writes_the_presets_columns_and_moves_the_statistics(one_step):
@@ -535,7 +569,8 @@ def test_trace_report_prints_both_plans():
     plan = {"layers": 4, "held": 8, "first": 0, "n_experts": 64, "per_token": 6,
             "rows_per_step": 32768, "capacity_factor": 2.0, "provisioned_assignments": 49152,
             "rows_per_trip": 24576, "provisioned_trips": 2, "dense_layers": 1,
-            "router": "sigmoid", "shared_width": 2816,
+            "router": "sigmoid", "shared_width": 2816, "product_operands": "float32",
+            "product_reason": "non-TPU backend (cpu)",
             "ring_columns": ["moe_held_share", "route_bias_max_abs"]}
     latent = {"layers": 5, "heads": 16, "nope_dim": 128, "rope_dim": 64, "v_dim": 128,
               "kv_rank": 512, "tokens": 4096, "path": "xla", "reason": "no kernel"}
@@ -551,6 +586,8 @@ def test_trace_report_prints_both_plans():
     table = trace_report.render_table(report)
     assert ("experts: 4 layers hold 8 of 64, 6 a token (sigmoid-routed, after 1 dense layers, "
             "shared experts of width 2816)") in table
+    assert ("in 2 trips of 24576 rows, grouped products on float32 operands (non-TPU backend "
+            "(cpu)); moe_held_share") in table
     assert "route_bias_max_abs 0.012" in table
     assert ("latent attention: 5 layers of 16 heads (128 + 64 shared rotary / 128), latent of "
             "512, 4096 tokens a row, on xla's path: no kernel") in table

@@ -238,6 +238,86 @@ def test_no_token_is_dropped_whatever_the_chunk(uncut_layer, chunk, provisioned,
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
+def _as_bfloat16(a):
+    """``a`` rounded to bfloat16, in float32."""
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def default_precision_dot(a, w):
+    """What a TPU's matrix product computes at default precision, written out:
+    both operands rounded to bfloat16, their products summed in float32; in
+    the backward pass two such products of the cotangent, which is rounded as
+    an operand of each and nowhere else."""
+    return jnp.dot(_as_bfloat16(a), _as_bfloat16(w), precision="highest")
+
+
+def _default_precision_dot_bwd(kept, d):
+    a, w = map(_as_bfloat16, kept)
+    d = _as_bfloat16(d)
+    return jnp.dot(d, w.T, precision="highest"), jnp.dot(a.T, d, precision="highest")
+
+
+default_precision_dot.defvjp(lambda a, w: (default_precision_dot(a, w), (a, w)),
+                             _default_precision_dot_bwd)
+
+
+def plain_mix(b, top_e, gates, w_gate, w_up, w_down, first, chunk=None, provisioned=0,
+              product=None):
+    """``experts.held_mix``'s result by its definition: every held expert over
+    every token through ``default_precision_dot``, weighed by the token's gate
+    for that expert (zero where it did not choose it); no sort, no loop over
+    rows, no grouped product."""
+    y = jnp.zeros_like(b)
+    for e in range(w_gate.shape[0]):
+        chosen = top_e == first + e
+        hidden = (jax.nn.silu(default_precision_dot(b, w_gate[e]))
+                  * default_precision_dot(b, w_up[e]))
+        y = y + default_precision_dot(hidden, w_down[e]) * jnp.sum(
+            jnp.where(chosen, gates, 0.0), axis=-1, keepdims=True)
+    return y, jnp.sum((top_e >= first) & (top_e < first + w_gate.shape[0]), dtype=jnp.int32)
+
+
+@pytest.mark.parametrize("shared_width", [0, 12])
+@pytest.mark.parametrize("rule", ["softmax", "sigmoid"])
+def test_bfloat16_products_round_each_operand_once_and_no_cotangent(monkeypatch, rule,
+                                                                    shared_width):
+    """The layer whose grouped products read bfloat16 operands (what
+    ``train.supcon.build`` gives a float32 layer on a TPU) is the float32
+    layer with every operand of those products rounded where it is made: its
+    result and every gradient are ``plain_mix``'s to the order of the sums.
+    No cotangent is rounded on its way: every gradient is float32 and holds
+    what bfloat16 cannot. The chunk is 16 rows: two trips over the held
+    assignments."""
+    attrs = dict(n_experts=8, top_k=2, width=16, held=(2, 4), router=rule, gate_scale=2.446,
+                 shared_width=shared_width)
+    h = jax.random.normal(jax.random.key(3), (2, 24, 32))
+    weigh = jax.random.normal(jax.random.key(4), h.shape)
+    variables = experts.ExpertLayer(**attrs).init(jax.random.key(5), h)
+    params = dict(variables["params"])
+    params.update(router=8 * params["router"], **{
+        n: 12 * params[n] for n in params if n.startswith(("w_", "shared_"))})
+    rest = {k: v for k, v in variables.items() if k != "params"}
+    monkeypatch.setattr(experts, "balanced_chunk_rows", lambda *a: 16)
+
+    def value_and_grads(layer):
+        def loss(params, h):
+            return jnp.sum(layer.apply({**rest, "params": params}, h)[0] * weigh)
+        return jax.value_and_grad(loss, argnums=(0, 1))(params, h)
+
+    got = value_and_grads(experts.ExpertLayer(product_dtype=jnp.bfloat16, **attrs))
+    exact = value_and_grads(experts.ExpertLayer(**attrs))
+    monkeypatch.setattr(experts, "held_mix", plain_mix)
+    want = value_and_grads(experts.ExpertLayer(**attrs))
+    paths = [jax.tree_util.keystr(path) for path, _ in jax.tree_util.tree_leaves_with_path(want)]
+    for path, a, b, c in zip(paths, *map(jax.tree.leaves, (got, want, exact))):
+        assert a.dtype == jnp.float32, path
+        assert rel(a, b) < 2e-6, (path, rel(a, b))
+        if a.ndim and "shared" not in path:  # the rounding is there to be seen
+            assert rel(a, c) > 1e-4, (path, rel(a, c))
+            assert float(jnp.mean(a != _as_bfloat16(a))) > 0.9, path
+
+
 def test_a_chunk_is_as_many_rows_as_the_trip_budget_holds():
     """``experts.TRIP_BYTES`` beside the nine weight-sized tensors of a trip,
     in whole tiles, evened out over the rows the layer sweeps."""
@@ -479,6 +559,8 @@ def test_build_says_what_the_expert_layers_hold(one_step):
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
                                 "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
                                 "dense_layers": 0, "router": "softmax", "shared_width": 0,
+                                "product_operands": "float32",
+                                "product_reason": "non-TPU backend (cpu)",
                                 "ring_columns": list(token_encoder.AUX_METRIC_KEYS)}
 
 
@@ -514,6 +596,7 @@ def test_trace_report_prints_the_expert_plan_and_the_ring_columns():
     plan = {"layers": 4, "held": 16, "first": 0, "n_experts": 128, "per_token": 8,
             "rows_per_step": 32768, "capacity_factor": 2.0, "provisioned_assignments": 65536,
             "rows_per_trip": 32768, "provisioned_trips": 2,
+            "product_operands": "bfloat16", "product_reason": None,
             "ring_columns": ["moe_held_share", "moe_load_max_over_mean"]}
     events = [span,
               {"name": "expert_plan", "track": "compile", "ph": "i", "ts": 0.1, "args": plan},
@@ -524,6 +607,6 @@ def test_trace_report_prints_the_expert_plan_and_the_ring_columns():
         "moe_held_share": 0.124, "moe_load_max_over_mean": 1.3}}
     table = trace_report.render_table(report)
     assert "experts: 4 layers hold 16 of 128, 8 a token" in table
-    assert ("65536 assignments a layer swept whatever the routing in 2 trips of 32768 rows"
-            in table)
+    assert ("65536 assignments a layer swept whatever the routing in 2 trips of 32768 rows, "
+            "grouped products on bfloat16 operands; moe_held_share") in table
     assert "encoder" not in trace_report.build_report([span])  # a ResNet's run

@@ -357,20 +357,29 @@ def test_sparse_attention_budget_is_the_compilers(one_chip):
     _compile(_ATTENTION_CALLS["fwd"], *_attention_kernel_shapes(one_chip, 5632)[0])
 
 
-def _top_level_arrays(text, within=None):
-    """``[(opcode, dtype, dims)]`` of every array in the result type of every
-    instruction outside fused computations and reducers: what goes through
-    HBM between the compiled program's kernels. ``within``: of the named
-    computations alone."""
-    fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
-    found, computation = [], None
+def _instructions(text):
+    """``(computation, line, its _HLO_LINE match)`` of every instruction of a
+    compiled program's text."""
+    computation = None
     for line in text.splitlines():
         header = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{\s*$", line)
         if header:
             computation = header.group(1)
             continue
         m = _HLO_LINE.match(line)
-        if not m or computation in fused or (within is not None and computation not in within):
+        if m:
+            yield computation, line, m
+
+
+def _top_level_arrays(text, within=None):
+    """``[(opcode, dtype, dims)]`` of every array in the result type of every
+    instruction outside fused computations and reducers: what goes through
+    HBM between the compiled program's kernels. ``within``: of the named
+    computations alone."""
+    fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    found = []
+    for computation, _, m in _instructions(text):
+        if computation in fused or (within is not None and computation not in within):
             continue
         for dtype, dims in re.findall(r"\b([a-z]+\d+|pred)\[([\d,]+)\]", m.group(2)):
             found.append((m.group(3), dtype, tuple(int(x) for x in dims.split(","))))
@@ -449,67 +458,132 @@ def test_no_layout_copy_around_the_attention_kernels(attention_layer_texts):
 # does not depend on its rows, and what the trip's size costs in memory
 
 
-@pytest.fixture(scope="module")
-def expert_layer_compiled(one_chip):
-    """The compiled gradient of one ``ExpertLayer`` at the cell's widths over
-    a step's 8 rows of 4,096 tokens."""
-    spec = token_encoder.TOKEN_ENCODERS["keye-vl2-a3b-ep8"]
-    layer = experts.ExpertLayer(
-        n_experts=spec.n_experts, top_k=spec.top_k, width=spec.expert_width, held=spec.held,
-        capacity_factor=spec.capacity_factor)
-    params = jax.tree.map(
+def _compile_expert_layer(one_chip, name, product_dtype):
+    """The compiled gradient of one ``ExpertLayer`` at ``name``'s widths over
+    a step's 8 rows of 4,096 tokens, its grouped products on operands of
+    ``product_dtype`` (None: the layer's own float32)."""
+    spec = token_encoder.TOKEN_ENCODERS[name]
+    layer = experts.ExpertLayer(**token_encoder.expert_attrs(spec, jnp.float32, product_dtype))
+    variables = jax.tree.map(
         lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: layer.init(
-            jax.random.key(0), jnp.zeros((2, 16, spec.hidden))))["params"])
+        jax.eval_shape(lambda: layer.init(jax.random.key(0), jnp.zeros((2, 16, spec.hidden)))))
+    params = variables.pop("params")
 
-    def loss(params, h):
-        out, stats = layer.apply({"params": params}, h)
+    def loss(params, h, rest):
+        out, stats = layer.apply({**rest, "params": params}, h)
         return jnp.sum(jnp.square(out)) + stats["balance"]
 
     return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, jax.ShapeDtypeStruct((8, 4096, spec.hidden), jnp.float32,
-                                     sharding=one_chip)).compile()
+        params, jax.ShapeDtypeStruct((8, 4096, spec.hidden), jnp.float32, sharding=one_chip),
+        variables).compile()
+
+
+@pytest.fixture(scope="module", params=[
+    ("keye-vl2-a3b-ep8", "f32"), ("keye-vl2-a3b-ep8", "bf16"), ("moonlight-16b-a3b-ep8", "bf16")],
+    ids="-".join)
+def expert_layer_compiled(one_chip, request):
+    """``(preset, the grouped products' operands by HLO's name, the compiled
+    gradient)``: Keye's layer (16 x 2,048 x 768, softmax-routed) on float32
+    operands, as off the TPU, and on bfloat16, as ``train.supcon.build`` sets
+    them on one; Moonlight's (8 x 2,048 x 1,408, sigmoid-routed under its
+    bias, shared experts beside) on bfloat16."""
+    name, operands = request.param
+    return (token_encoder.TOKEN_ENCODERS[name], operands, _compile_expert_layer(
+        one_chip, name, {"f32": None, "bf16": jnp.bfloat16}[operands]))
+
+
+def _loop_bodies(text):
+    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
+    assert len(bodies) == 2  # the forward sweep and the backward one
+    return bodies
 
 
 def test_no_weight_is_laid_out_again_inside_the_expert_loops(expert_layer_compiled):
     """The backward sweep's input-gradient products take the weights with
-    their last axes swapped; the swap is made once before the loop. With
-    ``jax.vjp`` of a chunk there were three ``copy`` instructions of a
-    ``[16, 2048, 768]`` float32 tensor in the backward loop's body, 0.6 GB a
-    trip (ISSUE 31). What is left there of that size and moves bytes in the
-    trip's own time: the three weight gradients out of their grouped products
-    and their three adds onto the carry. (The forward body's ``copy-start``
-    and ``slice-start`` of a weight are the compiler's prefetches into its
-    nearer memory, same layout, asynchronous: PERF.md section 6, PR 31.)"""
-    text = expert_layer_compiled.as_text()
-    bodies = set(re.findall(r"body=%([\w.\-]+)", text))
-    assert len(bodies) == 2  # the forward sweep and the backward one
-    weight = 16 * 2048 * 768
-    inside = [(opcode, dims) for opcode, dtype, dims in _top_level_arrays(text, within=bodies)
-              if dtype == "f32" and math.prod(dims) >= weight and dims[0] == 16]
-    assert [found for found in inside if found[0] in ("copy", "transpose", "fusion")] == [], inside
-    assert [opcode for opcode, _ in inside].count("add") == 3, inside
+    their last axes swapped; the swap is made once before the loop, and so is
+    the weights' rounding to the products' type (on its bfloat16 copy the swap
+    moves half the bytes). With ``jax.vjp`` of a chunk there were three
+    ``copy`` instructions of a ``[16, 2048, 768]`` float32 tensor in the
+    backward loop's body, 0.6 GB a trip (ISSUE 31). What is left there of
+    that size, in either type, and moves bytes in the trip's own time: the
+    three float32 weight gradients out of their grouped products and their
+    three adds onto the carry. (A body's ``copy-start`` and ``slice-start`` of
+    a weight are the compiler's prefetches into its nearer memory, same
+    layout, asynchronous: PERF.md section 6, PR 31.)"""
+    spec, _, compiled = expert_layer_compiled
+    text = compiled.as_text()
+    count = spec.held[1]
+    inside = [(opcode, dtype) for opcode, dtype, dims in _top_level_arrays(
+                  text, within=_loop_bodies(text))
+              if dtype in ("f32", "bf16") and dims[0] == count
+              and math.prod(dims) >= count * spec.hidden * spec.expert_width]
+    moved = [found for found in inside
+             if found[0] in ("copy", "transpose", "convert", "fusion")]
+    assert moved == [], moved
+    assert [dtype for opcode, dtype in inside if opcode == "add"] == ["f32"] * 3, inside
+
+
+def test_grouped_products_read_the_operands_they_were_given(expert_layer_compiled):
+    """The twelve ``ragged-dot`` Mosaic calls of the two loop bodies (three
+    forward; nine backward: the chunk recomputed, three input gradients,
+    three weight gradients) read rows and weights of the products' type, two
+    bytes an element where ``build`` gives bfloat16, and give float32. The
+    rows' rounding is the last instruction of the elementwise fusion that
+    makes them: no fusion of a body only converts a ``[rows, 2048]`` or
+    ``[rows, width]`` tensor."""
+    spec, operands, compiled = expert_layer_compiled
+    text = compiled.as_text()
+    bodies = _loop_bodies(text)
+    rows = {768: 32768, 1408: 24576}[spec.expert_width]  # a trip's, as the test below holds
+    row_sized = rf"\b(bf16|f32)\[{rows},(?:{spec.hidden}|{spec.expert_width})\]"
+    opcodes, calls, made = {}, [], set()
+    for computation, _, m in _instructions(text):
+        opcodes.setdefault(computation, set()).add(m.group(3))
+    for computation, line, m in _instructions(text):
+        if computation not in bodies:
+            continue
+        if m.group(1).startswith("ragged-dot-none"):
+            constraints = re.search(r"operand_layout_constraints=\{(.*?)\}\}", line).group(1)
+            calls.append((re.match(r"\(?(\w+)\[", m.group(2)).group(1),
+                          tuple(re.findall(r"\b(f32|bf16)\[", constraints))))
+        elif m.group(3) in ("fusion", "convert") and re.search(row_sized, m.group(2)):
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            assert called and opcodes[called.group(1)] - {
+                "parameter", "convert", "bitcast", "tuple"}, line[:200]
+            made.update(re.findall(row_sized, m.group(2)))
+    assert len(calls) == 12 and set(calls) == {("f32", (operands, operands))}, calls
+    assert made == {"f32", operands}, made  # the rounded rows come out of such fusions
 
 
 def test_a_trip_of_the_expert_sweep_holds_what_its_budget_says(expert_layer_compiled):
-    """At the cell's shapes ``experts.TRIP_BYTES`` gives 32,768 rows a trip,
-    and the rule counts 2.01 GB for such a trip: nine weight-sized tensors,
-    0.91 GB (three of them the gradients' sums, which are the layer's
-    results and no temporaries), and 1.11 GB of rows. The chip's compiler
-    holds the whole layer's gradient in 2.544 GB of temporaries there: the
-    trip's 1.71 GB and, beside it, the layer's own input, result and
-    cotangents (1.722 GB at the 8,192 rows of before, 3.651 GB with the
-    provision in one trip; PR 31)."""
-    spec = token_encoder.TOKEN_ENCODERS["keye-vl2-a3b-ep8"]
-    rows = experts.balanced_chunk_rows(
-        8 * 4096 * spec.top_k, spec.held[1], spec.n_experts, 65536, spec.hidden,
-        spec.expert_width, jnp.float32)
-    assert rows == 32768
+    """At the Keye cell's shapes ``experts.TRIP_BYTES`` gives 32,768 rows a
+    trip (24,576 at the Moonlight cell's), asked with the layer's float32
+    whatever the products read, and the rule counts 2.01 GB for such a trip:
+    nine weight-sized tensors, 0.91 GB (three of them the gradients' sums,
+    which are the layer's results and no temporaries), and 1.11 GB of rows.
+    The chip's compiler holds the whole layer's gradient in 2.544 GB of
+    temporaries there: the trip's 1.71 GB and, beside it, the layer's own
+    input, result and cotangents (1.722 GB at the 8,192 rows of before, 3.651
+    GB with the provision in one trip; PR 31). On bfloat16 operands it is
+    2.578 GB: the rows are smaller, and the weights' three bfloat16 copies
+    are held from the forward sweep to the backward one (the whole step,
+    where a layer's backward recomputes its forward, holds 8.66 GB of
+    temporaries for 8.91; PERF.md section 6, PR 33). Moonlight's layer, the
+    shared experts' gradient among it: 3.000 GB on bfloat16 operands for
+    3.252 on float32."""
+    spec, operands, compiled = expert_layer_compiled
+    assignments = 8 * 4096 * spec.top_k
+    provisioned = experts.provisioned_rows(assignments, spec.held[1], spec.n_experts,
+                                           spec.capacity_factor)
+    rows = experts.balanced_chunk_rows(assignments, spec.held[1], spec.n_experts, provisioned,
+                                       spec.hidden, spec.expert_width, jnp.float32)
+    assert (rows, provisioned) == {768: (32768, 65536), 1408: (24576, 49152)}[spec.expert_width]
     weight = spec.held[1] * spec.hidden * spec.expert_width * 4
     trip = 6 * weight + 3 * (spec.hidden + spec.expert_width) * 4 * rows
     assert trip + 3 * weight <= experts.TRIP_BYTES
-    temp = expert_layer_compiled.memory_analysis().temp_size_in_bytes
-    assert trip < temp < 1.1 * 2.544e9, temp
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    most = {768: 2.544e9, 1408: 3.000e9}[spec.expert_width]
+    assert trip * (1.0 if operands == "f32" else 0.7) < temp < 1.02 * most, temp
 
 
 # ---- the whole step of the cell moonlight-16b-a3b-ep8.pretrain-1024px-b4
