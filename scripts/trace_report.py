@@ -208,8 +208,9 @@ def encoder_section(events):
     ``train.supcon.plan_experts`` said at build (the ``expert_plan`` event on
     track ``compile``) and the newest ``health_window`` means of the
     encoder's own ring columns, which the event names (``ring_columns``),
-    with ``plan_sparse_attention``'s ``sparse_attention_plan`` event or
-    ``plan_latent_attention``'s ``latent_attention_plan`` event; nothing for
+    with ``plan_sparse_attention``'s ``sparse_attention_plan`` event,
+    ``plan_latent_attention``'s ``latent_attention_plan`` event or
+    ``plan_linear_attention``'s ``linear_attention_plan`` event; nothing for
     a ResNet's run."""
     plan = next((e["args"] for e in events if e["name"] == "expert_plan"), None)
     if plan is None:
@@ -222,8 +223,8 @@ def encoder_section(events):
     section = {"expert_plan": plan, "ring": last}
     section.update({"attention_plan": e["args"] for e in events
                     if e["name"] == "sparse_attention_plan"})
-    section.update({"latent_attention_plan": e["args"] for e in events
-                    if e["name"] == "latent_attention_plan"})
+    section.update({name: e["args"] for e in events for name in (
+        "latent_attention_plan", "linear_attention_plan") if e["name"] == name})
     return {"encoder": section}
 
 
@@ -264,7 +265,9 @@ def render_table(report):
             f"experts: {plan['layers']} layers hold {plan['held']} of "
             f"{plan['n_experts']}, {plan['per_token']} a token"
             + (f" ({plan['router']}-routed, after {plan['dense_layers']} dense layers, "
-               f"shared experts of width {plan['shared_width']})" if "router" in plan else "")
+               f"shared experts of width {plan['shared_width']}"
+               + (" under a sigmoid gate" if plan.get("shared_gate") else "") + ")"
+               if "router" in plan else "")
             + f", {plan['rows_per_step']} token rows a step, "
             f"{plan.get('provisioned_assignments', 0)} assignments a layer swept whatever "
             f"the routing in {plan.get('provisioned_trips', '?')} trips of "
@@ -288,6 +291,17 @@ def render_table(report):
                 f"({latent['nope_dim']} + {latent['rope_dim']} shared rotary / "
                 f"{latent['v_dim']}), latent of {latent['kv_rank']}, {latent['tokens']} "
                 f"tokens a row, on {latent['path']}'s path: {latent['reason']}")
+        linear = report["encoder"].get("linear_attention_plan")
+        if linear:
+            kinds = linear["layers"]
+            lines.append(
+                f"linear attention: {kinds.get('linear', 0)} Gated DeltaNet layers of "
+                f"{linear['key_heads']} key / {linear['value_heads']} value heads of "
+                f"{linear['key_dim']} / {linear['value_dim']} beside "
+                f"{sum(kinds.values()) - kinds.get('linear', 0)} full, "
+                f"{linear['conv_width']}-tap convolution, scan in chunks of {linear['chunk']} "
+                f"of {linear['tokens']} tokens, {linear['row_group']} rows a group, on "
+                f"{linear['path']}'s path: {linear['reason']}")
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

@@ -5,9 +5,11 @@ Expert parallelism's layer as one chip runs it: the router scores every
 token over all ``n_experts`` and chooses ``top_k`` of them by one of two
 rules (``route``), and this layer computes the part of the result that its
 own experts give, ``held = (first, count)``, and what every chip computes
-alike for its own rows, the shared experts (``shared_width``, none at 0):
+alike for its own rows, the shared experts (``shared_width``, none at 0),
+scaled where ``shared_expert_gate`` by ``sigmoid(b_t w_s)`` with ``w_s [D, 1]``
+(else by 1):
 
-    y_t = sum over e in (top_k of t) and in held of gate[t, e] * f_e(b_t) + f_sh(b_t)
+    y_t = sum over e in (top_k of t) and in held of gate[t, e] * f_e(b_t) + s_t f_sh(b_t)
     f_e(x) = (silu(x W_gate[e]) * (x W_up[e])) W_down[e]
 
 The rules: ``softmax`` takes the ``top_k`` largest probabilities and
@@ -334,6 +336,8 @@ class ExpertLayer(nn.Module):
     bias_rate: float = 0.0  # the step of ``route_bias`` in train mode
     sequence_balance: bool = False  # the balance term row by row
     shared_width: int = 0
+    # the shared experts' output scaled by sigmoid(b @ shared_expert_gate)
+    shared_expert_gate: bool = False
     rms_eps: float = RMS_EPS
 
     @nn.compact
@@ -349,6 +353,8 @@ class ExpertLayer(nn.Module):
             w.update({name: self.param(name, normal_init, shape) for name, shape in (
                 ("shared_gate", (D, self.shared_width)), ("shared_up", (D, self.shared_width)),
                 ("shared_down", (self.shared_width, D)))})
+            if self.shared_expert_gate:
+                w["shared_expert_gate"] = self.param("shared_expert_gate", normal_init, (D, 1))
         w["norm"] = self.param("norm", nn.initializers.ones, (D,))
         w, h = tie_gradients((w, h))
         b = rms_norm(h, w["norm"], self.rms_eps).reshape(-1, D)
@@ -370,9 +376,13 @@ class ExpertLayer(nn.Module):
             provisioned=provisioned, product=self.product_dtype)
         if self.shared_width:
             with jax.named_scope(SCOPE_SHARED):
-                y = y + gated_mlp(b.astype(self.dtype), *(
+                shared = gated_mlp(b.astype(self.dtype), *(
                     w[name].astype(self.dtype)
                     for name in ("shared_gate", "shared_up", "shared_down")))
+                if self.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(
+                        b.astype(self.dtype) @ w["shared_expert_gate"].astype(self.dtype))
+                y = y + shared
         out = h + y.reshape(h.shape).astype(h.dtype)
         stats = {"load": load, "prob": prob,
                  "balance": (sequence_balance(probs, top_e, h.shape[0]) if self.sequence_balance

@@ -272,6 +272,41 @@ def plan_latent_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
     return plan
 
 
+def plan_linear_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
+    """What the Gated DeltaNet layers of ``model``'s encoder are, said once
+    in a banner line and one ``linear_attention_plan`` event (track
+    ``compile``): the layers by kind, the linear layers' heads and widths,
+    the convolution's taps, the scan's chunk, the full layers' heads, the
+    rows a group and the path with its reason. Today the path is always
+    XLA's: no Mosaic kernel computes the chunked delta rule. None for an
+    encoder without such layers."""
+    from simclr_pytorch_distributed_tpu.models.sparse_attention import ROW_GROUP
+
+    spec = getattr(model.build_encoder(), "spec", None)
+    if spec is None or not spec.full_attention_interval:
+        return None
+    kinds = [spec.attention_of(k) for k in range(spec.layers)]
+    tokens = (cfg.size // spec.patch) ** 2
+    plan = {"layers": {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)},
+            "key_heads": spec.linear_key_heads, "value_heads": spec.linear_value_heads,
+            "key_dim": spec.linear_key_dim, "value_dim": spec.linear_value_dim,
+            "conv_width": spec.conv_width,
+            "chunk": spec.delta_chunk if tokens % spec.delta_chunk == 0 else tokens,
+            "full_heads": spec.n_heads, "full_kv_heads": spec.n_kv_heads,
+            "full_head_dim": spec.head_dim, "tokens": tokens, "row_group": ROW_GROUP,
+            "path": "xla", "reason": "no Mosaic kernel for the chunked delta rule"}
+    logging.info(
+        "[linear_attention] %d Gated DeltaNet layers of %d key / %d value heads of %d / %d, "
+        "%d-tap convolution, scan in chunks of %d tokens, beside %d %s layers of %d / %d "
+        "heads of %d; %d causal tokens a row, %d rows a group; on XLA's path: %s",
+        plan["layers"]["linear"], plan["key_heads"], plan["value_heads"], plan["key_dim"],
+        plan["value_dim"], plan["conv_width"], plan["chunk"],
+        len(kinds) - plan["layers"]["linear"], spec.attention, plan["full_heads"],
+        plan["full_kv_heads"], plan["full_head_dim"], tokens, ROW_GROUP, plan["reason"])
+    tracing.event("linear_attention_plan", track=tracing.COMPILE_TRACK, **plan)
+    return plan
+
+
 def expert_product_operands(dtype) -> tuple:
     """``(type, reason)`` of the operands that the expert layers' grouped
     products read: bfloat16 with no reason where the layers are float32 and
@@ -293,7 +328,8 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet,
     ``plan_pointwise_bwd`` says its plan, with the dense layers before them,
     the router's rule, the shared experts' width, the type of the grouped
     products' operands (``product_operands``, with ``product_reason`` where
-    ``expert_product_operands`` left them as they were) and the ring columns
+    ``expert_product_operands`` left them as they were), whether a sigmoid
+    gates the shared experts (``shared_gate``) and the ring columns
     the encoder sows (``scripts/trace_report.py`` reads their names from the
     event); None for an encoder without experts."""
     spec = getattr(model.build_encoder(), "spec", None)
@@ -311,7 +347,7 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet,
             "provisioned_assignments": provisioned,
             "rows_per_trip": trip, "provisioned_trips": -(-provisioned // trip),
             "dense_layers": spec.dense_layers, "router": spec.router,
-            "shared_width": spec.shared_width,
+            "shared_width": spec.shared_width, "shared_gate": spec.shared_expert_gate,
             "product_operands": jnp.dtype(
                 model.dtype if model.expert_product_dtype is None
                 else model.expert_product_dtype).name,
@@ -319,14 +355,14 @@ def plan_experts(cfg: config_lib.SupConConfig, model: SupConResNet,
             "ring_columns": list(model.aux_metric_keys)}
     logging.info(
         "[experts] %d layers hold experts %d-%d of %d, %d a token (%s-routed "
-        "over all %d) after %d dense layers, shared experts of width %d beside "
+        "over all %d) after %d dense layers, shared experts of width %d%s beside "
         "them; %d token rows a step, %.1f%% of their assignments land here "
         "when the load is balanced; a layer sweeps %d assignments a step (%.4g "
         "balanced shares, in trips of %d rows: %d) whatever the routing, and "
         "more where more land here; the grouped products read %s operands%s",
         plan["layers"], first, first + count - 1,
         spec.n_experts, spec.top_k, spec.router, spec.n_experts, spec.dense_layers,
-        spec.shared_width, rows,
+        spec.shared_width, " under a sigmoid gate" if spec.shared_expert_gate else "", rows,
         100.0 * count / spec.n_experts, provisioned, spec.capacity_factor,
         trip, plan["provisioned_trips"], plan["product_operands"],
         f" ({product_reason})" if product_reason else "",
@@ -357,6 +393,7 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
         expert_product_dtype=product_dtype, **encoder_kwargs,
     )
     plan_latent_attention(cfg, model)
+    plan_linear_attention(cfg, model)
     plan_experts(cfg, model, product_reason)
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the

@@ -493,7 +493,7 @@ def test_build_says_what_the_layers_are(one_step):
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
                                 "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
                                 "dense_layers": 1, "router": "sigmoid", "shared_width": 24,
-                                "product_operands": "float32",
+                                "shared_gate": False, "product_operands": "float32",
                                 "product_reason": "non-TPU backend (cpu)",
                                 "ring_columns": list(TOKEN_ENCODERS[TINY].ring_columns)}
     latent = [r for r in one_step["events"] if r["name"] == "latent_attention_plan"]

@@ -559,7 +559,7 @@ def test_build_says_what_the_expert_layers_hold(one_step):
                                 "capacity_factor": 2.0, "provisioned_assignments": 8 * 16 * 2,
                                 "rows_per_trip": 8 * 16 * 2, "provisioned_trips": 1,
                                 "dense_layers": 0, "router": "softmax", "shared_width": 0,
-                                "product_operands": "float32",
+                                "shared_gate": False, "product_operands": "float32",
                                 "product_reason": "non-TPU backend (cpu)",
                                 "ring_columns": list(token_encoder.AUX_METRIC_KEYS)}
 

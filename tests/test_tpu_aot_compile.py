@@ -591,14 +591,11 @@ def test_a_trip_of_the_expert_sweep_holds_what_its_budget_says(expert_layer_comp
 # of 4,096 tokens through train.supcon's own program
 
 
-def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, tmp_path):
-    """``ring_update`` as ``benchmark/run.py`` builds it (the configuration's
-    flags, global batch 4, the resident store's 64-step epoch buffer), lowered
-    from shapes for one described v5e. The chip's compiler holds it in 13.2 GB
-    of arguments and temporaries (Keye's step, which loads, in 13.6); the dense
-    layer's ``[tokens, 11264]`` intermediates are a 2-row group's (369 MB),
-    never the batch's (1.48 GB); the expert sweep makes 2 trips of 24,576
-    rows over its provision."""
+def _cells_step(topo, tmp_path, name):
+    """``ring_update`` of the cell of configuration ``name`` as
+    ``benchmark/run.py`` builds it (the configuration's flags, global batch
+    4, the resident store's 64-step epoch buffer), compiled from shapes for
+    one described v5e."""
     import json
 
     from simclr_pytorch_distributed_tpu import config as config_lib
@@ -609,11 +606,6 @@ def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, t
     from simclr_pytorch_distributed_tpu.train import supcon
     from simclr_pytorch_distributed_tpu.train.supcon_step import metric_keys
 
-    name = "moonlight-16b-a3b-ep8"
-    spec = token_encoder.TOKEN_ENCODERS[name]
-    assert experts.balanced_chunk_rows(8 * 4096 * spec.top_k, spec.held[1], spec.n_experts,
-                                       49152, spec.hidden, spec.expert_width,
-                                       jnp.float32) == 24576
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
         flags = json.load(f)["flags"]
@@ -639,14 +631,58 @@ def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, t
         recipe=recipe)
     repl = replicated_sharding(mesh)
     placed = lambda x, s=repl: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)  # noqa: E731
-    compiled = update.lower(
+    return update.lower(
         jax.tree.map(placed, state, state_sharding(mesh, state)),
         jax.tree.map(placed, jax.eval_shape(ring.init_buffer)),
         placed(jax.ShapeDtypeStruct((64, 4, 1024, 1024, 3), jnp.uint8)),
         placed(jax.ShapeDtypeStruct((64, 4), jnp.int32)),
         jax.tree.map(placed, jax.eval_shape(lambda: jax.random.key(0)))).compile()
+
+
+def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, tmp_path):
+    """The chip's compiler holds the step in 13.2 GB of arguments and
+    temporaries (Keye's step, which loads, in 13.6); the dense layer's
+    ``[tokens, 11264]`` intermediates are a 2-row group's (369 MB), never the
+    batch's (1.48 GB); the expert sweep makes 2 trips of 24,576 rows over its
+    provision."""
+    name = "moonlight-16b-a3b-ep8"
+    spec = token_encoder.TOKEN_ENCODERS[name]
+    assert experts.balanced_chunk_rows(8 * 4096 * spec.top_k, spec.held[1], spec.n_experts,
+                                       49152, spec.hidden, spec.expert_width,
+                                       jnp.float32) == 24576
+    compiled = _cells_step(topo, tmp_path, name)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.7e9
     wide = [dims for _, dtype, dims in _top_level_arrays(compiled.as_text())
             if spec.dense_width in dims]
     assert wide and max(math.prod(dims) for dims in wide) <= 2 * 4096 * spec.dense_width, wide
+
+
+# ---- the whole step of the cell qwen3-next-80b-a3b-ep32.pretrain-1024px-b4
+# (three Gated DeltaNet layers and a gated full-attention layer, 16 of 512
+# experts with a gated shared expert) as a TPU's program builds it
+
+
+def test_delta_cells_step_compiles_with_group_sized_scan_tensors(topo, tmp_path, monkeypatch):
+    """Built as on the chip (``jax.default_backend()`` reads "tpu": the
+    grouped products on bfloat16 operands, the fused loss's kernels): the
+    chip's compiler holds the step in 12.6 GB of arguments and temporaries
+    (Moonlight's, which loads, in 13.2), and no ``[..., 64, 64]`` block of the chunked delta rule at
+    the top level of the program is more than a 2-row group's (the 64 chunks
+    of 32 heads of two rows); the expert sweep makes one trip of 20,480
+    rows."""
+    from simclr_pytorch_distributed_tpu.train import supcon
+
+    name = "qwen3-next-80b-a3b-ep32"
+    spec = token_encoder.TOKEN_ENCODERS[name]
+    assignments = 8 * 4096 * spec.top_k
+    provisioned = experts.provisioned_rows(assignments, 16, 512, spec.capacity_factor)
+    assert provisioned == 20480 and experts.balanced_chunk_rows(
+        assignments, 16, 512, provisioned, spec.hidden, spec.expert_width, jnp.float32) == 20480
+    monkeypatch.setattr(supcon.jax, "default_backend", lambda: "tpu")
+    compiled = _cells_step(topo, tmp_path, name)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.8e9
+    blocks = [dims for _, dtype, dims in _top_level_arrays(compiled.as_text())
+              if len(dims) >= 2 and dims[-2:] == (64, 64)]
+    assert max(math.prod(dims) for dims in blocks) <= 2 * 32 * 64 * 64 * 64, blocks
