@@ -293,15 +293,19 @@ def render_table(report):
                 f"tokens a row, on {latent['path']}'s path: {latent['reason']}")
         linear = report["encoder"].get("linear_attention_plan")
         if linear:
-            kinds = linear["layers"]
+            kinds, reasons = linear["layers"], {}
+            for layer in linear["per_layer"]:
+                if layer["reason"]:
+                    reasons.setdefault(layer["reason"], []).append(layer["name"])
             lines.append(
                 f"linear attention: {kinds.get('linear', 0)} Gated DeltaNet layers of "
                 f"{linear['key_heads']} key / {linear['value_heads']} value heads of "
                 f"{linear['key_dim']} / {linear['value_dim']} beside "
                 f"{sum(kinds.values()) - kinds.get('linear', 0)} full, "
                 f"{linear['conv_width']}-tap convolution, scan in chunks of {linear['chunk']} "
-                f"of {linear['tokens']} tokens, {linear['row_group']} rows a group, on "
-                f"{linear['path']}'s path: {linear['reason']}")
+                f"of {linear['tokens']} tokens, {linear['row_group']} rows a group, "
+                f"{linear['engaged']} on the kernel pair, {linear['on_xla']} on XLA's path"
+                + "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()))
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

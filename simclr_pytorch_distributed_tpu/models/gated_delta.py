@@ -17,11 +17,16 @@ input, ``Hk`` key heads and ``Hv`` value heads of ``dk`` and ``dv``:
   ``o_t = S_t^T q_t``;
 - ``y = rms(o) * w * silu(z)`` per head, then ``W_out``.
 
-The recurrence runs a chunk of ``chunk`` tokens at a time
-(``chunked_delta_rule``: the WY form of the delta rule, all of a chunk's
-products at once and one small recurrence over the chunks), in XLA, forward
-and backward by autodiff; the tests hold it to the recurrence token by token
-(``benchmark/reference_delta.delta_rule``). The layer recomputes its own row
+The recurrence runs a chunk of ``chunk`` tokens at a time: the WY form of
+the delta rule, all of a chunk's products at once and one small recurrence
+over the chunks. Two paths compute it. ``chunked_delta_rule`` is XLA's,
+forward and backward by autodiff, over ``q`` and ``k`` repeated to the value
+heads; the tests hold it to the recurrence token by token
+(``benchmark/reference_delta.delta_rule``). ``ops/delta_rule.py``'s Mosaic
+kernel pair computes the same a row's chunks in one sweep, each head's state
+in VMEM, over the key heads whole. The layer takes the second where its
+owner says the program is one TPU's (``kernel``) and its own dtype and shape
+allow it (``GatedDeltaNet.kernel_reason``). The layer recomputes its own row
 groups (``map_row_groups``), as the latent layer does.
 
 Parameter layout: ``W_qkvz`` is ``[q | k | v | z]`` and ``W_ba`` ``[b | c]``,
@@ -33,7 +38,7 @@ channels]`` with the last tap on the current token.
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +46,13 @@ from flax import linen as nn
 from jax import lax
 
 from simclr_pytorch_distributed_tpu.models.sparse_attention import (
+    _interpret_kernel,
     map_row_groups,
     normal_init,
     rms_norm,
     tie_gradients,
 )
+from simclr_pytorch_distributed_tpu.ops import delta_rule as kernel_ops
 
 # the whole layer: norm, projections, convolution, gates, scan, output
 SCOPE_LINEAR = "linear_attn"
@@ -186,6 +193,26 @@ class GatedDeltaNet(nn.Module):
     chunk: int
     rms_eps: float
     dtype: Any = jnp.float32
+    # the chunked rule through ops/delta_rule.py's kernel pair. Set by the
+    # owner that knows the mesh holds ONE device and the backend is a TPU
+    # (train.supcon.build); the dtype and the row's shape can still say no
+    # (kernel_reason).
+    kernel: bool = False
+
+    def chunk_of(self, tokens: int) -> int:
+        """The scan's chunk for rows of ``tokens``: the whole row where
+        ``chunk`` does not cut it."""
+        return self.chunk if tokens % self.chunk == 0 else tokens
+
+    def kernel_reason(self, tokens: int) -> Optional[str]:
+        """Why rows of ``tokens`` keep XLA's path through this layer, or
+        None: the kernel pair is float32 in and out, at a shape it tiles
+        within its VMEM budget. ``__call__`` and ``plan_linear_attention``
+        both ask here."""
+        if self.dtype != jnp.float32:
+            return f"compute dtype {jnp.dtype(self.dtype).name}"
+        return kernel_ops.unsupported(tokens, self.chunk_of(tokens), self.n_key_heads,
+                                      self.n_value_heads, self.key_dim, self.value_dim)
 
     @nn.compact
     def __call__(self, h: jax.Array) -> tuple:
@@ -198,7 +225,8 @@ class GatedDeltaNet(nn.Module):
         w["A_log"] = self.param("A_log", a_log_init, (Hv,))
         w.update({name: self.param(name, nn.initializers.ones, (n,))
                   for name, n in (("norm", D), ("dt_bias", Hv), ("out_norm", dv))})
-        chunk = self.chunk if T % self.chunk == 0 else T
+        chunk = self.chunk_of(T)
+        kernel = self.kernel and self.kernel_reason(T) is None
         with jax.named_scope(SCOPE_LINEAR):
             w, h = tie_gradients((w, h))
             w = {name: x if name in ("A_log", "dt_bias") else x.astype(self.dtype)
@@ -212,13 +240,24 @@ class GatedDeltaNet(nn.Module):
                     qkv = jax.nn.silu(short_conv(qkvz[..., :mixed], w["conv"]))
                 q = l2_normalise(qkv[..., :Hk * dk].reshape(n, T, Hk, dk)) / math.sqrt(dk)
                 k = l2_normalise(qkv[..., Hk * dk:2 * Hk * dk].reshape(n, T, Hk, dk))
-                q, k = (jnp.repeat(x, Hv // Hk, axis=2) for x in (q, k))
-                v = qkv[..., 2 * Hk * dk:].reshape(n, T, Hv, dv)
+                if not kernel:
+                    q, k = (jnp.repeat(x, Hv // Hk, axis=2) for x in (q, k))
+                v = qkv[..., 2 * Hk * dk:]
                 ba = (a @ w["ba"]).astype(jnp.float32)
                 beta = jax.nn.sigmoid(ba[..., :Hv])
                 g = -jnp.exp(w["A_log"]) * jax.nn.softplus(ba[..., Hv:] + w["dt_bias"])
                 with jax.named_scope(SCOPE_SCAN):
-                    o = chunked_delta_rule(q, k, v, g, beta, chunk)
+                    if kernel:
+                        # the projections' layout as it comes: [rows, T, heads * d];
+                        # the products' operands as XLA's default precision has them
+                        interpret = _interpret_kernel()
+                        o = kernel_ops.delta_rule(
+                            q.reshape(n, T, Hk * dk), k.reshape(n, T, Hk * dk), v, g, beta,
+                            n_key_heads=Hk, chunk=chunk,
+                            operands=jnp.float32 if interpret else jnp.bfloat16,
+                            interpret=interpret).reshape(n, T, Hv, dv)
+                    else:
+                        o = chunked_delta_rule(q, k, v.reshape(n, T, Hv, dv), g, beta, chunk)
                 z = qkvz[..., mixed:].reshape(n, T, Hv, dv)
                 y = (rms_norm(o, w["out_norm"], self.rms_eps).astype(jnp.float32)
                      * jax.nn.silu(z.astype(jnp.float32))).astype(self.dtype)

@@ -126,7 +126,8 @@ class SupConResNet(nn.Module):
     # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
     pointwise_bwd: bool = False
     # a token encoder's attention through ops/sparse_attention.py's kernel
-    # pair: set by train.supcon.build likewise (models/token_encoder.py)
+    # pair and its chunked delta rule through ops/delta_rule.py's: set by
+    # train.supcon.build likewise (models/token_encoder.py)
     attn_kernel: bool = False
     # the operands of a token encoder's grouped expert products, ``dtype``
     # where None: set by train.supcon.build likewise (models/experts.py)

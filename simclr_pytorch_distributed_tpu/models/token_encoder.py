@@ -220,12 +220,14 @@ def gated_attrs(s: TokenEncoderSpec, dtype) -> dict:
         q_chunk=s.q_chunk, rope_theta=s.rope_theta, rms_eps=s.rms_eps, dtype=dtype)
 
 
-def delta_attrs(s: TokenEncoderSpec, dtype) -> dict:
-    """The attributes of a block's ``GatedDeltaNet``."""
+def delta_attrs(s: TokenEncoderSpec, dtype, kernel: bool) -> dict:
+    """The attributes of a block's ``GatedDeltaNet``: what ``Block`` builds
+    its layer from and ``train.supcon.plan_linear_attention`` asks the same
+    layer with."""
     return dict(
         n_key_heads=s.linear_key_heads, n_value_heads=s.linear_value_heads,
         key_dim=s.linear_key_dim, value_dim=s.linear_value_dim, conv_width=s.conv_width,
-        chunk=s.delta_chunk, rms_eps=s.rms_eps, dtype=dtype)
+        chunk=s.delta_chunk, rms_eps=s.rms_eps, dtype=dtype, kernel=kernel)
 
 
 def expert_attrs(s: TokenEncoderSpec, dtype, product_dtype=None) -> dict:
@@ -266,7 +268,8 @@ class Block(nn.Module):
         elif kind == "gated":
             h = GatedAttention(**gated_attrs(s, self.dtype), name="attn")(h)
         elif kind == "linear":
-            h, decay = GatedDeltaNet(**delta_attrs(s, self.dtype), name="attn")(h)
+            h, decay = GatedDeltaNet(**delta_attrs(s, self.dtype, self.attn_kernel),
+                                     name="attn")(h)
         else:
             raise ValueError(f"no attention of kind {kind!r}")
         if self.index < s.dense_layers:  # likewise
@@ -290,9 +293,11 @@ class TokenEncoder(nn.Module):
     dtype: Any = jnp.float32
     # each block's sparse attention and expert layer recomputed in the backward
     remat: bool = False
-    # attention through ops/sparse_attention.py's kernel pair: set by
+    # attention through ops/sparse_attention.py's kernel pair and the
+    # chunked delta rule through ops/delta_rule.py's: set by
     # train.supcon.build on a one-device TPU mesh; each layer's dtype and
-    # shape can still say no (SparseAttention.kernel_reason)
+    # shape can still say no (SparseAttention.kernel_reason,
+    # GatedDeltaNet.kernel_reason)
     attn_kernel: bool = False
     # the type of the expert layers' grouped products' operands, ``dtype``
     # where None: set by train.supcon.build likewise (ExpertLayer.product_dtype)

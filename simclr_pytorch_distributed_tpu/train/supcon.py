@@ -192,14 +192,21 @@ def _one_tpu_reason(n_devices: int) -> Optional[str]:
     return None
 
 
-def _say_kernel_plan(tag: str, what: str, plan: list) -> list:
-    """One banner line ``[tag] N <what>, M on XLA's path; <names>: <why>`` and
-    one ``<tag>_plan`` event (track ``compile``: ``engaged``, ``on_xla``,
-    ``reasons``) for a ``[{"name", "reason"}]`` plan; returns the plan."""
+def _reasons(plan: list) -> dict:
+    """``{reason: [names]}`` of the sites of a ``[{"name", "reason"}]`` plan
+    that stay on XLA's path."""
     reasons: dict = {}
     for site in plan:
         if site["reason"] is not None:
             reasons.setdefault(site["reason"], []).append(site["name"])
+    return reasons
+
+
+def _say_kernel_plan(tag: str, what: str, plan: list) -> list:
+    """One banner line ``[tag] N <what>, M on XLA's path; <names>: <why>`` and
+    one ``<tag>_plan`` event (track ``compile``: ``engaged``, ``on_xla``,
+    ``reasons``) for a ``[{"name", "reason"}]`` plan; returns the plan."""
+    reasons = _reasons(plan)
     on_xla = sum(len(names) for names in reasons.values())
     logging.info(
         "[%s] %d %s, %d on XLA's path%s", tag, len(plan) - on_xla, what, on_xla,
@@ -272,39 +279,58 @@ def plan_latent_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
     return plan
 
 
-def plan_linear_attention(cfg: config_lib.SupConConfig, model: SupConResNet):
-    """What the Gated DeltaNet layers of ``model``'s encoder are, said once
-    in a banner line and one ``linear_attention_plan`` event (track
-    ``compile``): the layers by kind, the linear layers' heads and widths,
-    the convolution's taps, the scan's chunk, the full layers' heads, the
-    rows a group and the path with its reason. Today the path is always
-    XLA's: no Mosaic kernel computes the chunked delta rule. None for an
-    encoder without such layers."""
+def plan_linear_attention(
+    cfg: config_lib.SupConConfig, n_devices: int, **encoder_kwargs
+) -> list:
+    """What the Gated DeltaNet layers of the encoder built with
+    ``encoder_kwargs`` are, said once in a banner line and one
+    ``linear_attention_plan`` event (track ``compile``): the layers by kind,
+    the linear layers' heads and widths, the convolution's taps, the scan's
+    chunk, the full layers' heads, the rows a group, and for each linear
+    layer its path with its reason (``per_layer``; ``engaged`` on
+    ops/delta_rule.py's kernel pair, ``on_xla``). No flag chooses: one
+    device, a TPU, and what the layer says of itself
+    (``GatedDeltaNet.kernel_reason``: float32, a chunk and head widths the
+    kernels tile within their VMEM budget). Returns ``[{"name", "reason"}]``
+    a linear layer, empty for an encoder without such layers."""
+    from simclr_pytorch_distributed_tpu.models.gated_delta import GatedDeltaNet
     from simclr_pytorch_distributed_tpu.models.sparse_attention import ROW_GROUP
+    from simclr_pytorch_distributed_tpu.models.token_encoder import build_encoder, delta_attrs
 
-    spec = getattr(model.build_encoder(), "spec", None)
+    encoder = build_encoder(cfg.model, **encoder_kwargs)
+    spec = getattr(encoder, "spec", None)
     if spec is None or not spec.full_attention_interval:
-        return None
+        return []
     kinds = [spec.attention_of(k) for k in range(spec.layers)]
     tokens = (cfg.size // spec.patch) ** 2
+    layer = GatedDeltaNet(**delta_attrs(spec, encoder.dtype, True))
+    owner = _one_tpu_reason(n_devices)
+    sites = [{"name": f"block{k}", "reason": owner or layer.kernel_reason(tokens)}
+             for k, kind in enumerate(kinds) if kind == "linear"]
+    reasons = _reasons(sites)
+    on_xla = sum(len(names) for names in reasons.values())
     plan = {"layers": {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)},
             "key_heads": spec.linear_key_heads, "value_heads": spec.linear_value_heads,
             "key_dim": spec.linear_key_dim, "value_dim": spec.linear_value_dim,
-            "conv_width": spec.conv_width,
-            "chunk": spec.delta_chunk if tokens % spec.delta_chunk == 0 else tokens,
+            "conv_width": spec.conv_width, "chunk": layer.chunk_of(tokens),
             "full_heads": spec.n_heads, "full_kv_heads": spec.n_kv_heads,
             "full_head_dim": spec.head_dim, "tokens": tokens, "row_group": ROW_GROUP,
-            "path": "xla", "reason": "no Mosaic kernel for the chunked delta rule"}
+            "engaged": len(sites) - on_xla, "on_xla": on_xla,
+            "per_layer": [{"name": site["name"],
+                           "path": "xla" if site["reason"] else "kernel",
+                           "reason": site["reason"]} for site in sites]}
     logging.info(
         "[linear_attention] %d Gated DeltaNet layers of %d key / %d value heads of %d / %d, "
         "%d-tap convolution, scan in chunks of %d tokens, beside %d %s layers of %d / %d "
-        "heads of %d; %d causal tokens a row, %d rows a group; on XLA's path: %s",
-        plan["layers"]["linear"], plan["key_heads"], plan["value_heads"], plan["key_dim"],
-        plan["value_dim"], plan["conv_width"], plan["chunk"],
-        len(kinds) - plan["layers"]["linear"], spec.attention, plan["full_heads"],
-        plan["full_kv_heads"], plan["full_head_dim"], tokens, ROW_GROUP, plan["reason"])
+        "heads of %d; %d causal tokens a row, %d rows a group; %d on the kernel pair, "
+        "%d on XLA's path%s",
+        len(sites), plan["key_heads"], plan["value_heads"], plan["key_dim"],
+        plan["value_dim"], plan["conv_width"], plan["chunk"], len(kinds) - len(sites),
+        spec.attention, plan["full_heads"], plan["full_kv_heads"], plan["full_head_dim"],
+        tokens, ROW_GROUP, plan["engaged"], on_xla,
+        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()))
     tracing.event("linear_attention_plan", track=tracing.COMPILE_TRACK, **plan)
-    return plan
+    return sites
 
 
 def expert_product_operands(dtype) -> tuple:
@@ -385,15 +411,15 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
     )
     tail_plan = plan_pointwise_bwd(cfg, n_devices, **encoder_kwargs)
     attention_plan = plan_sparse_attention(cfg, n_devices, **encoder_kwargs)
+    linear_plan = plan_linear_attention(cfg, n_devices, **encoder_kwargs)
     product_dtype, product_reason = expert_product_operands(dtype)
     model = SupConResNet(
         model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
         pointwise_bwd=any(site["reason"] is None for site in tail_plan),
-        attn_kernel=any(layer["reason"] is None for layer in attention_plan),
+        attn_kernel=any(layer["reason"] is None for layer in attention_plan + linear_plan),
         expert_product_dtype=product_dtype, **encoder_kwargs,
     )
     plan_latent_attention(cfg, model)
-    plan_linear_attention(cfg, model)
     plan_experts(cfg, model, product_reason)
     # --ngpu auto -> the mesh's data-parallel size; an explicit mismatch is
     # promoted from a log-only warning to a startup banner naming the

@@ -403,8 +403,9 @@ def test_build_says_what_the_layers_are(one_step):
     assert plan["track"] == "compile" and plan["args"] == {
         "layers": {"linear": 3, "gated": 1}, "key_heads": 2, "value_heads": 4, "key_dim": 8,
         "value_dim": 8, "conv_width": 4, "chunk": 4, "full_heads": 4, "full_kv_heads": 2,
-        "full_head_dim": 8, "tokens": 16, "row_group": 2, "path": "xla",
-        "reason": "no Mosaic kernel for the chunked delta rule"}
+        "full_head_dim": 8, "tokens": 16, "row_group": 2, "engaged": 0, "on_xla": 3,
+        "per_layer": [{"name": f"block{k}", "path": "xla", "reason": "non-TPU backend (cpu)"}
+                      for k in range(3)]}
     (experts_plan,) = [r["args"] for r in one_step["events"] if r["name"] == "expert_plan"]
     assert (experts_plan["layers"], experts_plan["router"], experts_plan["shared_width"],
             experts_plan["shared_gate"]) == (4, "softmax", 16, True)
@@ -445,7 +446,10 @@ def test_trace_report_prints_the_plans():
     linear = {"layers": {"linear": 3, "gated": 1}, "key_heads": 16, "value_heads": 32,
               "key_dim": 128, "value_dim": 128, "conv_width": 4, "chunk": 64, "full_heads": 16,
               "full_kv_heads": 2, "full_head_dim": 256, "tokens": 4096, "row_group": 2,
-              "path": "xla", "reason": "no kernel"}
+              "engaged": 2, "on_xla": 1,
+              "per_layer": [{"name": "block0", "path": "kernel", "reason": None},
+                            {"name": "block1", "path": "kernel", "reason": None},
+                            {"name": "block2", "path": "xla", "reason": "no kernel"}]}
     events = [span,
               {"name": "expert_plan", "track": "compile", "ph": "i", "ts": 0.1, "args": plan},
               {"name": "linear_attention_plan", "track": "compile", "ph": "i", "ts": 0.1,
@@ -458,5 +462,5 @@ def test_trace_report_prints_the_plans():
     assert "shared experts of width 512 under a sigmoid gate" in table
     assert ("linear attention: 3 Gated DeltaNet layers of 16 key / 32 value heads of 128 / 128 "
             "beside 1 full, 4-tap convolution, scan in chunks of 64 of 4096 tokens, 2 rows a "
-            "group, on xla's path: no kernel") in table
+            "group, 2 on the kernel pair, 1 on XLA's path; block2: no kernel") in table
     assert "delta_decay_mean 0.05" in table

@@ -29,7 +29,8 @@ from jax import lax
 from simclr_pytorch_distributed_tpu.models import experts
 from simclr_pytorch_distributed_tpu.models import sparse_attention as attention_layer
 from simclr_pytorch_distributed_tpu.models import token_encoder
-from simclr_pytorch_distributed_tpu.ops import pallas_loss, pointwise_bwd, sparse_attention
+from simclr_pytorch_distributed_tpu.ops import (
+    delta_rule, pallas_loss, pointwise_bwd, sparse_attention)
 
 ROWS, SIZE, FEAT_DIM = 512, 32, 128  # 2 * batch 256 view rows, CIFAR, head out
 
@@ -665,12 +666,14 @@ def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, t
 
 def test_delta_cells_step_compiles_with_group_sized_scan_tensors(topo, tmp_path, monkeypatch):
     """Built as on the chip (``jax.default_backend()`` reads "tpu": the
-    grouped products on bfloat16 operands, the fused loss's kernels): the
-    chip's compiler holds the step in 12.6 GB of arguments and temporaries
-    (Moonlight's, which loads, in 13.2), and no ``[..., 64, 64]`` block of the chunked delta rule at
-    the top level of the program is more than a 2-row group's (the 64 chunks
-    of 32 heads of two rows); the expert sweep makes one trip of 20,480
-    rows."""
+    grouped products on bfloat16 operands, the fused loss's kernels, the
+    chunked delta rule on ops/delta_rule.py's kernel pair): the chip's
+    compiler holds the step in under 12.8 GB of arguments and temporaries
+    (Moonlight's, which loads, in 13.2); the rule is nine Mosaic calls (three
+    layers' forward, recomputed forward and backward), and no ``[..., 64,
+    64]`` block of it is left at the top level of the program, where XLA's
+    path had a 2-row group's (the 64 chunks of 32 heads of two rows); the
+    expert sweep makes one trip of 20,480 rows."""
     from simclr_pytorch_distributed_tpu.train import supcon
 
     name = "qwen3-next-80b-a3b-ep32"
@@ -683,6 +686,70 @@ def test_delta_cells_step_compiles_with_group_sized_scan_tensors(topo, tmp_path,
     compiled = _cells_step(topo, tmp_path, name)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.8e9
-    blocks = [dims for _, dtype, dims in _top_level_arrays(compiled.as_text())
-              if len(dims) >= 2 and dims[-2:] == (64, 64)]
-    assert max(math.prod(dims) for dims in blocks) <= 2 * 32 * 64 * 64 * 64, blocks
+    text = compiled.as_text()
+    # every call under its layer's delta_scan scope, the backward's too
+    calls = [m.group(1) for line in text.splitlines() if "custom-call(" in line
+             for m in [re.search(r'op_name="[^"]*/delta_scan/(delta_rule_\w+)/pallas_call', line)]
+             if m]
+    assert sorted(calls) == ["delta_rule_bwd"] * 3 + ["delta_rule_fwd"] * 6, calls
+    # (the health check's eigenvalues split a [128, 128] matrix in four
+    # [64, 64] blocks: no chunk's, which carry the chunks' and heads' axes)
+    blocks = [dims for _, dtype, dims in _top_level_arrays(text)
+              if len(dims) >= 3 and dims[-2:] == (64, 64)]
+    assert blocks == [], blocks
+
+
+# ---- the chunked delta rule's kernel pair (ops/delta_rule.py) at the
+# geometry of the cell qwen3-next-80b-a3b-ep32.pretrain-1024px-b4: a 2-row
+# group of 4,096 tokens, 16 key and 32 value heads of 128, chunks of 64
+
+
+def _delta_rule_calls(sharding, chunk=64, d=128, rows=2, tokens=4096, key_heads=16,
+                      value_heads=32):
+    """``{"fwd", "fwd-states", "bwd"}``: each call with its operands' shapes,
+    as ``delta_rule._rule_fwd`` and ``_rule_bwd`` hand them over."""
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    qk, v, g = sds(rows, tokens, key_heads * d), sds(rows, tokens, value_heads * d), \
+        sds(rows, tokens, value_heads)
+    states = sds(rows, tokens // chunk, value_heads, d, d)
+    geometry = dict(n_key_heads=key_heads, chunk=chunk, operands=jnp.bfloat16, interpret=False)
+
+    def forward(keep_states):
+        return lambda *a: delta_rule._forward_call(*a, keep_states=keep_states, **geometry)
+
+    return {"fwd": (forward(False), (qk, qk, v, g, g)),
+            "fwd-states": (forward(True), (qk, qk, v, g, g)),
+            "bwd": (lambda *a: delta_rule._backward_call(*a, **geometry),
+                    (qk, qk, v, g, g, states, v))}
+
+
+@pytest.mark.parametrize("which", ["fwd", "fwd-states", "bwd"])
+def test_delta_rule_kernels_compile_at_the_cells_shapes(one_chip, which):
+    assert delta_rule.unsupported(4096, 64, 16, 32, 128, 128) is None
+    fn, shapes = _delta_rule_calls(one_chip)[which]
+    text = _compile(fn, *shapes)
+    assert f"delta_rule_{which[:3]}" in text
+
+
+def test_delta_rule_budget_is_the_compilers(one_chip):
+    """The predicate counts a backward step's blocks and the temporaries the
+    compiler adds beside them. Chunks of 128 (a key head's stacked block 256
+    rows): 13.6 MiB, admitted, and Mosaic needs 12.45M of its 16.00M.
+    Chunks of 64 over heads of 256: 16.9 MiB, refused, though Mosaic,
+    asked all the same, needs 15.94M (the margin). Chunks of 192: 20.5 MiB,
+    refused, and Mosaic refuses too."""
+    admitted = delta_rule.vmem_bytes(128, 16, 32, 128, 128)
+    assert 13 << 20 < admitted <= 14 << 20
+    assert delta_rule.unsupported(1024, 128, 16, 32, 128, 128) is None
+    fn, shapes = _delta_rule_calls(one_chip, chunk=128, tokens=1024)["bwd"]
+    _compile(fn, *shapes)
+    assert "16.9 MiB of VMEM" in delta_rule.unsupported(1024, 64, 16, 32, 256, 256)
+    fn, shapes = _delta_rule_calls(one_chip, d=256, tokens=1024)["bwd"]
+    _compile(fn, *shapes)
+    assert "20.5 MiB of VMEM" in delta_rule.unsupported(768, 192, 16, 32, 128, 128)
+    fn, shapes = _delta_rule_calls(one_chip, chunk=192, tokens=768)["bwd"]
+    with pytest.raises(Exception, match=r"(?i)vmem.*19\.\d\dM and limit 16\.00M"):
+        _compile(fn, *shapes)
