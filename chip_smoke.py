@@ -79,17 +79,17 @@ def phase(name: str):
 def compile_record(name: str, run: str) -> None:
     """What a driver's own flight recorder saw compile (track ``compile``,
     utils/tracing.forward_compile_events): seconds inside XLA's backend
-    compile *or* its persistent-cache read, summed over programs."""
+    compile *or* its persistent-cache read, summed over programs, and how
+    many of them the cache answered (each span's ``cache_hit``)."""
     from simclr_pytorch_distributed_tpu.utils import tracing
 
-    events = [e for e in tracing.load_events_jsonl(os.path.join(run, "events.jsonl"))
-              if e.get("track") == tracing.COMPILE_TRACK]
-    compiles = [e["args"] for e in events if e["name"] == "backend_compile"]
+    compiles = [e["args"] for e in tracing.load_events_jsonl(os.path.join(run, "events.jsonl"))
+                if e.get("track") == tracing.COMPILE_TRACK and e["name"] == "backend_compile"]
     check(compiles, f"{name}: <run>/events.jsonl records no backend_compile")
     slowest = max(compiles, key=lambda a: a["duration_s"])
     say(f"phase {name}: compile {sum(a['duration_s'] for a in compiles):.2f}s "
         f"over {len(compiles)} programs "
-        f"({sum(e['name'] == 'cache_hit' for e in events)} cache hits), "
+        f"({sum(bool(a.get('cache_hit')) for a in compiles)} cache hits), "
         f"slowest {slowest.get('fun_name', '?')} {slowest['duration_s']:.2f}s")
 
 

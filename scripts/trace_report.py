@@ -14,6 +14,13 @@ recording (each ``flush_boundary`` span carries its window's ``dispatch_s``):
 room in the device's queue, and ``rest`` is everything else no span covers.
 The epoch-end ``drain_wait`` spans are on ``main:flush`` and count there.
 
+The attribution starts at the recorder's own start, and leaves out the
+records from before it (``ts < 0``: the process's start, imports, set-up
+spans and compiles made before the recorder existed). Set-up has a table
+of its own: from the process's start to the first ``flush_boundary``, in
+rows that never share a second (``build_setup``), and the ten programs with
+the most compile seconds, each with its persistent-cache hits.
+
 This script reads the jsonl, builds that attribution table with anomaly
 flags (compile-dominated runs, flush-heavy windows, data stalls, recorded
 stall/rollback/preemption events), prints it, and writes a JSON artifact —
@@ -114,8 +121,11 @@ def build_report(events):
     synthetic event lists)."""
     if not events:
         raise ValueError("no events: recorder off or empty run?")
-    t0 = min(e["ts"] for e in events)
-    t1 = max(e["ts"] + e.get("dur", 0.0) for e in events)
+    # from the recorder's own start: what came before it (ts < 0: the
+    # process's start, set-up before the recorder) is the set-up table's
+    own = [e for e in events if e["ts"] >= 0] or events
+    t0 = min(e["ts"] for e in own)
+    t1 = max(e["ts"] + e.get("dur", 0.0) for e in own)
     wall = t1 - t0
 
     tracks = _attributed_tracks(events)
@@ -226,6 +236,126 @@ def encoder_section(events):
     section.update({name: e["args"] for e in events for name in (
         "latent_attention_plan", "linear_attention_plan") if e["name"] == name})
     return {"encoder": section}
+
+
+# ------------------------------------------------------------------ set-up
+
+
+def _union(spans):
+    """Sorted disjoint intervals covering the (start, end) pairs."""
+    out = []
+    for a, b in sorted(spans):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [tuple(x) for x in out]
+
+
+def _minus(spans, cover):
+    """The parts of the disjoint intervals ``spans`` outside ``cover``."""
+    out = []
+    for a, b in spans:
+        for c, d in cover:
+            if d <= a or c >= b:
+                continue
+            if c > a:
+                out.append((a, c))
+            a = max(a, d)
+            if a >= b:
+                break
+        if a < b:
+            out.append((a, b))
+    return out
+
+
+def _seconds(spans):
+    return sum(b - a for a, b in spans)
+
+
+def build_setup(events, top=10):
+    """The set-up table (pure): from the process's start (the earliest
+    record where none says it) to the first ``flush_boundary``, split into
+    rows that never share a second, so they sum to that wall. In order of
+    precedence: ``compile`` (union of ``backend_compile`` spans: XLA's
+    compile or the persistent cache's read), ``trace_lower`` (union of
+    ``trace`` and ``lower`` spans, less compile), then ``boot`` (process
+    start to the package's first line) and each ``setup`` span by name
+    (``import``, ``backend_start``, ``store``, ``tb_writer``, ...), then
+    what is left of ``first_step`` and the rest of the first flush window
+    (``first_window``), each less what a row above holds; ``other`` is what
+    no record covers. Unions, never sums: a function traced inside another
+    has a ``trace`` span inside the outer one. Also the ``top`` programs
+    with the most compile seconds, with their cache hits. None without a
+    ``flush_boundary``."""
+    ends = [e["ts"] for e in events
+            if e["name"] == "flush_boundary" and e.get("ph") == "X"]
+    if not ends:
+        return None
+    t1 = min(ends)
+    marks = {e["name"]: e["ts"] for e in events if e.get("track") == tracing.SETUP_TRACK
+             and e.get("ph") != "X"}
+    t0 = marks.get(tracing.PROCESS_START, min(e["ts"] for e in events))
+
+    def spans(keep):
+        return _union([(max(e["ts"], t0), min(e["ts"] + e["dur"], t1)) for e in events
+                       if e.get("ph") == "X" and keep(e)
+                       and e["ts"] < t1 and e["ts"] + e["dur"] > t0])
+
+    compiles = [e for e in events if e["name"] == "backend_compile" and e.get("ph") == "X"
+                and t0 <= e["ts"] < t1]
+    rows, covered = {}, []
+
+    def row(name, intervals):
+        own = _minus(_union(intervals), covered)
+        rows[name] = rows.get(name, 0.0) + _seconds(own)
+        covered[:] = _union(covered + own)
+
+    row("compile", spans(lambda e: e["name"] == "backend_compile"))
+    row("trace_lower", spans(lambda e: e.get("track") == tracing.COMPILE_TRACK
+                             and e["name"] in ("trace", "lower")))
+    if tracing.PROCESS_START in marks and tracing.PACKAGE_IMPORT in marks:
+        row("boot", [(t0, marks[tracing.PACKAGE_IMPORT])])
+    for name in dict.fromkeys(e["name"] for e in events
+                              if e.get("track") == tracing.SETUP_TRACK and e.get("ph") == "X"):
+        row(name, spans(lambda e: e.get("track") == tracing.SETUP_TRACK and e["name"] == name))
+    first = [e for e in events if e["name"] == "first_step" and e.get("ph") == "X"
+             and e["ts"] < t1]
+    if first:
+        row("first_step", spans(lambda e: e is first[0]))
+        row("first_window", [(first[0]["ts"] + first[0]["dur"], t1)])
+    wall = t1 - t0
+    rows["other"] = wall - sum(rows.values())
+    programs = {}
+    for e in compiles:
+        p = programs.setdefault(e["args"].get("fun_name", "?"), [0.0, 0, 0])
+        p[0] += e["dur"]
+        p[1] += 1
+        p[2] += bool(e["args"].get("cache_hit"))
+    dearest = sorted(programs.items(), key=lambda kv: -kv[1][0])[:top]
+    return {
+        "wall_s": round(wall, 6),
+        "process_start": tracing.PROCESS_START in marks,
+        "rows": {k: round(v, 6) for k, v in rows.items()},
+        "programs": [{"fun_name": name, "seconds": round(sec, 6), "compiles": n,
+                      "cache_hits": hits} for name, (sec, n, hits) in dearest],
+    }
+
+
+def render_setup(setup):
+    wall = setup["wall_s"]
+    head = "process start" if setup["process_start"] else "first record"
+    lines = [f"set-up, {head} to the first flush boundary: {wall:.3f}s"]
+    for name, sec in setup["rows"].items():
+        share = sec / wall if wall > 0 else 0.0
+        lines.append(f"  {name:<14}{sec:>10.3f}s {share:>7.1%}")
+    if setup["programs"]:
+        lines.append("dearest programs (compile or cache read):")
+    for p in setup["programs"]:
+        misses = p["compiles"] - p["cache_hits"]
+        lines.append(f"  {p['seconds']:>10.3f}s  {p['fun_name']}  "
+                     f"({p['cache_hits']} hit, {misses} miss)")
+    return "\n".join(lines)
 
 
 def render_table(report):
@@ -630,11 +760,18 @@ def main(argv=None):
 
     if args.fleet:
         return run_fleet(args)
-    report = build_report(load_events(args.events))
+    events = load_events(args.events)
+    report = build_report(events)
     print(render_table(report))
+    setup = build_setup(events)
+    if setup is not None:
+        print(render_setup(setup))
     if args.json:
+        out = build_output(args.events, report)
+        if setup is not None:
+            out["setup"] = setup
         with open(args.json, "w") as f:
-            json.dump(build_output(args.events, report), f, indent=1)
+            json.dump(out, f, indent=1)
         print(f"wrote {args.json}")
     return 0 if report["consistency"]["ok"] else 1
 

@@ -16,6 +16,13 @@ it TPU-first:
   (reference ``main_supcon.py:200-207``).
 """
 
+import time
+
+# The package's first line on the set-up clock: where the set-up span
+# ``import`` starts (utils/tracing.imports_done). Stdlib only, so the bare
+# package import stays free of jax and of ``utils``.
+IMPORT_STARTED = time.monotonic()
+
 __version__ = "0.1.0"
 
 

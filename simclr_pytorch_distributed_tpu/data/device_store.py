@@ -350,18 +350,22 @@ def make_store(
     non-contiguous input via ``ascontiguousarray``, and what resolution
     inspects must be exactly what the store would upload — two sources
     could drift on the memmap check.
+
+    All of it is the set-up span ``store`` (track ``setup``): placement and
+    the dataset's upload.
     """
-    placement = resolve_data_placement(
-        placement, loader.images, loader.labels, loader.global_batch_size,
-        mesh, budget_bytes=budget_bytes, window_batches=window_batches,
-    )
-    if placement == "device":
-        return DeviceStore(loader, mesh)
-    if placement == "window":
-        return WindowStore(
-            loader, mesh, window_batches or DEFAULT_WINDOW_BATCHES
+    with tracing.span("store", track=tracing.SETUP_TRACK):
+        placement = resolve_data_placement(
+            placement, loader.images, loader.labels, loader.global_batch_size,
+            mesh, budget_bytes=budget_bytes, window_batches=window_batches,
         )
-    return None
+        if placement == "device":
+            return DeviceStore(loader, mesh)
+        if placement == "window":
+            return WindowStore(
+                loader, mesh, window_batches or DEFAULT_WINDOW_BATCHES
+            )
+        return None
 
 
 def _validate_loader_geometry(loader, mesh, kind: str) -> None:

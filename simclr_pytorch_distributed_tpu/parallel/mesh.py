@@ -17,6 +17,7 @@ runtime is a single SPMD program:
 
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Optional, Sequence
 
@@ -28,6 +29,28 @@ logger = logging.getLogger(__name__)
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
+
+
+_backend_asked = False
+
+
+def _backend_ask():
+    """Around each of the program's asks of the backend that can be its
+    first (``setup_distributed``, ``create_mesh`` without devices,
+    ``is_main_process``, which flag parsing calls): the process's first is
+    the set-up span ``backend_start`` (track ``setup``), which starts the
+    backend (on a TPU host, the chips' runtime) unless something outside
+    the program did, and closes the span ``import`` before it; every later
+    one is a null context."""
+    global _backend_asked
+    if _backend_asked:
+        return contextlib.nullcontext()
+    _backend_asked = True
+    # lazy: utils imports this module
+    from simclr_pytorch_distributed_tpu.utils import tracing
+
+    tracing.imports_done()
+    return tracing.span("backend_start", track=tracing.SETUP_TRACK)
 
 
 def setup_distributed(
@@ -47,7 +70,9 @@ def setup_distributed(
         # raise), or this is a plain single-host run (nothing to do). Only
         # this branch may touch process_count(): the explicit-coordinator
         # path below must reach initialize() before any backend init.
-        if jax.process_count() > 1 or num_processes in (None, 1):
+        with _backend_ask():
+            n = jax.process_count()
+        if n > 1 or num_processes in (None, 1):
             return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
@@ -68,7 +93,8 @@ def create_mesh(
 ) -> Mesh:
     """Build a (data, model) mesh over all devices; model axis defaults to 1."""
     if devices is None:
-        devices = jax.devices()
+        with _backend_ask():
+            devices = jax.devices()
     devices = list(devices)
     n = len(devices)
     if n % model_parallel != 0:
@@ -121,7 +147,8 @@ def sync_processes(tag: str) -> None:
 
 def is_main_process() -> bool:
     """Process-0 gating for I/O (reference local_rank==0 checks)."""
-    return jax.process_index() == 0
+    with _backend_ask():
+        return jax.process_index() == 0
 
 
 def batch_sharding(mesh: Mesh, ndim: int = 1) -> NamedSharding:

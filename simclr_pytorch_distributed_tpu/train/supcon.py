@@ -837,7 +837,15 @@ def enable_compile_cache() -> str:
     is set in code. Unset -> ``<checkout>/.jax_cache``, one fixed path for the
     trainers, the server, bench.py and chip_smoke.py (a dir that moves
     with ``--workdir`` never hits).
+
+    The drivers call it first after their imports and flag parsing, so its
+    entry closes the set-up span ``import`` where the program's first ask of
+    the backend has not (``parallel/mesh.py``), and from here every
+    program's trace, lowering and compile is recorded (utils/tracing.py),
+    into the module's buffer until the run's recorder is installed.
     """
+    tracing.imports_done()
+    tracing.forward_compile_events()
     # Scopes and module paths are metadata, which the cache's key leaves out
     # by default: an executable cached by a build with OTHER scopes (or none)
     # would be loaded with its stale op_names, and the per-scope reduction of
@@ -1046,7 +1054,8 @@ def run(cfg: config_lib.SupConConfig) -> TrainState:
                 "recipe": recipe.name, "moco_queue": cfg.moco_queue}
 
     update_fn = build_update(policy.lr_scale)
-    tb = TBLogger(cfg.tb_folder, enabled=is_main_process())
+    with tracing.span("tb_writer", track=tracing.SETUP_TRACK):
+        tb = TBLogger(cfg.tb_folder, enabled=is_main_process())
     base_key = jax.random.key(cfg.seed + 1)
     tracer = StepTracer(
         cfg.trace_dir, cfg.trace_start_step, cfg.trace_steps,

@@ -35,9 +35,11 @@ class RunObservability:
     """Build (and later tear down, in the right order) the per-run
     observability stack from a trainer config:
 
-    - ``recorder`` — installed as the module-level tracing recorder, and
-      fed one ``backend_compile`` / ``cache_hit`` event per compile
-      (``tracing.forward_compile_events``); ``None`` under
+    - ``recorder`` — installed as the module-level tracing recorder, which
+      takes over the set-up records made before it (``process_start``, the
+      ``import`` span, compiles), and fed JAX's ``trace`` / ``lower`` /
+      ``backend_compile`` spans of every program, the last with
+      ``cache_hit`` (``tracing.forward_compile_events``); ``None`` under
       ``--flight_recorder off``;
     - ``watchdog`` — a started :class:`tracing.StallWatchdog` beating on
       the flush boundary (via ``TelemetrySession``); ``None`` unless

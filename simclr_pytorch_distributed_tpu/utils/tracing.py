@@ -34,22 +34,36 @@ attribution. Tracks owned by other threads (``telemetry:*``,
 ``prefetch:*``, ``serve:*``) carry no such invariant (concurrent serve
 requests overlap by design).
 
+Set-up is on the record too, on one clock from the process's start
+(``time.monotonic``): each recorder on that clock opens with a
+``process_start`` event at a negative ``ts`` (read once from
+``/proc/self/stat``; Linux only, elsewhere nothing is recorded), the track
+``setup`` holds spans that never overlap each other (``import``, ``store``,
+``backend_start``, ``tb_writer``), and the track ``compile`` JAX's own
+``trace``, ``lower`` and ``backend_compile`` spans per program
+(:func:`forward_compile_events`). ``setup`` is not a ``main:*`` track, so
+a main-thread phase may nest inside a set-up span.
+
 The module-level ``install``/``span``/``event`` helpers follow the
 ``logging`` pattern: instrumentation sites call ``tracing.span(...)``
 unconditionally and pay only a global read + a no-op context manager when
 no recorder is installed — deep modules (telemetry, device_store,
 checkpoint, preempt, the serve batcher) need no recorder threading through
-their signatures.
+their signatures. The one exception is the time before the process's first
+``install``: records on ``setup`` and ``compile`` then wait in a small
+module buffer, and the first install hands them to its recorder.
 """
 
 from __future__ import annotations
 
 import contextlib
 import faulthandler
+import functools
 import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -65,7 +79,14 @@ EPOCH_TRACK = "main:epoch"
 # records scripts/trace_report.py --fleet aligns multi-process timelines on
 FLEET_TRACK = "fleet"
 ANCHOR_EVENT = "clock_anchor"
-
+# set-up, from the process's start to the first step (module docstring)
+SETUP_TRACK = "setup"
+COMPILE_TRACK = "compile"
+PROCESS_START = "process_start"
+PACKAGE_IMPORT = "package_import"
+# the clock of records made before any recorder exists: a recorder on
+# another clock can place neither them nor the process's start
+_setup_clock = time.monotonic
 
 
 class FlightRecorder:
@@ -76,7 +97,9 @@ class FlightRecorder:
     ``events.jsonl`` file as they land. ``clock`` must be monotonic;
     timestamps are stored relative to construction time, so records from
     different processes align only per-file (one recorder per process,
-    ``recorder_for_run``).
+    ``recorder_for_run``). On the set-up clock (``time.monotonic``, the
+    default) the first record is ``process_start`` on track ``setup``, at a
+    negative ``ts``.
     """
 
     def __init__(
@@ -98,6 +121,10 @@ class FlightRecorder:
         self.process_index = int(process_index)
         self.dropped = 0  # records lost to the ring bound (jsonl keeps all)
         self._anchor_seq = 0  # clock_anchor sequence (see clock_anchor)
+        if clock is _setup_clock:
+            started = process_start()
+            if started is not None:
+                self.record_event(PROCESS_START, SETUP_TRACK, started)
 
     # ------------------------------------------------------------ record
     def _emit(self, rec: dict) -> None:
@@ -127,9 +154,15 @@ class FlightRecorder:
 
     def event(self, name: str, track: str = "events", **attrs) -> None:
         """An instantaneous event (Chrome ``ph: "i"``)."""
+        self.record_event(name, track, self._clock(), **attrs)
+
+    def record_event(self, name: str, track: str, at: float, /, **attrs) -> None:
+        """An instantaneous event at an explicit value of this recorder's
+        clock (see :meth:`record_span`); positional, so that an attribute
+        may be called ``at``."""
         rec = {
             "name": name, "track": track, "ph": "i",
-            "ts": round(self._clock() - self._t0, 6),
+            "ts": round(at - self._t0, 6),
         }
         if attrs:
             rec["args"] = attrs
@@ -245,7 +278,10 @@ class FlightRecorder:
 def chrome_trace_from_events(events: Iterable[dict], process_index: int = 0) -> dict:
     """Chrome trace-event JSON from recorder records (pure; schema pinned by
     tests/test_tracing.py). Tracks map to integer ``tid``s with
-    ``thread_name`` metadata; ``ts``/``dur`` are integer microseconds."""
+    ``thread_name`` metadata; ``ts``/``dur`` are integer microseconds, moved
+    so that none is negative (set-up records precede the recorder)."""
+    events = list(events)
+    base = min([0.0] + [rec["ts"] for rec in events])
     tids: dict = {}
     out = []
     for rec in events:
@@ -258,7 +294,7 @@ def chrome_trace_from_events(events: Iterable[dict], process_index: int = 0) -> 
             "ph": "X" if rec.get("ph") == "X" else "i",
             "pid": process_index,
             "tid": tid,
-            "ts": int(round(rec["ts"] * 1e6)),
+            "ts": int(round((rec["ts"] - base) * 1e6)),
             "args": rec.get("args", {}),
         }
         if ev["ph"] == "X":
@@ -408,10 +444,28 @@ def recorder_for_run(
 # and cost a global read when no recorder is installed.
 
 _current: Optional[FlightRecorder] = None
+# Records on these tracks made before the process's first install wait here,
+# stamped by the set-up clock, as (name, track, start, end or None, attrs);
+# None from the first install on (of a recorder or of None).
+_EARLY_TRACKS = frozenset((SETUP_TRACK, COMPILE_TRACK))
+EARLY_RECORDS_MAX = 256
+_early: Optional[list] = []
+_early_lock = threading.Lock()
 
 
 def install(recorder: Optional[FlightRecorder]) -> None:
-    global _current
+    """Make ``recorder`` the module's (None: none). The process's first
+    install hands the records made before it to ``recorder`` where it runs
+    on the set-up clock, and ends the buffering either way."""
+    global _current, _early
+    with _early_lock:
+        early, _early = _early, None
+    if early and recorder is not None and recorder._clock is _setup_clock:
+        for name, track, start, end, attrs in early:
+            if end is None:
+                recorder.record_event(name, track, start, **attrs)
+            else:
+                recorder.record_span(name, track, start, end, **attrs)
     _current = recorder
 
 
@@ -423,13 +477,30 @@ def current() -> Optional[FlightRecorder]:
     return _current
 
 
+def _keep_early(name: str, track: str, start: float, end: Optional[float],
+                attrs: dict) -> None:
+    with _early_lock:
+        if _early is not None and len(_early) < EARLY_RECORDS_MAX:
+            _early.append((name, track, start, end, attrs))
+
+
+def _buffering(track: str) -> bool:
+    return _early is not None and track in _EARLY_TRACKS
+
+
 @contextlib.contextmanager
 def span(name: str, track: str, **attrs):
     rec = _current
-    if rec is None:
-        yield
-        return
-    with rec.span(name, track, **attrs):
+    if rec is not None:
+        with rec.span(name, track, **attrs):
+            yield
+    elif _buffering(track):
+        start = _setup_clock()
+        try:
+            yield
+        finally:
+            _keep_early(name, track, start, _setup_clock(), attrs)
+    else:
         yield
 
 
@@ -437,6 +508,8 @@ def event(name: str, track: str = "events", **attrs) -> None:
     rec = _current
     if rec is not None:
         rec.event(name, track, **attrs)
+    elif _buffering(track):
+        _keep_early(name, track, _setup_clock(), None, attrs)
 
 
 def clock_anchor(kind: str, **attrs) -> Optional[int]:
@@ -452,44 +525,126 @@ def record_span(name: str, track: str, start: float, end: float, **attrs) -> Non
     rec = _current
     if rec is not None:
         rec.record_span(name, track, start, end, **attrs)
+    elif _buffering(track):
+        _keep_early(name, track, start, end, attrs)
+
+
+def record_event(name: str, track: str, at: float, /, **attrs) -> None:
+    rec = _current
+    if rec is not None:
+        rec.record_event(name, track, at, **attrs)
+    elif _buffering(track):
+        _keep_early(name, track, at, None, attrs)
+
+
+# ---------------------------------------------------------------- set-up
+# Where set-up starts: the process (``process_start``, from the kernel) and
+# the package (``package_import``, the package's own first line).
+
+_imports_recorded = False
+
+
+@functools.lru_cache(maxsize=1)
+def _started_after_boot() -> Optional[float]:
+    """Seconds after boot at which this process started: field 22 of
+    ``/proc/<pid>/stat`` (clock ticks since boot) over ``SC_CLK_TCK``; None
+    where the file or the clock is missing (not Linux)."""
+    try:
+        with open("/proc/self/stat") as f:
+            stat = f.read()
+        # field 2, the command, is in parentheses and may hold spaces
+        ticks = int(stat[stat.rindex(")") + 2:].split()[22 - 3])
+        return ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def process_start() -> Optional[float]:
+    """The process's start on the set-up clock, or None where the system
+    does not say (then nothing is recorded: it is never estimated).
+    ``/proc`` is read once; the age is taken on ``CLOCK_BOOTTIME``, the
+    clock the kernel's start time counts on, to the tick (10 ms)."""
+    started = _started_after_boot()
+    boottime = getattr(time, "CLOCK_BOOTTIME", None)
+    if started is None or boottime is None:
+        return None
+    return _setup_clock() - (time.clock_gettime(boottime) - started)
+
+
+def imports_done() -> None:
+    """Record the set-up span ``import``: from the package's first line
+    (``simclr_pytorch_distributed_tpu.IMPORT_STARTED``, where a
+    ``package_import`` event marks it) to now. Once a process, at the first
+    of two places: the program's first ask of the backend
+    (``parallel/mesh.py``, which flag parsing reaches, and which opens the
+    span ``backend_start`` next), and the entry of
+    ``train.supcon.enable_compile_cache``, where the drivers' imports and
+    flag parsing end."""
+    global _imports_recorded
+    if _imports_recorded:
+        return
+    _imports_recorded = True
+    package = sys.modules.get(__name__.split(".")[0])
+    started = getattr(package, "IMPORT_STARTED", None)
+    if started is None:
+        return
+    record_event(PACKAGE_IMPORT, SETUP_TRACK, started)
+    record_span("import", SETUP_TRACK, started, _setup_clock())
 
 
 # ---------------------------------------------------------------- compiles
-# The run's own compile record: one ``backend_compile`` event (track
-# ``compile``) per program the backend compiled or read back from the
-# persistent cache, with its seconds and the jitted function's name, and a
-# ``cache_hit`` event per persistent-cache hit. An operator whose run
+# The run's own compile record on track ``compile``: JAX's three phases of
+# every program as spans, ``trace`` (Python to jaxpr), ``lower`` (jaxpr to
+# the compiler's module) and ``backend_compile`` (XLA's compile, or its read
+# from the persistent cache: ``cache_hit`` says which; ``duration_s`` its
+# seconds), each with the jitted function's ``fun_name``. A function traced
+# inside another has a ``trace`` span of its own inside the outer one, so a
+# reader takes unions of these spans, never sums. An operator whose run
 # recompiles (resume on another mesh, a second layout of the update) sees
-# which program and for how long, not one slow step.
+# which program, which phase and for how long, not one slow step.
 
-COMPILE_TRACK = "compile"
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _compile_listeners_on = False
+# JAX fires the cache-hit event on the compiling thread inside the backend
+# compile whose span it then closes
+_cache_hit = threading.local()
 
 
 def forward_compile_events() -> None:
     """Register (once a process; jax.monitoring keeps listeners for good)
-    the two listeners that forward to whichever recorder is installed when
-    a compile happens. No recorder, no work."""
+    the listeners that forward the compile phases to whichever recorder is
+    installed when a program compiles, or before the first install to the
+    module's buffer. Afterwards with no recorder, no work."""
     global _compile_listeners_on
     if _compile_listeners_on:
         return
     import jax  # lazy: this module must stay importable without jax
 
-    def on_duration(name, duration, **kw):
-        rec = _current
-        if rec is not None and name == _BACKEND_COMPILE:
+    # JAX stamps the spans with time.time(): one offset, taken here, puts
+    # them on the set-up clock
+    wall_ahead = time.time() - _setup_clock()
+
+    def on_span(event, start, end, **kw):
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        if name == "backend_compile":
+            hit = getattr(_cache_hit, "hit", False)
+            _cache_hit.hit = False
             # fun_name: this JAX version passes it; an older one passes none
-            rec.event("backend_compile", track=COMPILE_TRACK,
-                      duration_s=round(duration, 6), **kw)
+            kw.update(duration_s=round(end - start, 6), cache_hit=hit)
+        record_span(name, COMPILE_TRACK, start - wall_ahead, end - wall_ahead, **kw)
 
-    def on_event(name, **kw):
-        rec = _current
-        if rec is not None and name == _CACHE_HIT:
-            rec.event("cache_hit", track=COMPILE_TRACK, **kw)
+    def on_event(event, **kw):
+        if event == _CACHE_HIT:
+            _cache_hit.hit = True
 
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_time_span_listener(on_span)
     jax.monitoring.register_event_listener(on_event)
     _compile_listeners_on = True
 

@@ -190,10 +190,11 @@ def test_flush_boundary_carries_the_dispatch_counters(tiny_run):
 
 def test_hot_loop_records_nothing_between_boundaries(tiny_run):
     """From a boundary's clock anchor to the next boundary's span: no record
-    on the main thread but the run's one compile step with its compiles (and,
-    under --trace_dir, the tracer's anchor)."""
+    on the main thread but the run's one compile step with its compiles, each
+    a program's trace, lowering and backend compile (and, under --trace_dir,
+    the tracer's anchor)."""
     events = _main_thread(tiny_run["events"])
-    allowed = {"first_step", "backend_compile", "cache_hit", profiling.TRACE_ANCHOR}
+    allowed = {"first_step", "trace", "lower", "backend_compile", profiling.TRACE_ANCHOR}
     inside = False
     for e in events:
         if e["name"] == tracing.ANCHOR_EVENT and e["args"]["kind"] == "flush_boundary":

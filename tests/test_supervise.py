@@ -31,7 +31,7 @@ from simclr_pytorch_distributed_tpu.supervise.supervisor import (
     SuperviseConfig,
     Supervisor,
 )
-from simclr_pytorch_distributed_tpu.utils import prom
+from simclr_pytorch_distributed_tpu.utils import prom, tracing
 
 pytestmark = pytest.mark.supervisor
 
@@ -1203,7 +1203,8 @@ def test_unlaunchable_command_gives_up_with_recorded_decision(tmp_path):
     assert rc == 127
     assert [d.action for d in sup.decisions] == [policy.GIVE_UP]
     assert "failed to launch" in sup.decisions[0].reason
-    events = read_events(sup)
+    # past the process's start, with which every recorder opens
+    events = [e for e in read_events(sup) if e["track"] != tracing.SETUP_TRACK]
     assert [e["name"] for e in events] == ["launch_failed", "decision"]
     assert events[1]["args"]["rc"] == 127
 

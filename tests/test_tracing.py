@@ -13,6 +13,7 @@ per epoch) exactly, recorder or no recorder.
 import json
 import logging
 import os
+import time
 import urllib.request
 
 import numpy as np
@@ -640,3 +641,267 @@ def test_run_paths_rotate_per_session(tmp_path):
     ep, tp = tracing.run_paths(str(tmp_path), process_index=1)
     assert os.path.basename(ep) == "events_p1.jsonl"
     assert os.path.basename(tp) == "trace_p1.json"
+
+
+# ------------------------------------------------ set-up on the record
+
+
+@pytest.fixture
+def before_any_install(monkeypatch):
+    """The module as a fresh process has it: no recorder, the buffer open,
+    imports not yet recorded; the set-up clock and the process's start are
+    the test's to set."""
+    monkeypatch.setattr(tracing, "_current", None)
+    monkeypatch.setattr(tracing, "_early", [])
+    monkeypatch.setattr(tracing, "_imports_recorded", False)
+    monkeypatch.setattr(tracing, "_started_after_boot", lambda: None)
+    clk = FakeClock(50.0)
+    monkeypatch.setattr(tracing, "_setup_clock", clk)
+    return clk
+
+
+def test_records_before_the_first_install_wait_and_are_rebased(before_any_install):
+    clk = before_any_install
+    with tracing.span("store", track=tracing.SETUP_TRACK):
+        clk.advance(2.0)
+    tracing.record_span("trace", tracing.COMPILE_TRACK, 52.5, 53.0, fun_name="f")
+    tracing.record_event("mark", tracing.SETUP_TRACK, 51.0, at="an attribute")
+    tracing.event("package_import", track=tracing.SETUP_TRACK)
+    tracing.event("y", track="main:flush")  # not a set-up track: dropped
+    with tracing.span("x", track="main:compile"):
+        clk.advance(1.0)
+    for _ in range(tracing.EARLY_RECORDS_MAX + 40):  # the bound holds
+        tracing.event("filler", track=tracing.COMPILE_TRACK)
+    assert len(tracing._early) == tracing.EARLY_RECORDS_MAX
+    clk.advance(4.0)  # the recorder starts at 57.0
+    rec = tracing.FlightRecorder(clock=clk)
+    tracing.install(rec)
+    try:
+        snap = rec.snapshot()
+        assert [e["name"] for e in snap[:4]] == ["store", "trace", "mark", "package_import"]
+        assert len(snap) == tracing.EARLY_RECORDS_MAX
+        store, trace, named, mark = snap[:4]
+        assert named["ts"] == pytest.approx(-6.0) and named["args"] == {"at": "an attribute"}
+        assert store["ts"] == pytest.approx(-7.0) and store["dur"] == pytest.approx(2.0)
+        assert trace["ts"] == pytest.approx(-4.5) and trace["args"] == {"fun_name": "f"}
+        assert mark["ph"] == "i" and mark["ts"] == pytest.approx(-5.0)
+    finally:
+        tracing.uninstall()
+    # after the first install nothing waits any more: no recorder, no work
+    tracing.event("late", track=tracing.SETUP_TRACK)
+    with tracing.span("late_span", track=tracing.SETUP_TRACK):
+        pass
+    assert tracing._early is None
+    rec2 = tracing.FlightRecorder(clock=clk)
+    tracing.install(rec2)
+    tracing.uninstall()
+    assert rec2.snapshot() == []
+
+
+def test_the_first_install_of_none_or_another_clock_drops_the_buffer(before_any_install):
+    tracing.event("package_import", track=tracing.SETUP_TRACK)
+    other = tracing.FlightRecorder(clock=FakeClock(3.0))  # not the set-up clock
+    tracing.install(other)
+    tracing.uninstall()
+    assert other.snapshot() == [] and tracing._early is None
+    tracing.install(None)  # a second first install finds nothing
+    assert tracing._early is None
+
+
+def test_imports_done_records_the_import_span_once(before_any_install, monkeypatch):
+    import simclr_pytorch_distributed_tpu as package
+
+    clk = before_any_install
+    monkeypatch.setattr(package, "IMPORT_STARTED", 51.0)
+    clk.advance(3.0)
+    tracing.imports_done()
+    clk.advance(1.0)
+    tracing.imports_done()  # once a process
+    rec = tracing.FlightRecorder(clock=clk)
+    tracing.install(rec)
+    tracing.uninstall()
+    mark, span = rec.snapshot()
+    assert (mark["name"], mark["ts"]) == (tracing.PACKAGE_IMPORT, pytest.approx(-3.0))
+    assert span["name"] == "import" and span["track"] == tracing.SETUP_TRACK
+    assert span["ts"] == pytest.approx(-3.0) and span["dur"] == pytest.approx(2.0)
+
+
+def test_process_start_is_negative_and_no_older_than_the_interpreter(tmp_path):
+    """A fresh interpreter's recorder: its ``process_start`` lies before the
+    interpreter's first line and after the moment it was launched, to the
+    kernel's tick. ``tracing`` is loaded from its file: stdlib only."""
+    import subprocess
+    import sys
+
+    if tracing.process_start() is None:
+        pytest.skip("no /proc/self/stat here")
+    tick = 1.0 / os.sysconf("SC_CLK_TCK")
+    script = tmp_path / "child.py"
+    script.write_text(
+        "import time\n"
+        "first = time.monotonic()\n"
+        "import importlib.util, json\n"
+        f"spec = importlib.util.spec_from_file_location('t', {tracing.__file__!r})\n"
+        "t = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(t)\n"
+        "rec = t.FlightRecorder()\n"
+        "(start,) = [e for e in rec.snapshot() if e['name'] == 'process_start']\n"
+        "print(json.dumps({'first': first, 'ts': start['ts'], 't0': rec._t0,\n"
+        "                  'track': start['track']}))\n"
+    )
+    launched = time.monotonic()
+    out = subprocess.run([sys.executable, str(script)], capture_output=True, text=True,
+                         timeout=120, check=True)
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    started = got["t0"] + got["ts"]
+    assert got["track"] == tracing.SETUP_TRACK and got["ts"] < 0
+    assert launched - tick <= started <= got["first"] + tick
+    # and this process's recorders say the same of this process
+    rec = tracing.FlightRecorder()
+    (start,) = [e for e in rec.snapshot() if e["name"] == tracing.PROCESS_START]
+    assert start["ts"] < 0 and rec._t0 + start["ts"] < launched
+
+
+def test_a_small_jit_records_trace_lower_and_compile_spans():
+    """One program: its trace, lowering and backend compile, spans on track
+    ``compile`` with ``fun_name``; the compile says whether the persistent
+    cache answered (``cache_hit``), and no separate hit event is left."""
+    import jax
+    import jax.numpy as jnp
+
+    tracing.forward_compile_events()
+    rec = tracing.FlightRecorder(clock=FakeClock())  # the spans carry JAX's times
+    tracing.install(rec)
+    try:
+        def spanned_small(v):
+            return jnp.sin(v) * 2.0
+
+        jax.jit(spanned_small)(np.ones(3, np.float32))
+        # the hit flag: set by the cache's event inside a compile, read by
+        # that compile's span, and not carried to the next
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 10.0, 10.5, fun_name="jit(spanned_hit)")
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 11.0, 11.25, fun_name="jit(spanned_miss)")
+    finally:
+        tracing.uninstall()
+    events = rec.snapshot()
+    mine = {e["name"]: e for e in events if "spanned_small" in e.get("args", {}).get("fun_name", "")}
+    assert set(mine) == {"trace", "lower", "backend_compile"}
+    assert all(e["ph"] == "X" and e["track"] == tracing.COMPILE_TRACK for e in mine.values())
+    assert mine["trace"]["ts"] <= mine["lower"]["ts"] <= mine["backend_compile"]["ts"]
+    args = mine["backend_compile"]["args"]
+    assert args["cache_hit"] is False and args["duration_s"] == mine["backend_compile"]["dur"]
+    hit, miss = (next(e["args"] for e in events if e.get("args", {}).get("fun_name") == name)
+                 for name in ("jit(spanned_hit)", "jit(spanned_miss)"))
+    assert hit["cache_hit"] is True and hit["duration_s"] == 0.5
+    assert miss["cache_hit"] is False
+    assert not [e for e in events if e["name"] == "cache_hit"]
+
+
+def test_bare_package_import_imports_neither_jax_nor_utils():
+    import subprocess
+    import sys
+
+    code = ("import sys; before = set(sys.modules); import simclr_pytorch_distributed_tpu; "
+            "print(sorted(set(sys.modules) - before))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         timeout=120, check=True, cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.stdout.strip() == "['simclr_pytorch_distributed_tpu']"
+
+
+def _trace_report():
+    import importlib
+    import sys
+
+    scripts = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts")
+    sys.path.insert(0, scripts)
+    try:
+        return importlib.import_module("trace_report")
+    finally:
+        sys.path.remove(scripts)
+
+
+def test_setup_table_sums_to_its_wall():
+    """Process start at -10 to the first flush boundary at 6: nested traces
+    count once, a compile inside ``store`` counts as compile, the first
+    step's trace and compile as theirs and its rest as ``first_step``."""
+    tr = _trace_report()
+
+    def x(name, track, ts, dur, **args):
+        return {"name": name, "track": track, "ph": "X", "ts": ts, "dur": dur, "args": args}
+
+    events = [
+        {"name": "process_start", "track": "setup", "ph": "i", "ts": -10.0},
+        {"name": "package_import", "track": "setup", "ph": "i", "ts": -9.0},
+        x("import", "setup", -9.0, 5.0),
+        x("backend_start", "setup", -3.5, 1.5),
+        x("store", "setup", -1.0, 2.0),
+        x("backend_compile", "compile", 0.0, 0.5, fun_name="jit(gather)", cache_hit=True),
+        x("trace", "compile", 2.0, 1.0, fun_name="f"),
+        x("trace", "compile", 2.2, 0.3, fun_name="sin"),  # nested: counted once
+        x("lower", "compile", 3.0, 0.5, fun_name="jit(f)"),
+        x("backend_compile", "compile", 3.5, 1.0, fun_name="jit(f)", cache_hit=False),
+        x("first_step", "main:compile", 1.5, 3.5, step=0),
+        x("tb_writer", "setup", 1.0, 0.25),
+        x("flush_boundary", "main:flush", 6.0, 0.1, steps=2),
+        x("flush_boundary", "main:flush", 7.0, 0.1, steps=2),
+    ]
+    setup = tr.build_setup(events)
+    rows = setup["rows"]
+    assert setup["wall_s"] == 16.0 and sum(rows.values()) == pytest.approx(16.0)
+    assert rows["compile"] == pytest.approx(1.5) and rows["trace_lower"] == pytest.approx(1.5)
+    assert rows["boot"] == pytest.approx(1.0) and rows["import"] == pytest.approx(5.0)
+    assert rows["backend_start"] == pytest.approx(1.5) and rows["tb_writer"] == pytest.approx(0.25)
+    assert rows["store"] == pytest.approx(1.5)  # its 0.5 of compile is compile's
+    # the first step less its trace, lowering and compile: [1.5, 2] and [4.5, 5]
+    assert rows["first_step"] == pytest.approx(1.0) and rows["first_window"] == pytest.approx(1.0)
+    assert rows["other"] == pytest.approx(16.0 - 14.25)
+    assert [p["fun_name"] for p in setup["programs"]] == ["jit(f)", "jit(gather)"]
+    assert setup["programs"][1]["cache_hits"] == 1
+    text = tr.render_setup(setup)
+    assert "backend_start" in text and "tb_writer" in text and "1 hit, 0 miss" in text
+    # the attribution table still starts at the recorder's own start
+    report = tr.build_report(events)
+    assert report["consistency"]["wall_s"] == pytest.approx(7.1) and report["consistency"]["ok"]
+
+
+def test_main_supcon_run_puts_its_setup_on_the_record(tmp_path):
+    """An operator's run of ``main_supcon.py``, in a process of its own:
+    ``events.jsonl`` opens at the process's start, holds the set-up spans
+    (``import``, ``backend_start``, ``store``, ``tb_writer``), none of which
+    overlaps another, and every compile as spans with ``cache_hit``; and
+    ``trace_report.py`` prints the set-up table beside an attribution that
+    still holds."""
+    import glob
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, XLA_FLAGS="", JAX_PLATFORMS="cpu")
+    subprocess.run(
+        [sys.executable, "main_supcon.py", "--dataset", "synthetic", "--epochs", "1",
+         "--batch_size", "256", "--size", "8", "--model", "resnet10", "--learning_rate",
+         "0.05", "--temp", "0.5", "--method", "SimCLR", "--print_freq", "3",
+         "--save_freq", "5", "--workdir", str(tmp_path)],
+        cwd=root, env=env, check=True, timeout=600, capture_output=True)
+    (path,) = glob.glob(str(tmp_path / "**" / "events.jsonl"), recursive=True)
+    events = tracing.load_events_jsonl(path)
+    assert events[0]["name"] == tracing.PROCESS_START and events[0]["ts"] < 0
+    spans = sorted((e for e in events if e["track"] == tracing.SETUP_TRACK and e["ph"] == "X"),
+                   key=lambda e: e["ts"])
+    assert {"import", "backend_start", "store", "tb_writer"} <= {e["name"] for e in spans}
+    for a, b in zip(spans, spans[1:]):
+        assert b["ts"] >= a["ts"] + a["dur"] - 1e-6, (a, b)
+    compiles = [e for e in events if e["name"] == "backend_compile"]
+    assert compiles and all(e["ph"] == "X" and "fun_name" in e["args"]
+                            and isinstance(e["args"]["cache_hit"], bool) for e in compiles)
+    assert not [e for e in events if e["name"] == "cache_hit"]
+    out = subprocess.run([sys.executable, "scripts/trace_report.py", "--events", path],
+                         cwd=root, env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stdout + out.stderr  # consistency.ok
+    assert "set-up, process start to the first flush boundary" in out.stdout
+    for row in ("boot", "import", "backend_start", "store", "tb_writer", "compile"):
+        assert f"\n  {row} " in out.stdout, out.stdout
