@@ -423,10 +423,12 @@ def render_table(report):
                 f"tokens a row, on {latent['path']}'s path: {latent['reason']}")
         linear = report["encoder"].get("linear_attention_plan")
         if linear:
-            kinds, reasons = linear["layers"], {}
+            kinds, reasons, conv_reasons = linear["layers"], {}, {}
             for layer in linear["per_layer"]:
                 if layer["reason"]:
                     reasons.setdefault(layer["reason"], []).append(layer["name"])
+                if layer.get("conv_reason"):
+                    conv_reasons.setdefault(layer["conv_reason"], []).append(layer["name"])
             lines.append(
                 f"linear attention: {kinds.get('linear', 0)} Gated DeltaNet layers of "
                 f"{linear['key_heads']} key / {linear['value_heads']} value heads of "
@@ -435,7 +437,11 @@ def render_table(report):
                 f"{linear['conv_width']}-tap convolution, scan in chunks of {linear['chunk']} "
                 f"of {linear['tokens']} tokens, {linear['row_group']} rows a group, "
                 f"{linear['engaged']} on the kernel pair, {linear['on_xla']} on XLA's path"
-                + "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()))
+                + "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items())
+                + (f"; convolution: {linear['conv_engaged']} on its kernel pair, "
+                   f"{linear['conv_on_xla']} on XLA's path" + "".join(
+                       f"; {', '.join(names)}: {why}" for why, names in conv_reasons.items())
+                   if "conv_engaged" in linear else ""))
     for a in report["anomalies"]:
         lines.append(f"ANOMALY [{a['phase']}]: {a['flag']}")
     if not report["consistency"]["ok"]:

@@ -26,8 +26,12 @@ heads; the tests hold it to the recurrence token by token
 kernel pair computes the same a row's chunks in one sweep, each head's state
 in VMEM, over the key heads whole. The layer takes the second where its
 owner says the program is one TPU's (``kernel``) and its own dtype and shape
-allow it (``GatedDeltaNet.kernel_reason``). The layer recomputes its own row
-groups (``map_row_groups``), as the latent layer does.
+allow it (``GatedDeltaNet.kernel_reason``). Likewise the convolution and its
+``silu``: ``short_conv`` is XLA's path, ``ops/short_conv.py``'s Mosaic
+kernel pair reads the projection's columns once a pass where ``kernel`` and
+``GatedDeltaNet.conv_reason`` allow, on its own terms, so either pair may
+engage without the other. The layer recomputes its own row groups
+(``map_row_groups``), as the latent layer does.
 
 Parameter layout: ``W_qkvz`` is ``[q | k | v | z]`` and ``W_ba`` ``[b | c]``,
 each part head-major; the published checkpoint groups the columns by key
@@ -53,6 +57,7 @@ from simclr_pytorch_distributed_tpu.models.sparse_attention import (
     tie_gradients,
 )
 from simclr_pytorch_distributed_tpu.ops import delta_rule as kernel_ops
+from simclr_pytorch_distributed_tpu.ops import short_conv as conv_ops
 
 # the whole layer: norm, projections, convolution, gates, scan, output
 SCOPE_LINEAR = "linear_attn"
@@ -193,10 +198,11 @@ class GatedDeltaNet(nn.Module):
     chunk: int
     rms_eps: float
     dtype: Any = jnp.float32
-    # the chunked rule through ops/delta_rule.py's kernel pair. Set by the
-    # owner that knows the mesh holds ONE device and the backend is a TPU
-    # (train.supcon.build); the dtype and the row's shape can still say no
-    # (kernel_reason).
+    # the chunked rule and the convolution through the kernel pairs of
+    # ops/delta_rule.py and ops/short_conv.py. Set by the owner that knows
+    # the mesh holds ONE device and the backend is a TPU
+    # (train.supcon.build); the dtype and the row's shape can still say no,
+    # to each on its own (kernel_reason, conv_reason).
     kernel: bool = False
 
     def chunk_of(self, tokens: int) -> int:
@@ -214,6 +220,15 @@ class GatedDeltaNet(nn.Module):
         return kernel_ops.unsupported(tokens, self.chunk_of(tokens), self.n_key_heads,
                                       self.n_value_heads, self.key_dim, self.value_dim)
 
+    def conv_reason(self, tokens: int) -> Optional[str]:
+        """Why rows of ``tokens`` keep XLA's convolution (``short_conv``),
+        or None: ops/short_conv.py's kernel pair is float32, over channels
+        of whole lanes and rows of whole token blocks, within its VMEM
+        budget. Independent of ``kernel_reason``: either kernel pair may
+        engage without the other."""
+        mixed = 2 * self.n_key_heads * self.key_dim + self.n_value_heads * self.value_dim
+        return conv_ops.unsupported(tokens, mixed, self.conv_width, self.dtype)
+
     @nn.compact
     def __call__(self, h: jax.Array) -> tuple:
         R, T, D = h.shape
@@ -227,6 +242,8 @@ class GatedDeltaNet(nn.Module):
                   for name, n in (("norm", D), ("dt_bias", Hv), ("out_norm", dv))})
         chunk = self.chunk_of(T)
         kernel = self.kernel and self.kernel_reason(T) is None
+        conv_kernel = self.kernel and self.conv_reason(T) is None
+        interpret = _interpret_kernel()
         with jax.named_scope(SCOPE_LINEAR):
             w, h = tie_gradients((w, h))
             w = {name: x if name in ("A_log", "dt_bias") else x.astype(self.dtype)
@@ -237,7 +254,10 @@ class GatedDeltaNet(nn.Module):
                 a = rms_norm(h, w["norm"], self.rms_eps).astype(self.dtype)
                 qkvz = a @ w["qkvz"]
                 with jax.named_scope(SCOPE_CONV):
-                    qkv = jax.nn.silu(short_conv(qkvz[..., :mixed], w["conv"]))
+                    if conv_kernel:  # reads the first ``mixed`` columns where they lie
+                        qkv = conv_ops.short_conv_silu(qkvz, w["conv"], interpret=interpret)
+                    else:
+                        qkv = jax.nn.silu(short_conv(qkvz[..., :mixed], w["conv"]))
                 q = l2_normalise(qkv[..., :Hk * dk].reshape(n, T, Hk, dk)) / math.sqrt(dk)
                 k = l2_normalise(qkv[..., Hk * dk:2 * Hk * dk].reshape(n, T, Hk, dk))
                 if not kernel:
@@ -250,7 +270,6 @@ class GatedDeltaNet(nn.Module):
                     if kernel:
                         # the projections' layout as it comes: [rows, T, heads * d];
                         # the products' operands as XLA's default precision has them
-                        interpret = _interpret_kernel()
                         o = kernel_ops.delta_rule(
                             q.reshape(n, T, Hk * dk), k.reshape(n, T, Hk * dk), v, g, beta,
                             n_key_heads=Hk, chunk=chunk,

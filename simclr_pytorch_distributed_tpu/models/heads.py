@@ -126,8 +126,9 @@ class SupConResNet(nn.Module):
     # set by train.supcon.build on a one-device TPU mesh (models/resnet.py)
     pointwise_bwd: bool = False
     # a token encoder's attention through ops/sparse_attention.py's kernel
-    # pair and its chunked delta rule through ops/delta_rule.py's: set by
-    # train.supcon.build likewise (models/token_encoder.py)
+    # pair, its chunked delta rule through ops/delta_rule.py's and its
+    # convolution through ops/short_conv.py's: set by train.supcon.build
+    # likewise (models/token_encoder.py)
     attn_kernel: bool = False
     # the operands of a token encoder's grouped expert products, ``dtype``
     # where None: set by train.supcon.build likewise (models/experts.py)

@@ -293,11 +293,12 @@ class TokenEncoder(nn.Module):
     dtype: Any = jnp.float32
     # each block's sparse attention and expert layer recomputed in the backward
     remat: bool = False
-    # attention through ops/sparse_attention.py's kernel pair and the
-    # chunked delta rule through ops/delta_rule.py's: set by
-    # train.supcon.build on a one-device TPU mesh; each layer's dtype and
-    # shape can still say no (SparseAttention.kernel_reason,
-    # GatedDeltaNet.kernel_reason)
+    # attention through ops/sparse_attention.py's kernel pair, the chunked
+    # delta rule through ops/delta_rule.py's and the convolution through
+    # ops/short_conv.py's: set by train.supcon.build on a one-device TPU
+    # mesh; each layer's dtype and shape can still say no
+    # (SparseAttention.kernel_reason, GatedDeltaNet.kernel_reason,
+    # GatedDeltaNet.conv_reason)
     attn_kernel: bool = False
     # the type of the expert layers' grouped products' operands, ``dtype``
     # where None: set by train.supcon.build likewise (ExpertLayer.product_dtype)

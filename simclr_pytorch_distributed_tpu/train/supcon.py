@@ -287,11 +287,14 @@ def plan_linear_attention(
     ``linear_attention_plan`` event (track ``compile``): the layers by kind,
     the linear layers' heads and widths, the convolution's taps, the scan's
     chunk, the full layers' heads, the rows a group, and for each linear
-    layer its path with its reason (``per_layer``; ``engaged`` on
-    ops/delta_rule.py's kernel pair, ``on_xla``). No flag chooses: one
-    device, a TPU, and what the layer says of itself
-    (``GatedDeltaNet.kernel_reason``: float32, a chunk and head widths the
-    kernels tile within their VMEM budget). Returns ``[{"name", "reason"}]``
+    layer the path of its rule and of its convolution with their reasons
+    (``per_layer``; ``engaged`` on ops/delta_rule.py's kernel pair and
+    ``on_xla``, ``conv_engaged`` on ops/short_conv.py's and
+    ``conv_on_xla``). No flag chooses: one device, a TPU, and what the
+    layer says of itself (``GatedDeltaNet.kernel_reason``: float32, a chunk
+    and head widths the kernels tile within their VMEM budget;
+    ``GatedDeltaNet.conv_reason``: float32, channels of whole lanes, rows
+    of whole token blocks). Returns ``[{"name", "reason", "conv_reason"}]``
     a linear layer, empty for an encoder without such layers."""
     from simclr_pytorch_distributed_tpu.models.gated_delta import GatedDeltaNet
     from simclr_pytorch_distributed_tpu.models.sparse_attention import ROW_GROUP
@@ -305,10 +308,14 @@ def plan_linear_attention(
     tokens = (cfg.size // spec.patch) ** 2
     layer = GatedDeltaNet(**delta_attrs(spec, encoder.dtype, True))
     owner = _one_tpu_reason(n_devices)
-    sites = [{"name": f"block{k}", "reason": owner or layer.kernel_reason(tokens)}
+    sites = [{"name": f"block{k}", "reason": owner or layer.kernel_reason(tokens),
+              "conv_reason": owner or layer.conv_reason(tokens)}
              for k, kind in enumerate(kinds) if kind == "linear"]
     reasons = _reasons(sites)
+    conv_reasons = _reasons([{"name": site["name"], "reason": site["conv_reason"]}
+                             for site in sites])
     on_xla = sum(len(names) for names in reasons.values())
+    conv_on_xla = sum(len(names) for names in conv_reasons.values())
     plan = {"layers": {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)},
             "key_heads": spec.linear_key_heads, "value_heads": spec.linear_value_heads,
             "key_dim": spec.linear_key_dim, "value_dim": spec.linear_value_dim,
@@ -316,19 +323,24 @@ def plan_linear_attention(
             "full_heads": spec.n_heads, "full_kv_heads": spec.n_kv_heads,
             "full_head_dim": spec.head_dim, "tokens": tokens, "row_group": ROW_GROUP,
             "engaged": len(sites) - on_xla, "on_xla": on_xla,
+            "conv_engaged": len(sites) - conv_on_xla, "conv_on_xla": conv_on_xla,
             "per_layer": [{"name": site["name"],
                            "path": "xla" if site["reason"] else "kernel",
-                           "reason": site["reason"]} for site in sites]}
+                           "reason": site["reason"],
+                           "conv_path": "xla" if site["conv_reason"] else "kernel",
+                           "conv_reason": site["conv_reason"]} for site in sites]}
     logging.info(
         "[linear_attention] %d Gated DeltaNet layers of %d key / %d value heads of %d / %d, "
         "%d-tap convolution, scan in chunks of %d tokens, beside %d %s layers of %d / %d "
         "heads of %d; %d causal tokens a row, %d rows a group; %d on the kernel pair, "
-        "%d on XLA's path%s",
+        "%d on XLA's path%s; convolution: %d on its kernel pair, %d on XLA's path%s",
         len(sites), plan["key_heads"], plan["value_heads"], plan["key_dim"],
         plan["value_dim"], plan["conv_width"], plan["chunk"], len(kinds) - len(sites),
         spec.attention, plan["full_heads"], plan["full_kv_heads"], plan["full_head_dim"],
         tokens, ROW_GROUP, plan["engaged"], on_xla,
-        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()))
+        "".join(f"; {', '.join(names)}: {why}" for why, names in reasons.items()),
+        plan["conv_engaged"], conv_on_xla,
+        "".join(f"; {', '.join(names)}: {why}" for why, names in conv_reasons.items()))
     tracing.event("linear_attention_plan", track=tracing.COMPILE_TRACK, **plan)
     return sites
 
@@ -416,7 +428,8 @@ def build(cfg: config_lib.SupConConfig, steps_per_epoch: int, n_devices: int = 1
     model = SupConResNet(
         model_name=cfg.model, head=cfg.head, feat_dim=cfg.feat_dim,
         pointwise_bwd=any(site["reason"] is None for site in tail_plan),
-        attn_kernel=any(layer["reason"] is None for layer in attention_plan + linear_plan),
+        attn_kernel=any(why is None for layer in attention_plan + linear_plan
+                        for why in (layer["reason"], layer.get("conv_reason", layer["reason"]))),
         expert_product_dtype=product_dtype, **encoder_kwargs,
     )
     plan_latent_attention(cfg, model)

@@ -404,7 +404,9 @@ def test_build_says_what_the_layers_are(one_step):
         "layers": {"linear": 3, "gated": 1}, "key_heads": 2, "value_heads": 4, "key_dim": 8,
         "value_dim": 8, "conv_width": 4, "chunk": 4, "full_heads": 4, "full_kv_heads": 2,
         "full_head_dim": 8, "tokens": 16, "row_group": 2, "engaged": 0, "on_xla": 3,
-        "per_layer": [{"name": f"block{k}", "path": "xla", "reason": "non-TPU backend (cpu)"}
+        "conv_engaged": 0, "conv_on_xla": 3,
+        "per_layer": [{"name": f"block{k}", "path": "xla", "reason": "non-TPU backend (cpu)",
+                       "conv_path": "xla", "conv_reason": "non-TPU backend (cpu)"}
                       for k in range(3)]}
     (experts_plan,) = [r["args"] for r in one_step["events"] if r["name"] == "expert_plan"]
     assert (experts_plan["layers"], experts_plan["router"], experts_plan["shared_width"],
@@ -446,10 +448,13 @@ def test_trace_report_prints_the_plans():
     linear = {"layers": {"linear": 3, "gated": 1}, "key_heads": 16, "value_heads": 32,
               "key_dim": 128, "value_dim": 128, "conv_width": 4, "chunk": 64, "full_heads": 16,
               "full_kv_heads": 2, "full_head_dim": 256, "tokens": 4096, "row_group": 2,
-              "engaged": 2, "on_xla": 1,
-              "per_layer": [{"name": "block0", "path": "kernel", "reason": None},
-                            {"name": "block1", "path": "kernel", "reason": None},
-                            {"name": "block2", "path": "xla", "reason": "no kernel"}]}
+              "engaged": 2, "on_xla": 1, "conv_engaged": 1, "conv_on_xla": 2,
+              "per_layer": [{"name": "block0", "path": "kernel", "reason": None,
+                             "conv_path": "kernel", "conv_reason": None},
+                            {"name": "block1", "path": "kernel", "reason": None,
+                             "conv_path": "xla", "conv_reason": "no conv kernel"},
+                            {"name": "block2", "path": "xla", "reason": "no kernel",
+                             "conv_path": "xla", "conv_reason": "no conv kernel"}]}
     events = [span,
               {"name": "expert_plan", "track": "compile", "ph": "i", "ts": 0.1, "args": plan},
               {"name": "linear_attention_plan", "track": "compile", "ph": "i", "ts": 0.1,
@@ -462,5 +467,13 @@ def test_trace_report_prints_the_plans():
     assert "shared experts of width 512 under a sigmoid gate" in table
     assert ("linear attention: 3 Gated DeltaNet layers of 16 key / 32 value heads of 128 / 128 "
             "beside 1 full, 4-tap convolution, scan in chunks of 64 of 4096 tokens, 2 rows a "
-            "group, 2 on the kernel pair, 1 on XLA's path; block2: no kernel") in table
+            "group, 2 on the kernel pair, 1 on XLA's path; block2: no kernel; convolution: 1 "
+            "on its kernel pair, 2 on XLA's path; block1, block2: no conv kernel") in table
+    # a run recorded before the convolution had a kernel pair prints as it did
+    for key in ("conv_engaged", "conv_on_xla"):
+        linear.pop(key)
+    for layer in linear["per_layer"]:
+        layer.pop("conv_path"), layer.pop("conv_reason")
+    table = trace_report.render_table(trace_report.build_report(events))
+    assert "block2: no kernel\n" in table + "\n" and "convolution:" not in table
     assert "delta_decay_mean 0.05" in table

@@ -273,6 +273,10 @@ def _cfg(**kw):
         **kw})
 
 
+# where the convolution's kernel pair gives another reason than the rule's
+CONV_WHY = {"tiny-by-shape": "64 channels are not a multiple of 128 lanes"}
+
+
 @pytest.mark.parametrize("case,cfg_kw,n_devices,backend,engaged,why", [
     ("the-cell-on-one-tpu", {}, 1, "tpu", 3, None),
     ("tiny-by-shape", {"model": "qwen3-next-tiny", "size": 16}, 1, "tpu", 0,
@@ -285,7 +289,10 @@ def test_linear_plan_says_which_layers_take_the_kernel_pair_and_why(
         monkeypatch, case, cfg_kw, n_devices, backend, engaged, why):
     """No flag: the backend, the mesh, the dtype and the layer's shape
     decide, and the run says so once (one ``linear_attention_plan`` event on
-    track ``compile``; 3 / 0 for the benchmark's cell on a TPU)."""
+    track ``compile``; 3 / 0 for the benchmark's cell on a TPU), for the
+    rule's kernel pair (``engaged`` / ``on_xla``, a layer's ``path`` and
+    ``reason``) and for the convolution's (``conv_engaged`` /
+    ``conv_on_xla``, ``conv_path`` and ``conv_reason``)."""
     cfg = _cfg(**cfg_kw)
     monkeypatch.setattr(supcon.jax, "default_backend", lambda: backend)
     rec = tracing.FlightRecorder(clock=lambda: 0.0)
@@ -302,6 +309,12 @@ def test_linear_plan_says_which_layers_take_the_kernel_pair_and_why(
     assert (said["engaged"], said["on_xla"]) == (engaged, 3 - engaged)
     assert [p["reason"] for p in plan] == [p["reason"] for p in said["per_layer"]] == [why] * 3
     assert [p["path"] for p in said["per_layer"]] == ["xla" if why else "kernel"] * 3
+    conv_why = CONV_WHY.get(case, why)
+    conv_engaged = 0 if conv_why else 3
+    assert (said["conv_engaged"], said["conv_on_xla"]) == (conv_engaged, 3 - conv_engaged)
+    assert ([p["conv_reason"] for p in plan] == [p["conv_reason"] for p in said["per_layer"]]
+            == [conv_why] * 3)
+    assert [p["conv_path"] for p in said["per_layer"]] == ["xla" if conv_why else "kernel"] * 3
 
 
 def test_an_encoder_without_linear_layers_has_no_linear_plan():

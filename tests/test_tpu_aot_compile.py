@@ -30,7 +30,7 @@ from simclr_pytorch_distributed_tpu.models import experts
 from simclr_pytorch_distributed_tpu.models import sparse_attention as attention_layer
 from simclr_pytorch_distributed_tpu.models import token_encoder
 from simclr_pytorch_distributed_tpu.ops import (
-    delta_rule, pallas_loss, pointwise_bwd, sparse_attention)
+    delta_rule, pallas_loss, pointwise_bwd, short_conv, sparse_attention)
 
 ROWS, SIZE, FEAT_DIM = 512, 32, 128  # 2 * batch 256 view rows, CIFAR, head out
 
@@ -664,26 +664,33 @@ def test_latent_cells_step_compiles_with_group_sized_dense_intermediates(topo, t
 # experts with a gated shared expert) as a TPU's program builds it
 
 
-def test_delta_cells_step_compiles_with_group_sized_scan_tensors(topo, tmp_path, monkeypatch):
-    """Built as on the chip (``jax.default_backend()`` reads "tpu": the
-    grouped products on bfloat16 operands, the fused loss's kernels, the
-    chunked delta rule on ops/delta_rule.py's kernel pair): the chip's
-    compiler holds the step in under 12.8 GB of arguments and temporaries
-    (Moonlight's, which loads, in 13.2); the rule is nine Mosaic calls (three
-    layers' forward, recomputed forward and backward), and no ``[..., 64,
-    64]`` block of it is left at the top level of the program, where XLA's
-    path had a 2-row group's (the 64 chunks of 32 heads of two rows); the
-    expert sweep makes one trip of 20,480 rows."""
+@pytest.fixture(scope="module")
+def delta_cells_step(topo, tmp_path_factory):
+    """The cell's step built as on the chip (``jax.default_backend()`` reads
+    "tpu": the grouped products on bfloat16 operands, the fused loss's
+    kernels, the chunked delta rule and the convolution on their kernel
+    pairs), compiled once for the tests below."""
     from simclr_pytorch_distributed_tpu.train import supcon
 
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(supcon.jax, "default_backend", lambda: "tpu")
+        return _cells_step(topo, tmp_path_factory.mktemp("delta"), "qwen3-next-80b-a3b-ep32")
+
+
+def test_delta_cells_step_compiles_with_group_sized_scan_tensors(delta_cells_step):
+    """The chip's compiler holds the step in under 12.8 GB of arguments and
+    temporaries (Moonlight's, which loads, in 13.2); the rule is nine Mosaic
+    calls (three layers' forward, recomputed forward and backward), and no
+    ``[..., 64, 64]`` block of it is left at the top level of the program,
+    where XLA's path had a 2-row group's (the 64 chunks of 32 heads of two
+    rows); the expert sweep makes one trip of 20,480 rows."""
     name = "qwen3-next-80b-a3b-ep32"
     spec = token_encoder.TOKEN_ENCODERS[name]
     assignments = 8 * 4096 * spec.top_k
     provisioned = experts.provisioned_rows(assignments, 16, 512, spec.capacity_factor)
     assert provisioned == 20480 and experts.balanced_chunk_rows(
         assignments, 16, 512, provisioned, spec.hidden, spec.expert_width, jnp.float32) == 20480
-    monkeypatch.setattr(supcon.jax, "default_backend", lambda: "tpu")
-    compiled = _cells_step(topo, tmp_path, name)
+    compiled = delta_cells_step
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.8e9
     text = compiled.as_text()
@@ -699,6 +706,59 @@ def test_delta_cells_step_compiles_with_group_sized_scan_tensors(topo, tmp_path,
     assert blocks == [], blocks
 
 
+def _short_conv_neighbours(text):
+    """``{call: ([(opcode, dims)] of its operands, [(opcode, dims)] of its
+    users)}`` for every ``short_conv`` Mosaic call of a compiled program, in
+    its own computation and seen through bitcasts, reshapes and tuple
+    elements (which move no bytes)."""
+    ops, users, calls = {}, {}, []
+    for computation, line, m in _instructions(text):
+        name, shape, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("),", 1)[0])
+        dims = [tuple(int(d) for d in found.split(",") if d)
+                for found in re.findall(r"\[([\d,]*)\]", shape)]
+        ops[computation, name] = (opcode, dims, operands)
+        for operand in operands:
+            users.setdefault((computation, operand), []).append(name)
+        call = re.search(r'op_name="[^"]*/short_conv/short_conv_(fwd|bwd)/pallas_call"', line)
+        if call and opcode == "custom-call":
+            calls.append((computation, name, call.group(1)))
+
+    def walk(computation, name, step):
+        for other in step(name):
+            if (computation, other) not in ops:
+                continue
+            opcode, dims, _ = ops[computation, other]
+            if opcode in _THROUGH:
+                yield from walk(computation, other, step)
+            else:
+                yield opcode, dims
+
+    return {(name, kind): (
+        list(walk(computation, name, lambda n, c=computation: ops[c, n][2])),
+        list(walk(computation, name, lambda n, c=computation: users.get((c, n), []))))
+        for computation, name, kind in calls}
+
+
+def test_the_convolution_reads_and_writes_the_projection_where_it_lies(delta_cells_step):
+    """The causal convolution and its ``silu`` are six ``short_conv_fwd``
+    calls (three layers' forward and recomputed forward) and three
+    ``short_conv_bwd`` calls, each under its layer's ``short_conv`` scope,
+    the backward's too. Each reads ``x`` from the ``[2, 4096, 12288]``
+    projection as the product that makes it left it, with no slice of its
+    first 8,192 columns; ``dx`` goes straight into the products that take the
+    projection's cotangent (the weight's and the input's gradients), which
+    read it beside ``z``'s: no copy, slice, concatenation or pad of the
+    projection or of its cotangent sits around a call."""
+    around = _short_conv_neighbours(delta_cells_step.as_text())
+    assert sorted(kind for _, kind in around) == ["bwd"] * 3 + ["fwd"] * 6, list(around)
+    projection, part = (2, 4096, 12288), (2, 4096, 8192)
+    for (name, kind), (operands, users) in around.items():
+        assert ("fusion", [projection]) in operands, (name, operands)
+        moved = [(opcode, dims) for opcode, dims in operands + users
+                 if opcode in ("copy", "transpose", "slice", "concatenate", "pad")
+                 and (projection in dims or part in dims)]
+        assert moved == [], (name, moved)
 # ---- the chunked delta rule's kernel pair (ops/delta_rule.py) at the
 # geometry of the cell qwen3-next-80b-a3b-ep32.pretrain-1024px-b4: a 2-row
 # group of 4,096 tokens, 16 key and 32 value heads of 128, chunks of 64
@@ -752,4 +812,47 @@ def test_delta_rule_budget_is_the_compilers(one_chip):
     assert "20.5 MiB of VMEM" in delta_rule.unsupported(768, 192, 16, 32, 128, 128)
     fn, shapes = _delta_rule_calls(one_chip, chunk=192, tokens=768)["bwd"]
     with pytest.raises(Exception, match=r"(?i)vmem.*19\.\d\dM and limit 16\.00M"):
+        _compile(fn, *shapes)
+
+
+# ---- the causal convolution's kernel pair (ops/short_conv.py) at the
+# geometry of the cell qwen3-next-80b-a3b-ep32.pretrain-1024px-b4: a 2-row
+# group of 4,096 tokens, x in the first 8,192 of the projection's 12,288
+# columns, 4 taps
+
+
+def _short_conv_calls(sharding, tokens=4096):
+    """``{"fwd", "bwd"}``: each call with its operands' shapes, as
+    ``short_conv._conv_fwd`` and ``_conv_bwd`` hand them over."""
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    x, w, dy = sds(2, tokens, 12288), sds(4, 8192), sds(2, tokens, 8192)
+    return {"fwd": (lambda *a: short_conv._forward_call(*a, interpret=False), (x, w)),
+            "bwd": (lambda *a: short_conv._backward_call(*a, interpret=False), (x, w, dy))}
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_short_conv_kernels_compile_at_the_cells_shapes(one_chip, which):
+    assert short_conv.unsupported(4096, 8192, 4, jnp.float32) is None
+    fn, shapes = _short_conv_calls(one_chip)[which]
+    assert f"short_conv_{which}" in _compile(fn, *shapes)
+
+
+def test_short_conv_budget_is_the_compilers(one_chip, monkeypatch):
+    """``vmem_bytes`` counts what Mosaic counts for a backward step, to the
+    hundredth of a MiB that its refusal prints: blocks of 1,024 tokens need
+    12.14 MiB, admitted, and compile; blocks of 2,048 need 24.14 MiB,
+    refused, and Mosaic refuses them too, for that count."""
+    monkeypatch.setattr(short_conv, "TOKEN_BLOCK", 1024)
+    assert short_conv.unsupported(4096, 8192, 4, jnp.float32) is None
+    assert f"{short_conv.vmem_bytes(8192, 4) / 2**20:.2f}" == "12.14"
+    fn, shapes = _short_conv_calls(one_chip)["bwd"]
+    _compile(fn, *shapes)
+    monkeypatch.setattr(short_conv, "TOKEN_BLOCK", 2048)
+    assert "24.1 MiB of VMEM" in short_conv.unsupported(4096, 8192, 4, jnp.float32)
+    need = f"{short_conv.vmem_bytes(8192, 4) / 2**20:.2f}M"
+    fn, shapes = _short_conv_calls(one_chip)["bwd"]
+    with pytest.raises(Exception, match=rf"(?i)vmem.*{re.escape(need)} and limit 16\.00M"):
         _compile(fn, *shapes)
