@@ -16,10 +16,10 @@ The rules: ``softmax`` takes the ``top_k`` largest probabilities and
 renormalises them over those; ``sigmoid`` scores each expert on its own,
 chooses the ``top_k`` of largest score plus a load-correcting bias, and
 weighs them by ``gate_scale`` times their unbiased scores over those scores'
-sum: the bias chooses and never weighs. The bias is state and no parameter
-(``route_bias`` in ``batch_stats``, zeros at rest): in train mode each step
-moves it by ``bias_rate`` towards the experts that took less than a balanced
-share of this step's assignments, and no gradient reaches it.
+sum plus ``gate_eps``: the bias chooses and never weighs. The bias is state
+and no parameter (``route_bias`` in ``batch_stats``, zeros at rest): in train
+mode each step moves it by ``bias_rate`` towards the experts that took less
+than a balanced share of this step's assignments, and no gradient reaches it.
 
 What the absent experts would add is left out; no code stands in for the
 other chips or their exchange. No token is dropped, whatever the imbalance:
@@ -118,12 +118,13 @@ def provisioned_rows(assignments: int, held: int, n_experts: int,
     return min(int(capacity_factor * assignments * held / n_experts), assignments)
 
 
-def route(logits: jax.Array, top_k: int, rule: str = "softmax", bias=None, scale: float = 1.0):
+def route(logits: jax.Array, top_k: int, rule: str = "softmax", bias=None, scale: float = 1.0,
+          eps: float = 1e-20):
     """``[N, E]`` float32 router logits -> ``(probabilities [N, E], the chosen
     experts [N, k], their gates [N, k])`` by the module docstring's ``rule``;
-    ``sigmoid`` takes the ``bias [E]`` it chooses by and the gates' ``scale``,
-    and its probabilities are a token's scores over their sum. Ties go to the
-    lower index."""
+    ``sigmoid`` takes the ``bias [E]`` it chooses by, the gates' ``scale`` and
+    the ``eps`` their denominator adds, and its probabilities are a token's
+    scores over their sum. Ties go to the lower index."""
     if rule == "softmax":
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         top_p, top_e = lax.top_k(probs, top_k)
@@ -133,7 +134,7 @@ def route(logits: jax.Array, top_k: int, rule: str = "softmax", bias=None, scale
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, top_e = lax.top_k(scores + bias, top_k)
     top_s = jnp.take_along_axis(scores, top_e, axis=-1)
-    gates = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    gates = scale * top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + eps)
     return scores / jnp.sum(scores, axis=-1, keepdims=True), top_e, gates
 
 
@@ -333,6 +334,7 @@ class ExpertLayer(nn.Module):
     product_dtype: Any = None
     router: str = "softmax"  # or "sigmoid": ``route``'s rule
     gate_scale: float = 1.0
+    gate_eps: float = 1e-20  # the sigmoid gates' denominator adds it
     bias_rate: float = 0.0  # the step of ``route_bias`` in train mode
     sequence_balance: bool = False  # the balance term row by row
     shared_width: int = 0
@@ -365,7 +367,8 @@ class ExpertLayer(nn.Module):
             bias = self.variable("batch_stats", "route_bias", jnp.zeros,
                                  (self.n_experts,), jnp.float32)
         probs, top_e, gates = route(logits, self.top_k, self.router,
-                                    None if bias is None else bias.value, self.gate_scale)
+                                    None if bias is None else bias.value, self.gate_scale,
+                                    self.gate_eps)
         load, prob = routing_statistics(probs, top_e)
         provisioned = provisioned_rows(top_e.size, count, self.n_experts, self.capacity_factor)
         y, n_held = held_mix(

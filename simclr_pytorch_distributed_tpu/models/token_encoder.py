@@ -1,5 +1,5 @@
 """Encoders over patch tokens: a language model's block stack as an image
-encoder. Three blocks so far, each a preset's data:
+encoder. Four blocks so far, each a preset's data:
 
 - Keye-VL-2.0's: grouped-query attention behind a learned top-k key indexer
   (``models/sparse_attention.py``), then softmax-routed experts
@@ -11,13 +11,18 @@ encoder. Three blocks so far, each a preset's data:
 - Qwen3-Next-80B-A3B's (``qwen3_next``): three Gated DeltaNet layers
   (``models/gated_delta.py``) to one gated full-attention layer
   (``models/gated_attention.py``), every layer then softmax-routed experts
-  beside a shared expert that a sigmoid gates.
+  beside a shared expert that a sigmoid gates;
+- LFM2-8B-A1B's (``lfm2_moe``): gated short convolutions
+  (``models/gated_conv.py``) and grouped-query attention without an output
+  gate, in the order the preset lists (``layer_kinds``), then a dense
+  feed-forward layer in the leading ``dense_layers`` blocks and, in the rest,
+  sigmoid-routed experts under a load-correcting bias with no shared expert.
 
 ``[N, H, W, 3]`` views are cut into non-overlapping ``patch x patch`` patches
 in raster order, embedded linearly, run through ``layers`` pre-norm blocks,
 RMS-normed and averaged over the tokens: ``[N, hidden]`` float32 features,
 what ``SupConResNet`` hands its projection head. What a layer is made of
-(each layer's kind of attention and its widths, how many leading layers are
+(each layer's kind of mixer and its widths, how many leading layers are
 dense and how wide, the router's rule, the shared experts' width and gate,
 the gates' scale) lives in ``TOKEN_ENCODERS`` and nowhere else; ``models/resnet.MODEL_DICT`` gets
 one entry a preset. Module names are ``block<k>/attn`` and ``block<k>/moe``
@@ -47,6 +52,7 @@ from flax import linen as nn
 
 from simclr_pytorch_distributed_tpu.models.experts import DenseLayer, ExpertLayer
 from simclr_pytorch_distributed_tpu.models.gated_attention import GatedAttention
+from simclr_pytorch_distributed_tpu.models.gated_conv import GatedShortConv
 from simclr_pytorch_distributed_tpu.models.gated_delta import GatedDeltaNet
 from simclr_pytorch_distributed_tpu.models.latent_attention import LatentAttention
 from simclr_pytorch_distributed_tpu.models.resnet import MODEL_DICT, build_encoder
@@ -80,11 +86,14 @@ class TokenEncoderSpec:
     # "sparse": SparseAttention, which takes the next six; "latent":
     # LatentAttention, which takes the four after them; "gated":
     # GatedAttention, which takes n_kv_heads, head_dim and rope_dim (the
-    # rotary dimensions of a head)
+    # rotary dimensions of a head); "full": GatedAttention without its gate
     attention: str = "sparse"
     # every full_attention_interval-th layer has ``attention`` and the others
     # are Gated DeltaNet ("linear"), which takes the next six; 0: none is
     full_attention_interval: int = 0
+    # each layer's kind where the preset lists them, and then neither of the
+    # two above decides; "conv" is GatedShortConv, which takes conv_width
+    layer_kinds: Tuple[str, ...] = ()
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_dim: int = 0
@@ -107,6 +116,7 @@ class TokenEncoderSpec:
     # experts.route's rule, and what the "sigmoid" rule takes
     router: str = "softmax"
     gate_scale: float = 1.0
+    gate_eps: float = 1e-20
     bias_rate: float = 0.0
     sequence_balance: bool = False
     shared_width: int = 0
@@ -116,11 +126,14 @@ class TokenEncoderSpec:
     # whatever the routing (models/experts.py): twice the balanced load, the
     # capacity factor of GShard's training runs, and beyond it as the data asks
     capacity_factor: float = 2.0
+    # the balance term's weight in the loss; 0: no such term
     balance_coef: float = 0.001
     index_coef: float = 1.0
 
     def attention_of(self, index: int) -> str:
-        """The kind of layer ``index``'s attention."""
+        """The kind of layer ``index``'s mixer."""
+        if self.layer_kinds:
+            return self.layer_kinds[index]
         if self.full_attention_interval and (index + 1) % self.full_attention_interval:
             return "linear"
         return self.attention
@@ -192,6 +205,27 @@ TOKEN_ENCODERS = {
         linear_key_heads=2, linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
         conv_width=4, delta_chunk=4, n_experts=8, top_k=2, expert_width=16, held=(0, 4),
         shared_width=16, shared_expert_gate=True),
+    # LFM2-8B-A1B's block (model_type lfm2_moe) at its published widths
+    # (https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json), one
+    # chip's share of a four-way expert split: 8 of the 32 experts, the first
+    # leading dense layer and the period after the two leading ones
+    # (published layers 0 and 2-5; benchmark/configs/lfm2-8b-a1b-ep4.json has
+    # the cut); the config names no balance term, and the bias's rate is
+    # DeepSeek-V3's
+    "lfm2-8b-a1b-ep4": TokenEncoderSpec(
+        patch=16, hidden=2048, layers=5, n_heads=32, n_kv_heads=8, head_dim=64, rope_dim=64,
+        q_chunk=512, rope_theta=1e6, attention="full",
+        layer_kinds=("conv", "full", "conv", "conv", "conv"), conv_width=3, dense_layers=1,
+        dense_width=7168, n_experts=32, top_k=4, expert_width=1792, held=(0, 8),
+        router="sigmoid", gate_eps=1e-6, bias_rate=0.001, rms_eps=1e-5, balance_coef=0.0),
+    # the same block at test size: the same five kinds of layer, 16 tokens,
+    # half of the experts held
+    "lfm2-tiny": TokenEncoderSpec(
+        patch=4, hidden=32, layers=5, n_heads=4, n_kv_heads=2, head_dim=8, rope_dim=8,
+        q_chunk=4, rope_theta=1e6, attention="full",
+        layer_kinds=("conv", "full", "conv", "conv", "conv"), conv_width=3, dense_layers=1,
+        dense_width=48, n_experts=8, top_k=2, expert_width=16, held=(0, 4),
+        router="sigmoid", gate_eps=1e-6, bias_rate=0.001, rms_eps=1e-5, balance_coef=0.0),
 }
 
 
@@ -236,14 +270,14 @@ def expert_attrs(s: TokenEncoderSpec, dtype, product_dtype=None) -> dict:
     return dict(
         n_experts=s.n_experts, top_k=s.top_k, width=s.expert_width, held=s.held,
         capacity_factor=s.capacity_factor, dtype=dtype, product_dtype=product_dtype,
-        router=s.router, gate_scale=s.gate_scale, bias_rate=s.bias_rate,
+        router=s.router, gate_scale=s.gate_scale, gate_eps=s.gate_eps, bias_rate=s.bias_rate,
         sequence_balance=s.sequence_balance, shared_width=s.shared_width,
         shared_expert_gate=s.shared_expert_gate, rms_eps=s.rms_eps)
 
 
 class Block(nn.Module):
-    """Layer ``index`` of the preset: its attention, then its dense layer or
-    its experts. Returns ``(h, the indexer's KL or None, the Gated DeltaNet's
+    """Layer ``index`` of the preset: its mixer, then its dense layer or its
+    experts. Returns ``(h, the indexer's KL or None, the Gated DeltaNet's
     mean decay or None, the expert layer's statistics or None)``."""
 
     spec: TokenEncoderSpec
@@ -262,14 +296,17 @@ class Block(nn.Module):
         if kind == "sparse":
             h, kl = wrap(SparseAttention)(
                 **attention_attrs(s, self.dtype, self.attn_kernel), name="attn")(h)
-        # the other three recompute their row groups themselves, remat or not
+        # the other four recompute their row groups themselves, remat or not
         elif kind == "latent":
             h = LatentAttention(**latent_attrs(s, self.dtype), name="attn")(h)
-        elif kind == "gated":
-            h = GatedAttention(**gated_attrs(s, self.dtype), name="attn")(h)
+        elif kind in ("gated", "full"):
+            h = GatedAttention(**gated_attrs(s, self.dtype), output_gate=kind == "gated",
+                               name="attn")(h)
         elif kind == "linear":
             h, decay = GatedDeltaNet(**delta_attrs(s, self.dtype, self.attn_kernel),
                                      name="attn")(h)
+        elif kind == "conv":
+            h = GatedShortConv(s.conv_width, s.rms_eps, self.dtype, name="attn")(h)
         else:
             raise ValueError(f"no attention of kind {kind!r}")
         if self.index < s.dense_layers:  # likewise
@@ -333,7 +370,7 @@ class TokenEncoder(nn.Module):
                                          self.expert_product_dtype, name=f"block{k}")(h, train)
             # the loss's terms before the columns' sums, balance before KL: the
             # order of the first block's program, which must not move
-            if routed is not None:
+            if routed is not None and s.balance_coef:
                 aux_loss = aux_loss + s.balance_coef * routed["balance"]
             if kl is not None:
                 aux_loss = aux_loss + s.index_coef * kl
@@ -384,7 +421,10 @@ def _input_projection(s: TokenEncoderSpec) -> tuple:
     if kind == "linear":
         return "qkvz", (s.hidden, 2 * s.linear_key_heads * s.linear_key_dim
                         + 2 * s.linear_value_heads * s.linear_value_dim)
-    width = {"sparse": s.head_dim, "latent": s.nope_dim + s.rope_dim, "gated": 2 * s.head_dim}
+    if kind == "conv":
+        return "bcx", (s.hidden, 3 * s.hidden)
+    width = {"sparse": s.head_dim, "latent": s.nope_dim + s.rope_dim, "gated": 2 * s.head_dim,
+             "full": s.head_dim}
     return "q", (s.hidden, s.n_heads * width[kind])
 
 

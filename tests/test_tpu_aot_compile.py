@@ -856,3 +856,40 @@ def test_short_conv_budget_is_the_compilers(one_chip, monkeypatch):
     fn, shapes = _short_conv_calls(one_chip)["bwd"]
     with pytest.raises(Exception, match=rf"(?i)vmem.*{re.escape(need)} and limit 16\.00M"):
         _compile(fn, *shapes)
+
+
+# ---- the whole step of the cell lfm2-8b-a1b-ep4.pretrain-1024px-b4 (four
+# gated short convolutions and one attention layer without a gate, a dense
+# leading layer, 8 of 32 sigmoid-routed experts) as a TPU's program builds it
+
+
+def test_conv_cells_step_compiles_with_group_sized_conv_and_dense_tensors(topo, tmp_path):
+    """The chip's compiler holds the step in under 12.8 GB of arguments and
+    temporaries (12.64 when written; Moonlight's, which loads at 79% of the
+    chip, in 13.2); the gated convolutions' ``[tokens, 3 x 2048]``
+    projections and the dense layer's ``[tokens, 7168]`` intermediates are a
+    2-row group's, never the batch's; the gated convolution is named in the
+    forward and in the backward; the expert sweep makes 3 trips of 22,016
+    rows over its 65,536-row provision."""
+    from simclr_pytorch_distributed_tpu.train import supcon
+
+    name = "lfm2-8b-a1b-ep4"
+    spec = token_encoder.TOKEN_ENCODERS[name]
+    assignments = 8 * 4096 * spec.top_k
+    provisioned = experts.provisioned_rows(assignments, 8, 32, spec.capacity_factor)
+    assert provisioned == 65536 and experts.balanced_chunk_rows(
+        assignments, 8, 32, provisioned, spec.hidden, spec.expert_width, jnp.float32) == 22016
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(supcon.jax, "default_backend", lambda: "tpu")
+        compiled = _cells_step(topo, tmp_path, name)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.8e9
+    text = compiled.as_text()
+    arrays = _top_level_arrays(text)
+    for width in (3 * spec.hidden, spec.dense_width):
+        wide = [dims for _, dtype, dims in arrays if width in dims]
+        assert wide and max(math.prod(dims) for dims in wide) <= 2 * 4096 * width, wide
+    assert any(dims == (22016, spec.expert_width) for _, _, dims in arrays)
+    for direction in (r"jvp\(SupConResNet\)", r"transpose\(jvp\(SupConResNet\)\)"):
+        assert re.search(rf'op_name="jit\(ring_update\)/{direction}/encoder/block4/attn/'
+                         r'conv_mixer/[^"]*gated_conv/', text), direction
